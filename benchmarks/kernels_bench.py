@@ -1,7 +1,8 @@
 """Kernel microbenchmark: correctness + FUM memory-traffic accounting.
 
-No TPU in this container, so kernels run in interpret mode: the benchmark
-verifies (a) allclose vs the pure-jnp oracle across shapes, and (b) the
+Kernels run natively on a TPU and in Pallas interpret mode on the CPU
+(``JAX_PLATFORMS=cpu``); interpret mode checks results, never speed. The
+benchmark verifies (a) allclose vs the pure-jnp oracle across shapes, and (b) the
 *structural* memory win of Fetch-Upon-Mask — HBM bytes that the
 block-sparse kernel's BlockSpecs fetch vs the dense flash kernel, at the
 sparsity level the scout actually produced. On hardware (b) is the

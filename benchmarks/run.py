@@ -755,6 +755,10 @@ def main(argv=None) -> int:
                          "BENCH_serving.json at the repo root), preserving "
                          "the recorded baseline block")
     args = ap.parse_args(argv)
+    from repro.launch.runtime import check_platform, enable_compile_cache
+
+    check_platform()
+    enable_compile_cache()
     _register()
     names = list(BENCHES) if not args.only else args.only.split(",")
     failures = []

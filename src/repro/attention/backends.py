@@ -99,7 +99,8 @@ def _run_paged(q, call, *, q_pos, k_pos, cache, page_table, stage3):
         draft=call.draft, per_query=call.verify,
         fk_pool=cache.get("f_scout"),
         k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-        kv_scale=getattr(call, "kv_scale", "grid"))
+        kv_scale=getattr(call, "kv_scale", "grid"),
+        layer=cache.get("layer"))
     return out, normalize_stats(st)
 
 

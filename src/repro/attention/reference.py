@@ -54,9 +54,15 @@ def _densify(cache, page_table, int_bits=4):
     ``int_bits`` is the pool grid of a quantized cache (ignored for fp32
     pools): it fixes the static scale of the derived integer scout view.
     """
+    from repro.models.attention import layer_view
+    cache = layer_view(cache)          # a layer-stacked pool -> its layer
     kp, vp = cache["k_pages"], cache["v_pages"]
     B, nP = page_table.shape
-    ps, N, hd = kp.shape[1], kp.shape[2], kp.shape[3]
+    N, ps, hd = kp.shape[1], kp.shape[2], kp.shape[3]
+
+    def pages(pool):  # head-major [P, N, ps, hd] -> [B, nP, ps, N, hd]
+        return jnp.swapaxes(pool[page_table], 2, 3)
+
     if kp.dtype == jnp.int8:
         # quantized pool: dequantize through the per-page scales (poison
         # sentinel -> NaN, exactly like the production stage 3) and
@@ -65,18 +71,18 @@ def _densify(cache, page_table, int_bits=4):
         from repro.core.quant import decode_pool, pool_view_finite
         ks = cache["k_scale"][page_table][:, :, None, :, None]
         vs = cache["v_scale"][page_table][:, :, None, :, None]
-        k = decode_pool(kp[page_table], ks).reshape(B, nP * ps, N, hd)
-        vg = vp[page_table]
+        k = decode_pool(pages(kp), ks).reshape(B, nP * ps, N, hd)
+        vg = pages(vp)
         v = (vg.astype(F32) * vs if vg.dtype != jnp.int8
              else decode_pool(vg, vs)).reshape(B, nP * ps, N, hd)
-        ik = jnp.trunc(pool_view_finite(kp[page_table], int_bits).reshape(
+        ik = jnp.trunc(pool_view_finite(pages(kp), int_bits).reshape(
             B, nP * ps, N, hd))
         return k, v, ik
-    k = kp[page_table].reshape(B, nP * ps, N, hd)
-    v = vp[page_table].reshape(B, nP * ps, N, hd)
+    k = pages(kp).reshape(B, nP * ps, N, hd)
+    v = pages(vp).reshape(B, nP * ps, N, hd)
     ik = None
     if "k_scout" in cache:
-        ik = cache["k_scout"][page_table].reshape(B, nP * ps, N, hd).astype(F32)
+        ik = pages(cache["k_scout"]).reshape(B, nP * ps, N, hd).astype(F32)
     return k, v, ik
 
 

@@ -141,9 +141,6 @@ def effective_policy(spec: AttnSpec) -> str:
             else "static")
 
 
-_COST_WARNED = False
-
-
 def resolve_backend(call: AttnCall, spec: Optional[AttnSpec] = None, *,
                     sig=None, tuner=None) -> Backend:
     """Pick the backend serving ``call`` under ``spec`` (static logic).
@@ -169,22 +166,10 @@ def resolve_backend(call: AttnCall, spec: Optional[AttnSpec] = None, *,
         # only pin the layout; explicit non-auto requests still win
         req = os.environ.get(BACKEND_ENV, "auto")
     if req == "auto" and sig is not None and effective_policy(spec) == "cost":
-        try:
-            if tuner is None:
-                from repro.autotune.tuner import default_tuner
-                tuner = default_tuner()
-            return tuner.choose(call, sig, cands)
-        except Exception:
-            # never let a cost-model bug change dispatch correctness —
-            # degrade to the static order, warn once per process
-            global _COST_WARNED
-            if not _COST_WARNED:
-                _COST_WARNED = True
-                import warnings
-                warnings.warn("cost-policy backend selection failed; "
-                              "falling back to static priority order",
-                              RuntimeWarning, stacklevel=2)
-            return best(cands)
+        if tuner is None:
+            from repro.autotune.tuner import default_tuner
+            tuner = default_tuner()
+        return tuner.choose(call, sig, cands)
     if req != "auto":
         known = {n for b in _REGISTRY.values() for n in (b.name, *b.tags)}
         if req not in known:

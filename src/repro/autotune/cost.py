@@ -112,7 +112,7 @@ def call_signature(call, q, k=None, cache=None, page_table=None,
     """
     B, N, G, Sq, hd = q.shape
     if call.layout == "paged":
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[-2]
         kv = page_table.shape[1] * ps
         kv_item = cache["k_pages"].dtype.itemsize
     else:

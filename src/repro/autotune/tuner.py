@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.autotune.cost import (CallSig, CostEstimate, SparsityEstimate,
                                  predict)
+from repro.common.transient import is_transient
 from repro.roofline.hardware import (HardwareProfile, detect_profile,
                                      get_profile)
 
@@ -149,10 +150,14 @@ class Tuner:
         for key, (call, sig, names) in list(self.pending.items()):
             try:
                 winner = self._probe(call, sig, names)
-            except Exception:
-                # a probe failure must never take serving down; keep the
-                # predicted decision and stop re-trying this signature
+            except Exception as e:
+                # a transient probe failure (pool pressure, a timeout)
+                # keeps the predicted decision and stops re-trying this
+                # signature; anything else — a kernel the compiler
+                # refuses, a shape bug — is a real fault and surfaces
                 del self.pending[key]
+                if not is_transient(e):
+                    raise
                 continue
             del self.pending[key]
             self.measured[key] = winner
@@ -246,7 +251,7 @@ def _synthetic_inputs(call, sig: CallSig):
     """(q, k, v, cache, table, q_pos, k_pos) matching ``sig``'s shapes.
 
     Mirrors the serving layout contracts: paged pools are the per-call
-    [P, ps, N, hd] views with page 0 as scratch and tables pointing at
+    head-major [P, N, ps, hd] views with page 0 as scratch and tables pointing at
     pages 1..; per-slot position arrays carry the batch dim with -1
     marking invalid columns.
     """
@@ -279,22 +284,20 @@ def _synthetic_inputs(call, sig: CallSig):
     ps = sig.page_size
     n_pages = kv // ps
     P = B * n_pages + 1                     # + scratch page 0
-    k_pages = np.zeros((P, ps, N, hd), np.float32)
-    v_pages = np.zeros((P, ps, N, hd), np.float32)
-    k_pages[1:] = k_host.reshape(B * n_pages, ps, N, hd)
-    v_pages[1:] = v_host.reshape(B * n_pages, ps, N, hd)
-    cache = {"k_pages": jnp.asarray(k_pages),
-             "v_pages": jnp.asarray(v_pages)}
+    def pool(x, dtype):   # [B, kv, N, hd] -> [P, N, ps, hd], page 0 zero
+        pages = np.zeros((P, N, ps, hd), dtype)
+        pages[1:] = np.asarray(x).reshape(
+            B * n_pages, ps, N, hd).transpose(0, 2, 1, 3)
+        return jnp.asarray(pages)
+
+    cache = {"k_pages": pool(k_host, np.float32),
+             "v_pages": pool(v_host, np.float32)}
     if call.hdp is not None:
-        scout = scout_int8(jnp.asarray(k_host), call.hdp)
-        sc = np.zeros((P, ps, N, hd), np.int8)
-        sc[1:] = np.asarray(scout).reshape(B * n_pages, ps, N, hd)
-        cache["k_scout"] = jnp.asarray(sc)
+        cache["k_scout"] = pool(scout_int8(jnp.asarray(k_host), call.hdp),
+                                np.int8)
         if call.draft is not None and call.draft.scores == "scout":
-            frac = scout_frac_int8(jnp.asarray(k_host), call.hdp)
-            fc = np.zeros((P, ps, N, hd), np.int8)
-            fc[1:] = np.asarray(frac).reshape(B * n_pages, ps, N, hd)
-            cache["f_scout"] = jnp.asarray(fc)
+            cache["f_scout"] = pool(
+                scout_frac_int8(jnp.asarray(k_host), call.hdp), np.int8)
     table = jnp.arange(1, B * n_pages + 1,
                        dtype=jnp.int32).reshape(B, n_pages)
     return q, None, None, cache, table, q_pos, k_pos

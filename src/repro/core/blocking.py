@@ -58,6 +58,11 @@ def row_threshold(
 
     theta: [..., R, C]; valid: optional bool [..., R, C] marking blocks that
     participate in the statistics. Returns [..., R, 1].
+
+    Both branches are convex combinations bounded by the row max, but in
+    floating point they can round one ULP above it — a causal row with a
+    single valid block would then prune its only block. The threshold is
+    clamped to the row max so the strongest valid block always survives.
     """
     rho = jnp.asarray(rho_b, theta.dtype)
     if valid is None:
@@ -73,7 +78,7 @@ def row_threshold(
         tmean = jnp.where(valid, theta, 0.0).sum(axis=-1, keepdims=True) / cnt
     pos = rho * tmax + (1.0 - rho) * tmean
     neg = -rho * tmin + (1.0 + rho) * tmean
-    return jnp.where(rho >= 0, pos, neg)
+    return jnp.minimum(jnp.where(rho >= 0, pos, neg), tmax)
 
 
 def block_keep_mask(

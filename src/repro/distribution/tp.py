@@ -37,10 +37,10 @@ import jax
 _ctx = threading.local()
 
 #: head (sharded) axis index of each pool leaf in the FULL pool
-#: [L, P, ps, N, hd] / scales [L, P, N]; per-layer views drop the
+#: [L, P, N, ps, hd] / scales [L, P, N]; per-layer views drop the
 #: leading L. Scout views mirror the page layout.
 POOL_HEAD_AXIS = {
-    "k_pages": 3, "v_pages": 3, "k_scout": 3, "f_scout": 3,
+    "k_pages": 2, "v_pages": 2, "k_scout": 2, "f_scout": 2,
     "k_scale": 2, "v_scale": 2,
 }
 
@@ -113,15 +113,14 @@ def tp_paged_attention(q, call, spec, *, q_pos, k_pos, cache, page_table,
     """Head-sharded paged-decode attention under ``mesh``.
 
     ``q`` [B,N,G,Sq,hd] with N the KV-head axis; ``cache`` is the
-    per-layer pool view (pages [P,ps,N,hd], scales [P,N]). Each model
+    per-layer pool view (pages [P,N,ps,hd], scales [P,N]) or the
+    layer-stacked pool with its ``"layer"`` index (replicated). Each model
     shard runs the registry dispatch on its local head slice — the
     scout, keep mask, page gather, and stage-3 kernel all see
     N/tp heads and a per-shard fetched set. Returns the full-width
     ``(out, stats)`` with ``out`` constrained replicated (exact
     all-gather concat over heads, no float reduction).
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.attention.registry import attention
     from repro.attention.stats import AttnStats
 
@@ -132,7 +131,9 @@ def tp_paged_attention(q, call, spec, *, q_pos, k_pos, cache, page_table,
                          k_pos=k_pos, cache=cache, page_table=page_table)
 
     q_spec = _pspec(None, "model")
-    cache_specs = {name: pool_pspec(name, per_layer=True) for name in cache}
+    stacked = "layer" in cache    # layer-stacked pool + its layer index
+    cache_specs = {name: pool_pspec(name, per_layer=not stacked)
+                   for name in cache}
 
     def body(q_l, cache_l, table, qp, kp):
         out, stats = attention(q_l, None, None, call, spec=spec, q_pos=qp,
@@ -160,10 +161,10 @@ def tp_paged_attention(q, call, spec, *, q_pos, k_pos, cache, page_table,
         q, cache, page_table, q_pos, k_pos)
     out_specs = (q_spec, jax.tree.map(lambda _: _pspec(), out_shape[1]))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, cache_specs, _pspec(), _pspec(), _pspec()),
-        out_specs=out_specs, check_rep=False)
+        out_specs=out_specs, check_vma=False)
     out, stats = sharded(q, cache, page_table, q_pos, k_pos)
     # exact all-gather of the head-sharded output before the o-projection:
     # every shard then computes the (replicated) wo einsum on full width

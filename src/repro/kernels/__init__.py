@@ -1,8 +1,8 @@
 """Pallas TPU kernels for the HDP hot spots + dense baseline.
 
-Validated in interpret mode on CPU; compiled natively on TPU.
+Compiled natively on TPU; off-TPU (tests with ``JAX_PLATFORMS=cpu``) they
+run in Pallas interpret mode.
 """
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.ops import flash, hdp_attention_tpu
 
-__all__ = ["flash", "hdp_attention_tpu", "tpu_compiler_params"]
+__all__ = ["flash", "hdp_attention_tpu"]

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 F32 = jnp.float32
 NEG = -1e30
@@ -99,7 +98,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, 1), F32),
             pltpu.VMEM((block_q, 1), F32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 F32 = jnp.float32
 NEG = -1e30
@@ -145,7 +144,7 @@ def hdp_block_sparse_attention(q, k, v, kv_idx, counts, head_kept, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, sqp, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(idx, cnt, hk, ss, lens, qp, kp, vp)

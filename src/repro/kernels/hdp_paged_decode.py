@@ -42,7 +42,6 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.quant import POISON_CODE, int_frac_split, quantize_fixed
-from repro.kernels.compat import tpu_compiler_params
 
 F32 = jnp.float32
 NEG = -1e30
@@ -65,22 +64,21 @@ def _kernel(pid_ref, logical_ref, cnt_ref, len_ref,   # scalar prefetch
 
     @pl.when(j < cnt_ref[b])
     def _body():
-        rows = q_ref.shape[2] * q_ref.shape[3]        # G * Sq
-        q = q_ref[0, 0].reshape(rows, -1).astype(F32)  # [G*Sq, hd] fixed grid
+        q = q_ref[0, 0].astype(F32)                   # [G*Sq, hd] fixed grid
         if kscl_ref is None:
             # fp32 pool: snap the full-precision page to the write-time
             # scout's grid on the VPU (the shared core.quant ops are
             # plain jnp — safe here)
-            k = k_ref[0, :, 0].astype(F32)            # [ps, hd] pool page
+            k = k_ref[0, 0, 0].astype(F32)            # [ps, hd] pool page
             kq = quantize_fixed(k, int_bits, frac_bits)
-            v = v_ref[0, :, 0]
+            v = v_ref[0, 0, 0]
         else:
             # int8 pool: dequantize in register from the prefetched
             # per-page scale — decoded values already sit on the grid
-            kc = k_ref[0, :, 0]                       # [ps, hd] int8 codes
+            kc = k_ref[0, 0, 0].astype(jnp.int32)     # [ps, hd] int8 codes
             ks = kscl_ref[pid_ref[b, j], n]
             kq = jnp.where(kc == POISON_CODE, jnp.nan, kc.astype(F32)) * ks
-            v = v_ref[0, :, 0].astype(F32) * vscl_ref[pid_ref[b, j], n]
+            v = v_ref[0, 0, 0].astype(F32) * vscl_ref[pid_ref[b, j], n]
         s = jax.lax.dot_general(q, kq, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32)
         if approx:
@@ -95,7 +93,7 @@ def _kernel(pid_ref, logical_ref, cnt_ref, len_ref,   # scalar prefetch
         # row r (query index r % Sq) extends the base length by r % Sq
         sq_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) % n_q
         valid = cols < (len_ref[b] + sq_idx)
-        valid = valid & (keep_ref[0, 0, 0].reshape(rows) > 0)[:, None]
+        valid = valid & (keep_ref[0, 0, 0] > 0)      # [G*Sq, 1] row keep
         s = jnp.where(valid, s, NEG)
 
         m_prev = m_ref[...]
@@ -113,8 +111,7 @@ def _kernel(pid_ref, logical_ref, cnt_ref, len_ref,   # scalar prefetch
     @pl.when(j == max_keep - 1)
     def _fin():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).reshape(o_ref.shape[2:]).astype(
-            o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -122,80 +119,99 @@ def _kernel(pid_ref, logical_ref, cnt_ref, len_ref,   # scalar prefetch
 def hdp_paged_fum_decode(qq, k_pool, v_pool, page_ids, logical, counts,
                          keep, kv_len, *, approx: bool = True,
                          int_bits: int = 4, frac_bits: int = 12,
-                         k_scale=None, v_scale=None,
+                         k_scale=None, v_scale=None, layer=None,
                          interpret: bool = False):
     """qq [B,N,G,Sq,hd] fixed-grid queries (Sq = 1 for plain decode, > 1
-    for the speculative multi-query verify); k/v_pool [P,ps,N,hd] page
-    pools; page_ids/logical [B,mk] int32 (pool id / slot position of each
-    kept page — the union over query rows, scratch-0-padded past counts);
+    for the speculative multi-query verify); k/v_pool [P,N,ps,hd]
+    head-major page pools, or the layer-stacked [L,P,N,ps,hd] pools with
+    ``layer`` the int32 layer to read (the pool then reaches the kernel
+    as the serving loop carries it — no per-layer slice is copied);
+    page_ids/logical [B,mk] int32 (pool id / slot position of each kept
+    page — the union over query rows, scratch-0-padded past counts);
     counts [B] int32 kept pages per row; keep [B,mk,N,G,Sq] int32
     per-query-row keep; kv_len [B] int32 valid KV extent of query row 0
     (row j's extent is kv_len + j: verify rows are consecutive
-    positions). ``k_scale``/``v_scale`` [P,N] fp32 mark a quantized pool
-    (int8 codes + per-page scales, dequantized in register from scalar
-    prefetch). Returns [B,N,G,Sq,hd] (head gate applied by the caller).
-    Pages absent from ``page_ids`` are never read.
+    positions). ``k_scale``/``v_scale`` [P,N] fp32 (this layer's) mark a
+    quantized pool (int8 codes + per-page scales, dequantized in register
+    from scalar prefetch). Returns [B,N,G,Sq,hd] (head gate applied by
+    the caller). Pages absent from ``page_ids`` are never read.
     """
     B, N, G, Sq, hd = qq.shape
-    _, ps, _, _ = k_pool.shape
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    ps = k_pool.shape[3]
     mk = page_ids.shape[1]
+    rows = G * Sq
     quantized = k_scale is not None
     base = functools.partial(
         _kernel, scale=1.0 / (hd ** 0.5), approx=approx, int_bits=int_bits,
         frac_bits=frac_bits, ps=ps, max_keep=mk, n_q=Sq)
 
-    # scalar-prefetch operands: the page lists driving the BlockSpec
-    # index maps, plus (quantized pools) the per-page scales the kernel
-    # body reads at dequant time. Prefetch refs arrive positionally ahead
-    # of the tensor refs, so the quantized wrapper peels the two scale
-    # refs off into the keyword slots; the index-map lambdas take one ref
-    # per prefetch operand after the grid indices.
+    # scalar-prefetch operands: the page lists and the layer index driving
+    # the BlockSpec index maps, plus (quantized pools) the per-page scales
+    # the kernel body reads at dequant time. Prefetch refs arrive
+    # positionally ahead of the tensor refs, so the wrapper drops the
+    # layer ref (only the index maps read it) and peels the two scale refs
+    # off into the keyword slots; the index-map lambdas take one ref per
+    # prefetch operand after the grid indices.
     if quantized:
-        n_pref = 6
+        n_pref = 7
 
-        def kernel(pid, lg, c, le, ks, vs, *refs):
+        def kernel(pid, lg, c, le, lay, ks, vs, *refs):
             return base(pid, lg, c, le, *refs, kscl_ref=ks, vscl_ref=vs)
 
         def imap(fn):
-            return lambda b, n, j, pid, lg, c, le, ks, vs: fn(b, n, j, pid)
+            return lambda b, n, j, pid, lg, c, le, lay, ks, vs: fn(
+                b, n, j, pid, lay)
     else:
-        n_pref = 4
-        kernel = base
+        n_pref = 5
+
+        def kernel(pid, lg, c, le, lay, *refs):
+            return base(pid, lg, c, le, *refs)
 
         def imap(fn):
-            return lambda b, n, j, pid, lg, c, le: fn(b, n, j, pid)
+            return lambda b, n, j, pid, lg, c, le, lay: fn(b, n, j, pid, lay)
 
+    # TPU blocks need their last two dims (8, 128)-aligned or whole, so
+    # every operand keeps a whole trailing tile: the G*Sq query rows of a
+    # head share one [rows, hd] tile, and the head-major pool's [ps, hd]
+    # page of one head is itself a whole tile — the pool reaches the
+    # kernel as stored (no relayout copy), and the DMA moves exactly that
+    # head's slice of each surviving page.
+    page = pl.BlockSpec(
+        (1, 1, 1, ps, hd),
+        imap(lambda b, n, j, pid, lay: (lay[0], pid[b, j], n, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,
         grid=(B, N, mk),
         in_specs=[
-            pl.BlockSpec((1, 1, G, Sq, hd),
-                         imap(lambda b, n, j, pid: (b, n, 0, 0, 0))),
-            pl.BlockSpec((1, ps, 1, hd),
-                         imap(lambda b, n, j, pid: (pid[b, j], 0, n, 0))),
-            pl.BlockSpec((1, ps, 1, hd),
-                         imap(lambda b, n, j, pid: (pid[b, j], 0, n, 0))),
-            pl.BlockSpec((1, 1, 1, G, Sq),
-                         imap(lambda b, n, j, pid: (b, j, n, 0, 0))),
+            pl.BlockSpec((1, 1, rows, hd),
+                         imap(lambda b, n, j, pid, lay: (b, n, 0, 0))),
+            page,
+            page,
+            pl.BlockSpec((1, 1, 1, rows, 1),
+                         imap(lambda b, n, j, pid, lay: (b, j, n, 0, 0))),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Sq, hd),
-                               imap(lambda b, n, j, pid: (b, n, 0, 0, 0))),
+        out_specs=pl.BlockSpec((1, 1, rows, hd),
+                               imap(lambda b, n, j, pid, lay: (b, n, 0, 0))),
         scratch_shapes=[
-            pltpu.VMEM((G * Sq, hd), F32),
-            pltpu.VMEM((G * Sq, 1), F32),
-            pltpu.VMEM((G * Sq, 1), F32),
+            pltpu.VMEM((rows, hd), F32),
+            pltpu.VMEM((rows, 1), F32),
+            pltpu.VMEM((rows, 1), F32),
         ],
     )
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, N, G, Sq, hd), qq.dtype),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((B, N, rows, hd), qq.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )
+    pref = (page_ids, logical, counts, kv_len,
+            jnp.asarray(layer, jnp.int32).reshape(1))
     if quantized:
-        return call(page_ids, logical, counts, kv_len,
-                    k_scale.astype(F32), v_scale.astype(F32),
-                    qq, k_pool, v_pool, keep)
-    return call(page_ids, logical, counts, kv_len, qq, k_pool, v_pool, keep)
+        pref += (k_scale.astype(F32), v_scale.astype(F32))
+    out = call(*pref, qq.reshape(B, N, rows, hd), k_pool, v_pool,
+               keep.reshape(B, mk, N, rows, 1))
+    return out.reshape(B, N, G, Sq, hd)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 
 F32 = jnp.float32
 BIG = 1e30
@@ -44,29 +43,39 @@ def _kernel(iq_ref, ik_ref, theta_ref, mask_ref, trow_ref,
     if causal:
         valid = valid & (rows >= cols)
     s = jnp.where(valid, jnp.abs(s), 0.0)
-    theta_chunk = s.reshape(block_q, ck, bk).sum(axis=(0, 2))  # [ck]
-    trow_ref[0, pl.ds(j * ck, ck)] = theta_chunk
+    # pool each block's |scores| and place it in the theta row with a
+    # lane select: block starts are 128-lane aligned at real widths, and
+    # the row is only ever written whole (no dynamic lane-offset store)
+    lane = jax.lax.broadcasted_iota(jnp.int32, trow_ref.shape, 1)
+    trow = trow_ref[...]                                   # [1, nk_pad]
+    for c in range(ck):
+        blk = s[:, c * bk:(c + 1) * bk].sum(axis=0, keepdims=True)
+        blk = blk.sum(axis=1, keepdims=True)               # [1, 1]
+        trow = jnp.where(lane == j * ck + c, blk, trow)
+    trow_ref[...] = trow
 
     # ---- Sparsity Engine: threshold + mask once the row is complete ----
     @pl.when(j == nkc - 1)
     def _finish():
-        trow = trow_ref[0, :]                                  # [nk_pad]
-        bcols = jax.lax.iota(jnp.int32, trow.shape[0]) * bk    # block start
-        bvalid = bcols < sk_true
+        bidx = lane                                            # block index
+        bvalid = (bidx * bk < sk_true) & (bidx < nk)
         if causal:
-            bvalid = bvalid & (bcols <= i * block_q + block_q - 1)
-        bvalid = bvalid & (jax.lax.iota(jnp.int32, trow.shape[0]) < nk)
-        cnt = jnp.maximum(bvalid.sum().astype(F32), 1.0)
-        tmin = jnp.where(bvalid, trow, BIG).min()
-        tmax = jnp.where(bvalid, trow, -BIG).max()
-        tmean = jnp.where(bvalid, trow, 0.0).sum() / cnt
+            bvalid = bvalid & (bidx * bk <= i * block_q + block_q - 1)
+        cnt = jnp.maximum(
+            bvalid.astype(F32).sum(axis=1, keepdims=True), 1.0)
+        tmin = jnp.where(bvalid, trow, BIG).min(axis=1, keepdims=True)
+        tmax = jnp.where(bvalid, trow, -BIG).max(axis=1, keepdims=True)
+        tmean = jnp.where(bvalid, trow, 0.0).sum(axis=1, keepdims=True) / cnt
+        # same f32 arithmetic and row-max clamp as blocking.row_threshold
+        rho = jnp.float32(rho_b)
         if rho_b >= 0:
-            thr = rho_b * tmax + (1.0 - rho_b) * tmean
+            thr = rho * tmax + (1.0 - rho) * tmean
         else:
-            thr = -rho_b * tmin + (1.0 + rho_b) * tmean
+            thr = -rho * tmin + (1.0 + rho) * tmean
+        thr = jnp.minimum(thr, tmax)
         keep = (trow >= thr) & bvalid
-        theta_ref[0, 0, :] = jnp.where(bvalid, trow, 0.0)
-        mask_ref[0, 0, :] = keep.astype(jnp.int32)
+        theta_ref[0, 0] = jnp.where(bvalid, trow, 0.0)
+        mask_ref[0, 0] = keep.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("rho_b", "block_q", "block_k",
@@ -105,21 +114,23 @@ def hdp_scout(iq, ik, *, rho_b: float, block_q: int = 128,
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, ck * block_k, hd), lambda b, i, j: (b, j, 0)),
         ],
+        # one whole [1, nk_pad] row per (head, q tile): the trailing
+        # singleton keeps the block's last two dims equal to the array's
         out_specs=[
-            pl.BlockSpec((1, 1, nk_pad), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 1, nk_pad), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, 1, nk_pad), lambda b, i, j: (b, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, nk_pad), lambda b, i, j: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, nq, nk_pad), F32),
-            jax.ShapeDtypeStruct((B * H, nq, nk_pad), jnp.int32),
+            jax.ShapeDtypeStruct((B * H, nq, 1, nk_pad), F32),
+            jax.ShapeDtypeStruct((B * H, nq, 1, nk_pad), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((1, nk_pad), F32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(iqp, ikp)
 
-    theta = theta[:, :, :nk].reshape(B, H, nq, nk)
-    keep = mask[:, :, :nk].reshape(B, H, nq, nk) > 0
+    theta = theta[:, :, 0, :nk].reshape(B, H, nq, nk)
+    keep = mask[:, :, 0, :nk].reshape(B, H, nq, nk) > 0
     theta_head = theta.sum((-2, -1))
     return theta, keep, theta_head

@@ -13,6 +13,16 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices) -> jax.sharding.Mesh:
+    """Mesh whose axes are all ``Auto``: the serving and training code
+    places arrays with ``NamedSharding`` + ``with_sharding_constraint``
+    and lets the partitioner propagate, which JAX's default ``Explicit``
+    axis types refuse outside a ``jax.set_mesh`` context."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -25,7 +35,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"mesh {shape} needs {need} devices, have {len(devs)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={need} before any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return _auto_mesh(shape, axes, devs[:need])
 
 
 def make_serving_mesh(tp: int = 1, dp: int = 1,
@@ -49,7 +59,7 @@ def make_serving_mesh(tp: int = 1, dp: int = 1,
             f"{len(devs)} — on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             "before any jax import")
-    return jax.make_mesh((dp, tp), ("data", "model"), devices=devs[:need])
+    return _auto_mesh((dp, tp), ("data", "model"), devs[:need])
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
@@ -57,5 +67,5 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     model = max(1, min(model, n))
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[: data * model])
+    return _auto_mesh((data, model), ("data", "model"),
+                      jax.devices()[: data * model])

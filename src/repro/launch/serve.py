@@ -3,6 +3,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
         --reduced --requests 16 --max-new 8
 
+``--full`` serves the published widths (on a TPU; ``--max-len`` sets the
+context, and the prefill buckets and prompt lengths follow the page size
+and ``--max-len``). With ``JAX_PLATFORMS=cpu`` it runs on the CPU, Pallas
+kernels in interpret mode; without it a TPU is required when the TPU
+runtime is installed.
+
 Drives `serving.Engine` (continuous batching, per-slot positions, HDP
 prefill/decode) with synthetic prompts and reports throughput + achieved
 HDP sparsity. `--no-hdp` serves the identical model with dense attention
@@ -24,8 +30,10 @@ import numpy as np
 from repro.attention import AttnSpec, spec_from_legacy
 from repro.configs import get_config
 from repro.configs.base import reduced
+from repro.launch.runtime import check_platform, enable_compile_cache
 from repro.serving import Engine, ReplicaSet, Request, SchedulerConfig
 from repro.serving.engine import MESH_DP_ENV
+from repro.serving.kv_cache import default_page_size
 
 log = logging.getLogger("repro.serve")
 
@@ -183,6 +191,26 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def prefill_buckets(page_size: int, max_len: int) -> tuple:
+    """Prefill pad-to lengths: the power-of-two multiples of the page size
+    from 16 tokens up to ``max_len`` (just ``max_len`` when no page fits).
+    Page-aligned buckets keep HDP's blocks whole, and a prompt longer than
+    the largest bucket prefills in chunks of it."""
+    b = page_size
+    while b < 16:
+        b *= 2
+    out = []
+    while b <= max_len:
+        out.append(b)
+        b *= 2
+    return tuple(out) or (max_len,)
+
+
+def prompt_cap(page_size: int, max_len: int) -> int:
+    """Longest synthetic prompt: half the context, in whole pages."""
+    return max(page_size, max_len // 2 // page_size * page_size)
+
+
 def run(args) -> dict:
     cfg = get_config(args.arch)
     if args.reduced:
@@ -222,8 +250,9 @@ def run(args) -> dict:
     if dp is None:
         dp = int(os.environ.get(MESH_DP_ENV) or 1)
     dp = max(int(dp), 1)
+    ps = default_page_size(cfg)
     engine_kw = dict(max_batch=args.max_batch, max_len=args.max_len,
-                     prefill_buckets=(16, 32, 64),
+                     prefill_buckets=prefill_buckets(ps, args.max_len),
                      collect_stats=not args.no_hdp, attn=spec,
                      prefix_cache=args.prefix_cache,
                      decode_horizon=args.decode_horizon,
@@ -269,7 +298,8 @@ def run(args) -> dict:
                           size=args.shared_prefix).tolist()
     prompts = []
     for uid in range(args.requests):
-        hi = min(48, args.max_len - args.max_new - args.shared_prefix)
+        hi = min(prompt_cap(ps, args.max_len),
+                 args.max_len - args.max_new - args.shared_prefix)
         plen = int(rng.integers(4, max(hi, 5)))
         prompts.append(shared
                        + rng.integers(1, cfg.vocab_size, size=plen).tolist())
@@ -445,6 +475,8 @@ def main(argv=None) -> int:
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
     args = build_parser().parse_args(argv)
+    check_platform()
+    enable_compile_cache()
     out = run(args)
     if out.get("fault_plan"):
         # under injected faults some requests fail BY DESIGN — success is

@@ -428,6 +428,48 @@ def scout_frac_int8(k, hdp: HDPConfig):
     return scout_frac_codes(k, hdp.int_bits, hdp.frac_bits)
 
 
+def gather_pages(pool, idx, layer=None):
+    """Pages ``idx`` of a head-major [P, N, ps, hd] pool, token-major:
+    [..., ps, N, hd]. The pool is stored head-major so one head's page is
+    a whole [ps, hd] tile the Pallas kernel DMAs as stored; the XLA paths
+    read the same pages through this view. With ``layer`` the pool is the
+    layer-stacked [L, P, N, ps, hd] one, read in a single gather (no
+    per-layer slice is materialized)."""
+    g = pool[idx] if layer is None else pool[layer, idx]
+    return jnp.swapaxes(g, -3, -2)
+
+
+def write_pages(pool, pidx, off, rows, layer=None):
+    """Write token rows [B, S, N, hd] into a head-major pool: row (b, s)
+    lands on page ``pidx[b, s]`` at offset ``off[b, s]``, every kv head.
+
+    One dynamic-update-slice per row: it updates the pool in place in its
+    own tiled layout, where a scatter makes XLA relayout the whole pool
+    on TPU (twice: to the scatter's tiling and back). ``layer`` selects
+    the layer of a layer-stacked [L, P, N, ps, hd] pool. Rows redirected
+    to the same scratch position overwrite each other in row order."""
+    B, S = pidx.shape
+    rows = rows.astype(pool.dtype)
+    for b in range(B):
+        for s in range(S):
+            row = rows[b, s][None, :, None, :]          # [1, N, 1, hd]
+            start = (pidx[b, s], 0, off[b, s], 0)
+            if layer is not None:
+                row, start = row[None], (layer,) + start
+            pool = jax.lax.dynamic_update_slice(pool, row, start)
+    return pool
+
+
+def layer_view(cache):
+    """Per-layer view of a paged cache: a layer-stacked cache (one that
+    carries its ``"layer"`` index) sliced to that layer, any other
+    returned as is."""
+    if cache is None or "layer" not in cache:
+        return cache
+    li = cache["layer"]
+    return {k: v[li] for k, v in cache.items() if k != "layer"}
+
+
 def _dequant_pages(pages, scale):
     """Gathered pool pages [..., ps, N, hd] + per-page scales [..., N]
     -> fp32 values; the POISON_CODE sentinel (int8 pools) and a NaN page
@@ -463,7 +505,8 @@ def resolve_write_pages(positions, page_table, page_size, write_floor=None):
 
 def _paged_scan_attention(qq, fq, k_pool, v_pool, gather_idx, keep, valid,
                           head_kept, *, hdp: HDPConfig, ps: int, cpp: int,
-                          scale: float, k_scale=None, v_scale=None):
+                          scale: float, k_scale=None, v_scale=None,
+                          layer=None):
     """Stage 2+3 as an online-softmax scan over page chunks.
 
     Peak stage-2 memory is O(B * cpp * ps) — one chunk of gathered pages —
@@ -498,13 +541,13 @@ def _paged_scan_attention(qq, fq, k_pool, v_pool, gather_idx, keep, valid,
         m, l, acc = carry
         idx_i, keep_i, valid_i = xs
         if k_scale is not None:
-            k_i = _dequant_pages(k_pool[idx_i], k_scale[idx_i])
-            v_i = _dequant_pages(v_pool[idx_i], v_scale[idx_i])
+            k_i = _dequant_pages(gather_pages(k_pool, idx_i, layer), k_scale[idx_i])
+            v_i = _dequant_pages(gather_pages(v_pool, idx_i, layer), v_scale[idx_i])
             k_i = k_i.reshape(B, cpp * ps, N, hd)
             v_i = v_i.reshape(B, cpp * ps, N, hd)
         else:
-            k_i = k_pool[idx_i].reshape(B, cpp * ps, N, hd)
-            v_i = v_pool[idx_i].reshape(B, cpp * ps, N, hd)
+            k_i = gather_pages(k_pool, idx_i, layer).reshape(B, cpp * ps, N, hd)
+            v_i = gather_pages(v_pool, idx_i, layer).reshape(B, cpp * ps, N, hd)
         kq_i, _, fk_i = _fixed_split(k_i, hdp)
         s = jnp.einsum("bngqh,bsnh->bngqs", qq, kq_i,
                        preferred_element_type=F32)
@@ -531,7 +574,7 @@ def _paged_scan_attention(qq, fq, k_pool, v_pool, gather_idx, keep, valid,
 
 def _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep, head_kept,
                              q_pos, fetched, *, hdp: HDPConfig, ps: int,
-                             k_scale=None, v_scale=None):
+                             k_scale=None, v_scale=None, layer=None):
     """Stage 2+3 through the gather-free Pallas kernel.
 
     Compresses the OR-over-heads (and, for multi-query verify, OR-over-
@@ -570,7 +613,7 @@ def _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep, head_kept,
         qq, k_pool, v_pool, page_ids, logical, counts,
         keep_in, kv_len, approx=hdp.approx, int_bits=hdp.int_bits,
         frac_bits=hdp.frac_bits, k_scale=k_scale, v_scale=v_scale,
-        interpret=_auto_interpret(None))
+        layer=layer, interpret=_auto_interpret(None))
     return _head_gate(out, head_kept)
 
 
@@ -580,12 +623,16 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                stage3: str = "xla", page_chunk: int = 128,
                                draft=None, per_query: bool = False,
                                fk_pool=None, k_scale=None, v_scale=None,
-                               kv_scale: str = "grid"):
+                               kv_scale: str = "grid", layer=None):
     """HDP decode over a block-paged KV cache — the FUM dataflow in XLA.
 
-    q [B,N,G,Sq,hd]; k/v_pool [P,ps,N,hd] page pools (page 0 is the
-    reserved scratch page); ik_pool [P,ps,N,hd] int8 scout copy of K;
-    table [B,nP] int32 page table (0-padded).
+    q [B,N,G,Sq,hd]; k/v_pool [P,N,ps,hd] head-major page pools (page 0
+    is the reserved scratch page); ik_pool [P,N,ps,hd] int8 scout copy
+    of K; table [B,nP] int32 page table (0-padded). With ``layer`` (an
+    int32 scalar) every pool and scale array is the layer-stacked one
+    ([L, ...]) and that layer is read: pages are gathered straight from
+    the stack and the Pallas kernel DMAs from it, so no per-layer copy of
+    the pool is made.
 
     An int8 ``k_pool`` switches on the quantized-pool path:
     ``k_scale``/``v_scale`` [P, N] carry the per-page scales, ``ik_pool``
@@ -636,48 +683,12 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
     surviving pages' V is fetched.
     """
     B, N, G, Sq, hd = q.shape
-    P, ps, _, _ = k_pool.shape
+    ps = k_pool.shape[-2]
     nP = table.shape[1]
     Sk = nP * ps
     scale = 1.0 / (hd ** 0.5)
     quantized = k_pool.dtype == jnp.int8
     absmax = quantized and kv_scale == "absmax"
-
-    # ---- stage 1: integer scout on the always-streamed int8 copy ----
-    if absmax:
-        # calibrated scales: dequantize the scout stream through a
-        # sanitized scale copy — poison codes -> 0 and NaN freed-page
-        # scales -> the static step, preserving the scout-always-finite
-        # contract of the static-grid view
-        codes = k_pool[table]                            # [B,nP,ps,N,hd]
-        ksc = k_scale[table]                             # [B,nP,N]
-        ksc = jnp.where(jnp.isfinite(ksc), ksc, pool_scale(hdp.int_bits))
-        cf = jnp.where(codes == POISON_CODE, 0, codes).astype(F32)
-        k_fin = (cf * ksc[:, :, None, :, None]).reshape(B, Sk, N, hd)
-        ik = jnp.trunc(k_fin)
-    elif quantized:
-        # the pool's codes ARE the scout stream: the finite static-grid
-        # view (poison sentinels -> 0, masked anyway) truncates to the
-        # same integer parts the fp32 pools' write-time copy stored
-        k_fin = pool_view_finite(k_pool[table], hdp.int_bits)
-        k_fin = k_fin.reshape(B, Sk, N, hd)
-        ik = jnp.trunc(k_fin)
-    else:
-        ik = ik_pool[table].reshape(B, Sk, N, hd).astype(F32)
-    qq, iq, fq = _fixed_split(q, hdp)
-    s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik, preferred_element_type=F32)
-    valid = _mask_bias(q_pos, k_pos, hdp.causal, window)
-    keep, bvalid, theta, theta_head, head_kept = decode_scout(
-        s_int, valid, hdp, per_query=per_query)
-
-    # ---- stage 2: fetch-upon-mask page selection ----
-    # page fetch granularity is OR-over-heads (a page holds all kv heads)
-    # and, under multi-query verify, OR-over-query-rows (the pool is read
-    # once per round); the per-head/per-row keep mask still applies inside
-    # the softmax below. Early head-gated heads (output zeroed) don't
-    # demand their pages at all.
-    fetched = (keep & head_kept[..., None]).any(
-        axis=tuple(range(1, keep.ndim - 1)))                  # [B, nP]
 
     if stage3 != "xla" and window:
         # the kernels' per-row validity is an upper bound (cols < kv_len)
@@ -694,6 +705,56 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
         # grid; under calibrated scales that scout would disagree with
         # the one above — fall back rather than fork the keep mask
         stage3 = "xla"
+    if layer is not None:
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
+        if stage3 != "pallas_paged" or (draft is not None
+                                        and draft.scores != "approx"):
+            # the XLA stages read one layer's slice: a gather straight
+            # from the stack lets XLA re-lay the whole carried pool out
+            # for it (hd 80 puts the page's 128 positions in lanes),
+            # copying every layer in and out of the decode program
+            k_pool, v_pool, ik_pool, fk_pool = (
+                None if x is None else x[layer]
+                for x in (k_pool, v_pool, ik_pool, fk_pool))
+            layer = None
+
+    # ---- stage 1: integer scout on the always-streamed int8 copy ----
+    if absmax:
+        # calibrated scales: dequantize the scout stream through a
+        # sanitized scale copy — poison codes -> 0 and NaN freed-page
+        # scales -> the static step, preserving the scout-always-finite
+        # contract of the static-grid view
+        codes = gather_pages(k_pool, table, layer)              # [B,nP,ps,N,hd]
+        ksc = k_scale[table]                             # [B,nP,N]
+        ksc = jnp.where(jnp.isfinite(ksc), ksc, pool_scale(hdp.int_bits))
+        cf = jnp.where(codes == POISON_CODE, 0, codes).astype(F32)
+        k_fin = (cf * ksc[:, :, None, :, None]).reshape(B, Sk, N, hd)
+        ik = jnp.trunc(k_fin)
+    elif quantized:
+        # the pool's codes ARE the scout stream: the finite static-grid
+        # view (poison sentinels -> 0, masked anyway) truncates to the
+        # same integer parts the fp32 pools' write-time copy stored
+        k_fin = pool_view_finite(gather_pages(k_pool, table, layer), hdp.int_bits)
+        k_fin = k_fin.reshape(B, Sk, N, hd)
+        ik = jnp.trunc(k_fin)
+    else:
+        ik = gather_pages(ik_pool, table, layer).reshape(B, Sk, N, hd).astype(F32)
+    qq, iq, fq = _fixed_split(q, hdp)
+    s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik, preferred_element_type=F32)
+    valid = _mask_bias(q_pos, k_pos, hdp.causal, window)
+    keep, bvalid, theta, theta_head, head_kept = decode_scout(
+        s_int, valid, hdp, per_query=per_query)
+
+    # ---- stage 2: fetch-upon-mask page selection ----
+    # page fetch granularity is OR-over-heads (a page holds all kv heads)
+    # and, under multi-query verify, OR-over-query-rows (the pool is read
+    # once per round); the per-head/per-row keep mask still applies inside
+    # the softmax below. Early head-gated heads (output zeroed) don't
+    # demand their pages at all.
+    fetched = (keep & head_kept[..., None]).any(
+        axis=tuple(range(1, keep.ndim - 1)))                  # [B, nP]
+
     if draft is not None and draft.scores != "approx":
         # draft stage 3: scores from the int8 scout copies — s_int alone
         # ("int") or QQ·IK + IQ·FK^ ("scout": the quantized-fraction copy
@@ -717,7 +778,8 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                     "(PagedKVCache(draft_scout=True)); pass fk_pool or "
                     'use scores="int"')
             else:
-                fkh = fk_pool[table].reshape(B, Sk, N, hd).astype(F32) \
+                fkh = gather_pages(fk_pool, table, layer).reshape(
+                    B, Sk, N, hd).astype(F32) \
                     / FRAC_SCOUT_SCALE
             s = s + jnp.einsum("bngqh,bsnh->bngqs", fq, ik,
                                preferred_element_type=F32) \
@@ -725,10 +787,11 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                preferred_element_type=F32)
         gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
         if quantized:
-            v = _dequant_pages(v_pool[gather_idx], v_scale[gather_idx])
+            v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
+                               v_scale[gather_idx])
             v = v.reshape(B, Sk, N, hd)
         else:
-            v = v_pool[gather_idx].reshape(B, Sk, N, hd)
+            v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
         out = _approx_block_attention(None, None, None, None, v, keep, valid,
                                       head_kept, block_k=ps, scale=scale,
                                       approx=False, scores=s)
@@ -737,7 +800,8 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                        head_kept, q_pos, fetched,
                                        hdp=hdp, ps=ps,
                                        k_scale=k_scale if quantized else None,
-                                       v_scale=v_scale if quantized else None)
+                                       v_scale=v_scale if quantized else None,
+                                       layer=layer)
     elif stage3 == "pallas_block":
         from repro.kernels.hdp_block_attn import hdp_block_sparse_attention
         from repro.kernels.ops import _auto_interpret
@@ -745,13 +809,15 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
 
         gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
         if quantized:
-            k = _dequant_pages(k_pool[gather_idx], k_scale[gather_idx])
-            v = _dequant_pages(v_pool[gather_idx], v_scale[gather_idx])
+            k = _dequant_pages(gather_pages(k_pool, gather_idx, layer),
+                               k_scale[gather_idx])
+            v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
+                               v_scale[gather_idx])
             k = k.reshape(B, Sk, N, hd)
             v = v.reshape(B, Sk, N, hd)
         else:
-            k = k_pool[gather_idx].reshape(B, Sk, N, hd)
-            v = v_pool[gather_idx].reshape(B, Sk, N, hd)
+            k = gather_pages(k_pool, gather_idx, layer).reshape(B, Sk, N, hd)
+            v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
         H = N * G
         def per_head(x):  # [B,Sk,N,hd] -> [B,H,Sk,hd]
             xh = jnp.repeat(x.transpose(0, 2, 1, 3), G, axis=1)
@@ -780,13 +846,15 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
             # and reduce exactly like the dense-layout decode (keeps paged
             # and dense engines token-identical on short contexts)
             if quantized:
-                k = _dequant_pages(k_pool[gather_idx], k_scale[gather_idx])
-                v = _dequant_pages(v_pool[gather_idx], v_scale[gather_idx])
+                k = _dequant_pages(gather_pages(k_pool, gather_idx, layer),
+                                   k_scale[gather_idx])
+                v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
+                                   v_scale[gather_idx])
                 k = k.reshape(B, Sk, N, hd)
                 v = v.reshape(B, Sk, N, hd)
             else:
-                k = k_pool[gather_idx].reshape(B, Sk, N, hd)
-                v = v_pool[gather_idx].reshape(B, Sk, N, hd)
+                k = gather_pages(k_pool, gather_idx, layer).reshape(B, Sk, N, hd)
+                v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
             kq, _, fk = _fixed_split(k, hdp)
             out = _approx_block_attention(qq, fq, kq, fk, v, keep, valid,
                                           head_kept, block_k=ps, scale=scale,
@@ -796,7 +864,8 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                         keep, valid, head_kept, hdp=hdp,
                                         ps=ps, cpp=cpp, scale=scale,
                                         k_scale=k_scale if quantized else None,
-                                        v_scale=v_scale if quantized else None)
+                                        v_scale=v_scale if quantized else None,
+                                        layer=layer)
 
     stats = None
     if return_stats:
@@ -940,13 +1009,16 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
                 v = roundtrip_pool(v, ib).astype(v.dtype)
 
         if cache is not None and "k_pages" in cache:
-            # block-paged serving cache (decode only): scatter the S
+            # block-paged serving cache (decode only): write the S
             # tokens' K/V (+ int8 scout copy) into their slots' pages
-            # (S > 1 = speculative verify — one scatter, then one
+            # (S > 1 = speculative verify — one write, then one
             # attention over the pool), then attend through the table.
+            # A layer-stacked cache (it carries its "layer" index) is
+            # written and read in place: no per-layer slice is copied.
             assert mode == "decode" and positions.ndim == 2, \
                 "paged cache is a decode-time serving layout"
-            ps = cache["k_pages"].shape[1]
+            li = cache.get("layer")
+            ps = cache["k_pages"].shape[-2]
             nP = page_table.shape[1]
             pidx = resolve_write_pages(positions, page_table, ps,
                                        write_floor)
@@ -960,13 +1032,14 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
                 # poison back to the static step so the encode is finite
                 ib = pool_int_bits(cfg.hdp)
                 s0 = pool_scale(ib)
-                ks = cache["k_scale"][pidx]                    # [B,S,N]
+                sc = layer_view(cache)
+                ks = sc["k_scale"][pidx]                       # [B,S,N]
                 ks = jnp.where(jnp.isfinite(ks), ks, s0)[..., None]
                 k_store = encode_pool_scaled(k, ks)
                 if cache["v_pages"].dtype != jnp.int8:
                     v_store = v.astype(cache["v_pages"].dtype)
                 else:
-                    vs = cache["v_scale"][pidx]
+                    vs = sc["v_scale"][pidx]
                     vs = jnp.where(jnp.isfinite(vs), vs, s0)[..., None]
                     v_store = encode_pool_scaled(v, vs)
             elif pool_q:
@@ -979,10 +1052,10 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
                 k_store = k.astype(cache["k_pages"].dtype)
                 v_store = v.astype(cache["v_pages"].dtype)
             new_cache = {**cache,
-                         "k_pages": cache["k_pages"].at[pidx, off].set(
-                             k_store),
-                         "v_pages": cache["v_pages"].at[pidx, off].set(
-                             v_store)}
+                         "k_pages": write_pages(cache["k_pages"], pidx, off,
+                                                k_store, li),
+                         "v_pages": write_pages(cache["v_pages"], pidx, off,
+                                                v_store, li)}
             if not pool_q and draft is not None \
                     and draft.scores != "approx" \
                     and cfg.hdp is not None and cfg.hdp.enabled:
@@ -998,11 +1071,12 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
                 # later draft steps stream, so the scatter is live
                 new_cache["k_pages"] = cache["k_pages"]
             if "k_scout" in cache:
-                new_cache["k_scout"] = cache["k_scout"].at[pidx, off].set(
-                    scout_int8(k, cfg.hdp))
+                new_cache["k_scout"] = write_pages(
+                    cache["k_scout"], pidx, off, scout_int8(k, cfg.hdp), li)
             if "f_scout" in cache:
-                new_cache["f_scout"] = cache["f_scout"].at[pidx, off].set(
-                    scout_frac_int8(k, cfg.hdp))
+                new_cache["f_scout"] = write_pages(
+                    cache["f_scout"], pidx, off, scout_frac_int8(k, cfg.hdp),
+                    li)
             ar = jnp.arange(nP * ps)
             k_pos = jnp.where(ar[None, :] <= positions[:, -1:], ar, -1)
             k_pos = k_pos[:, None, None, :]              # [B,1,1,nP*ps]

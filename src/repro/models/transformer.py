@@ -84,20 +84,33 @@ def _stack(cfg, params, x, *, mode, positions, cache, collect_stats,
         x, (stats, aux) = jax.lax.scan(body, x, params["layers"])
         return x, None, stats, aux
 
+    # the paged pool stays layer-stacked: attention writes and reads it
+    # in place at the carried layer index, so no per-layer slice of the
+    # pool is copied out and back (the Pallas decode kernel DMAs only
+    # surviving pages straight from the stack)
+    paged = page_table is not None and "k_pages" in cache
+
     def body(carry, lp):
         y, cache_all, li = carry
-        lc = jax.tree.map(
-            lambda c: jax.lax.dynamic_index_in_dim(c, li, 0, keepdims=False),
-            cache_all)
+        if paged:
+            lc = {**cache_all, "layer": li}
+        else:
+            lc = jax.tree.map(
+                lambda c: jax.lax.dynamic_index_in_dim(c, li, 0,
+                                                       keepdims=False),
+                cache_all)
         y, nc, st, aux = _block(cfg, lp, y, mode=mode, positions=positions,
                                 cache=lc, collect_stats=collect_stats,
                                 page_table=page_table,
                                 write_floor=write_floor, attn=attn,
                                 draft=draft)
-        cache_all = jax.tree.map(
-            lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                c, n.astype(c.dtype), li, 0),
-            cache_all, nc)
+        if paged:
+            cache_all = {k: nc[k] for k in cache_all}
+        else:
+            cache_all = jax.tree.map(
+                lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                    c, n.astype(c.dtype), li, 0),
+                cache_all, nc)
         return (y, cache_all, li + 1), (st, aux)
 
     # no remat here: the cache path is inference-only (no backward), and

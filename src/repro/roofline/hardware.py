@@ -12,6 +12,9 @@ choices, so the constants live here as named profiles and
 ``analysis.py`` keeps re-exporting the TPU v5e numbers under their old
 names (``PEAK_FLOPS`` / ``HBM_BW`` / ``ICI_BW`` / ``HBM_BYTES``): the
 dry-run tables intentionally project the deploy target, not the host.
+
+A device whose ``device_kind`` has no profile is an error, never a
+silent default: peaks of one chip generation would misprice another.
 """
 from __future__ import annotations
 
@@ -65,6 +68,10 @@ HOST_CPU = HardwareProfile(
 
 PROFILES = {p.name: p for p in (TPU_V5E, HOST_CPU)}
 
+#: ``jax.Device.device_kind`` -> profile, for every accelerator whose
+#: published peaks are above (Google Cloud documentation, "TPU v5e").
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
 
 def get_profile(name: str) -> HardwareProfile:
     try:
@@ -75,7 +82,17 @@ def get_profile(name: str) -> HardwareProfile:
 
 
 def detect_profile() -> HardwareProfile:
-    """Profile of the platform JAX actually runs on (TPU else CPU host)."""
+    """Profile of the device JAX actually runs on, keyed on its
+    ``device_kind``; the CPU host profile for CPU devices. An accelerator
+    with no profile raises."""
     import jax
 
-    return TPU_V5E if jax.default_backend() == "tpu" else HOST_CPU
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return HOST_CPU
+    try:
+        return DEVICE_KINDS[dev.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no hardware profile for {dev.platform} device_kind "
+            f"{dev.device_kind!r}; known: {sorted(DEVICE_KINDS)}") from None

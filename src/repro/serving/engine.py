@@ -156,10 +156,51 @@ ADAPTIVE_ENV = "REPRO_ADAPTIVE_SPEC"
 KV_DTYPE_ENV = "REPRO_KV_DTYPE"
 
 #: env var giving the default tensor-parallel degree when ``tp=None`` and
-#: no mesh is passed (explicit kwargs win; the env default degrades
-#: silently — to 1 — for layouts/head-counts/device-counts that cannot
-#: shard, so a CI matrix can run the whole suite under it).
+#: no mesh is passed (explicit kwargs win). A value the engine cannot
+#: honour — a dense layout, a head count it does not divide, too few
+#: devices — raises, exactly as the kwarg does.
 MESH_TP_ENV = "REPRO_MESH_TP"
+
+class TensorParallelUnsupported(ValueError):
+    """A tensor-parallel degree the engine cannot serve at: a dense
+    layout or a kv-head count it does not divide. (Too few devices is
+    the serving mesh's RuntimeError.)"""
+
+
+def resolve_tp(tp=None, mesh=None, *, cfg=None, layout=None) -> int:
+    """The tensor-parallel degree an engine serves at.
+
+    ``mesh``'s "model" axis if a mesh is given (``tp`` must agree), else
+    ``tp``, else ``REPRO_MESH_TP``, else 1. A degree above 1 that cannot
+    be honoured raises ``TensorParallelUnsupported`` — from the kwarg and
+    the env alike, since a silent tp=1 would serve unsharded unnoticed.
+    ``cfg``/``layout`` enable the head-count and layout checks.
+    """
+    if mesh is not None:
+        mesh_tp = int(dict(mesh.shape).get("model", 1))
+        if tp is not None and int(tp) != mesh_tp:
+            raise ValueError(
+                f"tp={tp} disagrees with the mesh's model axis ({mesh_tp})")
+        tp = mesh_tp
+    if tp is None:
+        env = os.environ.get(MESH_TP_ENV, "")
+        try:
+            tp = int(env) if env else 1
+        except ValueError:
+            raise ValueError(f"{MESH_TP_ENV}={env!r}: not an int")
+    tp = int(tp)
+    if tp < 1:
+        raise ValueError(f"tp must be >= 1, got {tp}")
+    if tp > 1:
+        if layout is not None and layout != "paged":
+            raise TensorParallelUnsupported(
+                "tp > 1 shards the paged page pool along the head axis; "
+                "dense-layout families cannot serve sharded")
+        if cfg is not None and cfg.n_kv_heads % tp != 0:
+            raise TensorParallelUnsupported(
+                f"n_kv_heads={cfg.n_kv_heads} not divisible by tp={tp}")
+    return tp
+
 
 #: env var giving the default engine-replica count for launch/serve.py's
 #: ``--dp`` flag (the Engine itself is one replica; see serving/replica.py).
@@ -455,33 +496,8 @@ class Engine:
                 "kv_scale='absmax' calibrates a quantized pool's scales; "
                 "it needs kv_dtype='int8'/'fp8_v' and the paged layout")
         self.kv_scale = spec.kv_scale if layout == "paged" else "grid"
-        if mesh is not None:
-            mesh_tp = int(dict(mesh.shape).get("model", 1))
-            if tp is not None and int(tp) != mesh_tp:
-                raise ValueError(
-                    f"tp={tp} disagrees with the mesh's model axis "
-                    f"({mesh_tp})")
-            tp = mesh_tp
-        if tp is None:
-            env = os.environ.get(MESH_TP_ENV, "")
-            try:
-                tp = int(env) if env else 1
-            except ValueError:
-                raise ValueError(f"{MESH_TP_ENV}={env!r}: not an int")
-            # env default degrades silently, like the other REPRO_* envs,
-            # so a CI leg can run every engine under it
-            if (layout != "paged" or tp < 1 or cfg.n_kv_heads % max(tp, 1)
-                    or len(jax.devices()) < tp):
-                tp = 1
-        tp = int(tp)
+        tp = resolve_tp(tp, mesh, cfg=cfg, layout=layout)
         if tp > 1:
-            if layout != "paged":
-                raise ValueError(
-                    "tp > 1 shards the paged page pool along the head "
-                    "axis; dense-layout families cannot serve sharded")
-            if cfg.n_kv_heads % tp != 0:
-                raise ValueError(
-                    f"n_kv_heads={cfg.n_kv_heads} not divisible by tp={tp}")
             if mesh is None:
                 from repro.launch.mesh import make_serving_mesh
                 mesh = make_serving_mesh(tp=tp)
@@ -492,6 +508,11 @@ class Engine:
         if params is None:
             rng = rng if rng is not None else jax.random.PRNGKey(0)
             params, _ = registry.init_params(cfg, rng)
+        if self.mesh is not None:
+            # one replicated copy per mesh device, placed once: a tree left
+            # on one device would be re-broadcast by every jitted call
+            params = jax.device_put(params, jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec()))
         self.params = params
 
         if self.paged:
@@ -807,13 +828,13 @@ class Engine:
             ent = resolve_write_pages(stale, table_eff, ps, floors)
             reject = reject & (ent != 0)     # never poison the scratch page
             off = stale % ps
-            kp = cache["k_pages"]                           # [L, P, ps, N, hd]
+            kp = cache["k_pages"]                           # [L, P, N, ps, hd]
             poison = (jnp.asarray(POISON_CODE, kp.dtype)
                       if kp.dtype == jnp.int8
                       else jnp.asarray(jnp.nan, kp.dtype))
-            cur = kp[:, ent, off]                           # [L, B, k, N, hd]
-            val = jnp.where(reject[None, :, :, None, None], poison, cur)
-            return {**cache, "k_pages": kp.at[:, ent, off].set(val)}
+            cur = kp[:, ent, :, off]                        # [B, k, L, N, hd]
+            val = jnp.where(reject[:, :, None, None, None], poison, cur)
+            return {**cache, "k_pages": kp.at[:, ent, :, off].set(val)}
         kc = cache["k"]                                     # [L, B, S, N, hd]
         b = jnp.arange(kc.shape[1])[:, None]
         cur = kc[:, b, stale]                               # [L, B, k, N, hd]

@@ -201,11 +201,26 @@ def cache_bytes(cache) -> int:
 # --------------------------------------------------------------------------
 # Block-paged KV cache (transformer families)
 # --------------------------------------------------------------------------
+def default_page_size(cfg) -> int:
+    """Tokens per KV page: the HDP key block when HDP is on (pages are the
+    scout's pruning blocks), else 16."""
+    hdp = cfg.hdp
+    return hdp.block_k if hdp is not None and hdp.enabled else 16
+
+
+def _head_major(pages):
+    """[L, n, ps, N, hd] token-major pages <-> [L, n, N, ps, hd] pool
+    layout (the swap is its own inverse)."""
+    return jnp.swapaxes(pages, 2, 3)
+
+
 class PagedKVCache(_DonatableCache):
     """Page pool + per-slot page tables, aligned to HDP's ``block_k``.
 
-    Layout: ``k_pages``/``v_pages`` are [L, P, page_size, N, hd] pools
-    shared by every slot; a host-side page table maps slot -> page ids.
+    Layout: ``k_pages``/``v_pages`` are head-major [L, P, N, page_size,
+    hd] pools shared by every slot — one kv head's page is a whole
+    [page_size, hd] tile, which the Pallas decode kernel DMAs as stored —
+    and a host-side page table maps slot -> page ids.
 
     ``kv_dtype`` selects the pool storage format:
 
@@ -298,7 +313,7 @@ class PagedKVCache(_DonatableCache):
         #: pool memory. Quantized pools derive both scout copies from
         #: the codes instead, so the flag allocates nothing there.
         self.draft_scout = draft_scout and self.scout
-        ps = page_size or (hdp.block_k if self.scout else 16)
+        ps = page_size or default_page_size(cfg)
         if self.scout and ps != hdp.block_k:
             raise ValueError(
                 f"page_size {ps} must equal hdp.block_k {hdp.block_k} so "
@@ -319,32 +334,30 @@ class PagedKVCache(_DonatableCache):
         self.int_bits = pool_int_bits(hdp)
         L, N, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
         dt = jnp.dtype(cfg.dtype)
-        shape = (L, self.num_pages, ps, N, hd)
+        shape = (L, self.num_pages, N, ps, hd)
+        # leaf name -> (shape, dtype, fill value)
         if self.quantized:
             v_dt = jnp.dtype(jnp.float8_e4m3fn) if kv_dtype == "fp8_v" \
                 else jnp.dtype(jnp.int8)
             s0 = pool_scale(self.int_bits)
-            self.cache: Dict[str, jnp.ndarray] = {
-                "k_pages": jnp.zeros(shape, jnp.int8),
-                "v_pages": jnp.zeros(shape, v_dt),
+            leaves = {
+                "k_pages": (shape, jnp.int8, 0),
+                "v_pages": (shape, v_dt, 0),
                 # per-page per-kv-head scales; the scratch page's stays
                 # the static grid scale forever (finite by contract)
-                "k_scale": jnp.full((L, self.num_pages, N), s0, jnp.float32),
-                "v_scale": jnp.full((L, self.num_pages, N),
-                                    1.0 if kv_dtype == "fp8_v" else s0,
-                                    jnp.float32),
+                "k_scale": ((L, self.num_pages, N), jnp.float32, s0),
+                "v_scale": ((L, self.num_pages, N), jnp.float32,
+                            1.0 if kv_dtype == "fp8_v" else s0),
             }
         else:
-            self.cache = {
-                "k_pages": jnp.zeros(shape, dt),
-                "v_pages": jnp.zeros(shape, dt),
-            }
+            leaves = {"k_pages": (shape, dt, 0), "v_pages": (shape, dt, 0)}
             if self.scout:
-                self.cache["k_scout"] = jnp.zeros(shape, jnp.int8)
+                leaves["k_scout"] = (shape, jnp.int8, 0)
             if self.draft_scout:
-                self.cache["f_scout"] = jnp.zeros(shape, jnp.int8)
+                leaves["f_scout"] = (shape, jnp.int8, 0)
         self.mesh = mesh
         self.tp = 1
+        shardings = {}
         if mesh is not None:
             from repro.distribution.tp import pool_shardings
             self.tp = int(dict(mesh.shape).get("model", 1))
@@ -352,9 +365,12 @@ class PagedKVCache(_DonatableCache):
                 raise ValueError(
                     f"n_kv_heads={N} not divisible by tp={self.tp}")
             # resident pool lives head-sharded: each model shard holds
-            # 1/tp of every page's codes, scales and scout views
-            self.cache = jax.device_put(
-                self.cache, pool_shardings(mesh, self.cache))
+            # 1/tp of every page's codes, scales and scout views — created
+            # in place, so a pool larger than one device's memory fits
+            shardings = pool_shardings(mesh, leaves)
+        self.cache: Dict[str, jnp.ndarray] = {
+            name: jnp.full(shp, fill, dtype, device=shardings.get(name))
+            for name, (shp, dtype, fill) in leaves.items()}
         self.allocator = PageAllocator(self.num_pages, reserved=1,
                                        on_free=self._on_free)
         self._slot_pages: Dict[int, List[int]] = {}
@@ -464,7 +480,7 @@ class PagedKVCache(_DonatableCache):
         if not self.quantized:
             return np.isnan(kp)
         scl = np.isnan(np.asarray(self.cache["k_scale"]))  # [L, P, N]
-        return (kp == POISON_CODE) | scl[:, :, None, :, None]
+        return (kp == POISON_CODE) | scl[:, :, :, None, None]
 
     # -------------------------------------------------------------- insert
     def _row_to_pages(self, k, row, npg):
@@ -515,25 +531,25 @@ class PagedKVCache(_DonatableCache):
             # scales are (re)written with the codes, so a reused page
             # sheds any freed-poison sentinel the moment it holds data
             return {
-                "k_pages": pool["k_pages"].at[:, flat].set(kq),
-                "v_pages": pool["v_pages"].at[:, flat].set(vq),
+                "k_pages": pool["k_pages"].at[:, flat].set(_head_major(kq)),
+                "v_pages": pool["v_pages"].at[:, flat].set(_head_major(vq)),
                 "k_scale": pool["k_scale"].at[:, flat].set(ks),
                 "v_scale": pool["v_scale"].at[:, flat].set(vs),
             }
         new = {
             "k_pages": pool["k_pages"].at[:, flat].set(
-                kp.astype(pool["k_pages"].dtype)),
+                _head_major(kp.astype(pool["k_pages"].dtype))),
             "v_pages": pool["v_pages"].at[:, flat].set(
-                vp.astype(pool["v_pages"].dtype)),
+                _head_major(vp.astype(pool["v_pages"].dtype))),
         }
         if self.scout:
             from repro.models.attention import scout_int8
             new["k_scout"] = pool["k_scout"].at[:, flat].set(
-                scout_int8(kp, self.cfg.hdp))
+                _head_major(scout_int8(kp, self.cfg.hdp)))
         if self.draft_scout:
             from repro.models.attention import scout_frac_int8
             new["f_scout"] = pool["f_scout"].at[:, flat].set(
-                scout_frac_int8(kp, self.cfg.hdp))
+                _head_major(scout_frac_int8(kp, self.cfg.hdp)))
         return new
 
     def insert(self, one_cache, slot: int, row: int = 0,
@@ -572,12 +588,13 @@ class PagedKVCache(_DonatableCache):
         holds exactly the round-tripped values a cold prefill writes, so
         hot and cold runs stay token-identical."""
         kp = pool["k_pages"]
-        L, _, ps, N, hd = kp.shape
+        L, _, N, ps, hd = kp.shape
 
         def to_cache(codes, scale):
-            g = codes[:, idx]                       # [L, nP, ps, N, hd]
+            g = codes[:, idx]                       # [L, nP, N, ps, hd]
             if scale is not None:
-                g = g.astype(jnp.float32) * scale[:, idx][:, :, None, :, None]
+                g = g.astype(jnp.float32) * scale[:, idx][..., None, None]
+            g = _head_major(g)                      # [L, nP, ps, N, hd]
             g = g.reshape(L, self.pages_per_slot * ps, N, hd)
             return g[:, None, :self.max_len]
 
@@ -598,21 +615,23 @@ class PagedKVCache(_DonatableCache):
         return self._gather_jit(self.cache, jnp.asarray(idx))
 
     # ------------------------------------------------------------ metrics
-    def _page_bytes(self) -> int:
-        per = sum(v.dtype.itemsize * int(np.prod(v.shape[2:]))
-                  for v in self.cache.values()) * self.cfg.n_layers
-        return per
+    def page_bytes(self) -> int:
+        """Bytes one page holds over every layer and pool leaf (codes,
+        per-page scales, any scout copies) — what sizing a pool by bytes
+        divides by."""
+        return sum(v.dtype.itemsize * int(np.prod(v.shape[2:]))
+                   for v in self.cache.values()) * self.cfg.n_layers
 
     def active_bytes(self, pages: Optional[int] = None) -> int:
         """Bytes resident for `pages` allocated pages (default: current)."""
         n = self.pages_in_use if pages is None else pages
-        return n * self._page_bytes()
+        return n * self.page_bytes()
 
     def bytes_per_token(self) -> float:
         """Resident pool bytes per cached token, over every pool leaf
         (codes + per-page scales + any scout copies) — the
         dtype-sensitive footprint the serving summary reports."""
-        return self._page_bytes() / self.page_size
+        return self.page_bytes() / self.page_size
 
     def pool_bytes(self) -> int:
         return cache_bytes(self.cache)

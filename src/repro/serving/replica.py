@@ -4,8 +4,9 @@ Above tensor parallelism (which shards ONE engine's page pool across a
 mesh's "model" axis — see ``distribution.tp``) sits the replica layer:
 N complete engines, each with its own page pool, slot state, scheduler
 and prefix cache, served through a single submit/step/serve surface.
-Replicas share one params tree, so which replica serves a request never
-changes its tokens — dispatch is a pure load/locality decision:
+Replicas hold copies of one params tree, so which replica serves a
+request never changes its tokens — dispatch is a pure load/locality
+decision:
 
 * **prefix affinity** first: the replica whose radix prefix cache holds
   the longest cached prefix of the prompt (a read-only ``peek``) wins —
@@ -47,9 +48,17 @@ from __future__ import annotations
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Set
 
+import jax
+
 from repro.common.transient import is_transient
-from repro.serving.engine import Engine, Request, Result
+from repro.serving.engine import Engine, Request, Result, resolve_tp
 from repro.serving.faults import coerce_injector
+
+
+def _cache_devices(eng: Engine) -> List[int]:
+    """Ids of the devices holding ``eng``'s KV cache (pool or slots)."""
+    store = eng.pages if eng.paged else eng.slots
+    return sorted(d.id for d in jax.tree.leaves(store.cache)[0].devices())
 
 
 class ReplicaSet:
@@ -79,23 +88,44 @@ class ReplicaSet:
     @classmethod
     def build(cls, cfg, dp: int, *, params=None, rng=None, faults=None,
               step_retries: int = 1, **engine_kw) -> "ReplicaSet":
-        """Build ``dp`` replicas sharing ONE params tree.
+        """Build ``dp`` replicas of ONE params tree, each on its own devices.
 
         The first engine initializes (or adopts) the params; the rest
-        reuse the same tree, so every replica is token-identical by
-        construction. Per-engine kwargs (tp, attn, spec_decode, ...)
-        apply to every replica alike. ``faults`` (a plan/spec/injector;
-        env fallback ``REPRO_FAULT_PLAN``) is coerced ONCE and shared by
-        the fleet and every member, so each scheduled event fires
-        exactly once fleet-wide.
+        copy the same tree, so every replica is token-identical by
+        construction. Replica ``i`` holds its params and page pool on
+        device group ``i`` — ``tp`` consecutive devices, a tensor-parallel
+        mesh when ``tp > 1``. With fewer than ``dp * tp`` devices the
+        groups wrap round-robin, so replicas share devices (a one-device
+        host runs every replica on it). Per-engine kwargs (tp, attn,
+        spec_decode, ...) apply to every replica alike. ``faults`` (a
+        plan/spec/injector; env fallback ``REPRO_FAULT_PLAN``) is coerced
+        ONCE and shared by the fleet and every member, so each scheduled
+        event fires exactly once fleet-wide.
         """
         if dp < 1:
             raise ValueError(f"dp must be >= 1, got {dp}")
+        from repro.launch.mesh import make_serving_mesh
+
         inj = coerce_injector(faults)
-        first = Engine(cfg, params=params, rng=rng, faults=inj, **engine_kw)
-        rest = [Engine(cfg, params=first.params, faults=inj, **engine_kw)
-                for _ in range(dp - 1)]
-        return cls([first] + rest, faults=inj, step_retries=step_retries)
+        tp = resolve_tp(engine_kw.get("tp"), engine_kw.get("mesh"))
+        devs = jax.devices()
+        n_groups = max(1, len(devs) // tp)
+        engines: List[Engine] = []
+        for i in range(dp):
+            g = i % n_groups
+            group = devs[g * tp:(g + 1) * tp]
+            kw = dict(engine_kw)
+            p = engines[0].params if engines else params
+            if tp > 1:
+                # the engine replicates its params onto its own mesh
+                kw["mesh"] = make_serving_mesh(tp=tp, devices=group)
+            elif p is not None:
+                p = jax.device_put(p, group[0])
+            # the pool and slot state are created on the group's device
+            with jax.default_device(group[0]):
+                engines.append(Engine(cfg, params=p, rng=rng, faults=inj,
+                                      **kw))
+        return cls(engines, faults=inj, step_retries=step_retries)
 
     # -------------------------------------------------------------- dispatch
     def _healthy(self) -> List[Engine]:
@@ -285,6 +315,7 @@ class ReplicaSet:
             "replica_queue_depth": [e._n_pending() for e in self.engines],
             "replica_inflight": [len(e._active) for e in self.engines],
             "replica_last_step_s": list(self._last_step_s),
+            "replica_devices": [_cache_devices(e) for e in self.engines],
             "replicas": subs,
         }
         if m["decode_s"]:

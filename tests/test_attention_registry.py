@@ -61,8 +61,8 @@ def _paged_setup(seed, hdp, n_pages=4):
     P = 1 + n_pages                       # page 0 = reserved scratch
     rng = jax.random.PRNGKey(seed)
     q = jax.random.normal(jax.random.fold_in(rng, 0), (B, N, G, 1, HD), F32)
-    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, ps, N, HD), F32)
-    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, ps, N, HD), F32)
+    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, N, ps, HD), F32)
+    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, N, ps, HD), F32)
     cache = {"k_pages": ks, "v_pages": vs, "k_scout": scout_int8(ks, hdp)}
     table = jnp.arange(1, P, dtype=jnp.int32).reshape(B, n_pages)
     sk = n_pages * ps

@@ -72,6 +72,22 @@ class TestHardware:
         expect = TPU_V5E if jax.default_backend() == "tpu" else HOST_CPU
         assert prof is expect
 
+    @pytest.mark.parametrize("kind,expect", [("TPU v5 lite", TPU_V5E),
+                                             ("TPU v4", None),
+                                             ("NVIDIA H100", None)])
+    def test_detect_profile_keys_on_device_kind(self, monkeypatch, kind,
+                                                expect):
+        class Dev:
+            platform = "gpu" if kind.startswith("NVIDIA") else "tpu"
+            device_kind = kind
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+        if expect is None:
+            with pytest.raises(RuntimeError, match="no hardware profile"):
+                detect_profile()
+        else:
+            assert detect_profile() is expect
+
     def test_analysis_reexports_tpu_constants(self):
         from repro.roofline import analysis
         assert analysis.PEAK_FLOPS == TPU_V5E.peak_flops
@@ -106,7 +122,7 @@ class TestCallSig:
         call = AttnCall(mode="decode", layout="paged", hdp=HDP,
                         per_slot=True)
         q = jnp.zeros((B, N, G, 1, HD), jnp.float32)
-        cache = {"k_pages": jnp.zeros((9, 4, N, HD), jnp.float32)}
+        cache = {"k_pages": jnp.zeros((9, N, 4, HD), jnp.float32)}
         table = jnp.ones((B, 6), jnp.int32)
         sig = call_signature(call, q, cache=cache, page_table=table)
         assert sig.kv_len == 6 * 4 and sig.page_size == 4
@@ -281,13 +297,28 @@ class TestTuner:
         t.choose(self.CALL, sig, _cands("xla_dense", "reference"))
 
         def boom(call, sig, names):
-            raise RuntimeError("probe exploded")
+            raise RuntimeError("probe timed out")   # transient
 
         t._probe = boom
         assert t.flush_probes() is False
         assert not t.pending  # never re-tried
         assert t.decision[sig.key()] == "xla_dense"
         assert t.flush_probes() is False  # idempotent when drained
+
+    def test_probe_error_propagates(self):
+        # a non-transient probe failure (e.g. a kernel the compiler
+        # refuses) is a real fault: it surfaces instead of being dropped
+        t = Tuner(hw=HOST_CPU, margin=1e9)
+        sig = _decode_sig(kv=256)
+        t.choose(self.CALL, sig, _cands("xla_dense", "reference"))
+
+        def boom(call, sig, names):
+            raise ValueError("kernel refused")
+
+        t._probe = boom
+        with pytest.raises(ValueError, match="kernel refused"):
+            t.flush_probes()
+        assert not t.pending  # the failed signature is not re-queued
 
     def test_real_probe_on_paged_hdp_call(self):
         # one end-to-end probe: synthetic inputs + jitted backend run

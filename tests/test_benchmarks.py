@@ -3,13 +3,15 @@ equal the production model forward, or every figure analog is meaningless."""
 from __future__ import annotations
 
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, "/root/repo")  # benchmarks package lives at repo root
+# the benchmarks package lives at the repo root
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from benchmarks import common  # noqa: E402
 from repro.core.hdp import dense_attention_reference  # noqa: E402

@@ -96,14 +96,17 @@ class TestCheckpoint:
 
 
 class TestFault:
-    def test_step_timer_flags_straggler(self):
+    def test_step_timer_flags_straggler(self, monkeypatch):
+        # a scripted clock: step times are exact however loaded the host
+        clock = [0.0]
+        monkeypatch.setattr(fault.time, "perf_counter", lambda: clock[0])
         t = fault.StepTimer(window=20, threshold=2.0, warmup=0)
         for i in range(10):
             t.start()
-            time.sleep(0.002)
+            clock[0] += 0.002
             t.stop(i)
         t.start()
-        time.sleep(0.05)  # 25x median
+        clock[0] += 0.05  # 25x median
         t.stop(10)
         assert len(t.events) == 1
         assert t.events[0].slowdown > 2.0

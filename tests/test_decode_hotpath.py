@@ -204,8 +204,8 @@ def _paged_inputs(seed, hdp, n_pages, B=2, N=2, G=2, hd=8):
     P = 1 + B * n_pages
     rng = jax.random.PRNGKey(seed)
     q = jax.random.normal(jax.random.fold_in(rng, 0), (B, N, G, 1, hd), F32)
-    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, ps, N, hd), F32)
-    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, ps, N, hd), F32)
+    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, N, ps, hd), F32)
+    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, N, ps, hd), F32)
     ik = scout_int8(ks, hdp)
     table = jnp.arange(1, P, dtype=jnp.int32).reshape(B, n_pages)
     pos = jnp.full((B, 1), n_pages * ps - 1, jnp.int32)
@@ -243,7 +243,8 @@ def test_paged_scan_never_reads_pruned_pages():
         page_chunk=8)
 
     B, nP = table.shape
-    ik_full = ik[table].reshape(B, nP * hdp.block_k, 2, 8).astype(F32)
+    ik_full = jnp.swapaxes(ik[table], 2, 3).reshape(
+        B, nP * hdp.block_k, 2, 8).astype(F32)
     _, iq, _ = _fixed_split(q, hdp)
     s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik_full,
                        preferred_element_type=F32)

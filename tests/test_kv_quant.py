@@ -148,8 +148,8 @@ def test_quantized_pools_match_fp32_and_never_dma_pruned(stage3, page_chunk):
     hdp = HDPConfig(block_q=1, block_k=ps, rho_b=0.5, causal=True,
                     head_pruning=False, calib="none")
     ib = hdp.int_bits
-    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, ps, N, hd), F32)
-    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, ps, N, hd), F32)
+    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, N, ps, hd), F32)
+    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, N, ps, hd), F32)
     kc, vc = encode_pool(ks, ib), encode_pool(vs, ib)
     kscl = jnp.full((P, N), pool_scale(ib), F32)
     vscl = jnp.full((P, N), pool_scale(ib), F32)
@@ -175,7 +175,8 @@ def test_quantized_pools_match_fp32_and_never_dma_pruned(stage3, page_chunk):
     np.testing.assert_array_equal(np.asarray(out_q), np.asarray(out_fp))
 
     # reconstruct the fetch decision exactly as stage 1 does
-    ik = jnp.trunc(pool_view_finite(kc[table], ib)).reshape(B, Sk, N, hd)
+    ik = jnp.trunc(pool_view_finite(jnp.swapaxes(kc[table], 2, 3), ib))
+    ik = ik.reshape(B, Sk, N, hd)
     _, iq, _ = _fixed_split(q, hdp)
     s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik,
                        preferred_element_type=F32)
@@ -402,9 +403,9 @@ def test_absmax_decode_drift_gate_vs_fp32_oracle():
     ib = hdp.int_bits
     # small-magnitude K/V: the regime where calibration matters
     ks = 0.05 * jax.random.normal(jax.random.fold_in(rng, 1),
-                                  (P, ps, N, hd), F32)
+                                  (P, N, ps, hd), F32)
     vs = 0.05 * jax.random.normal(jax.random.fold_in(rng, 2),
-                                  (P, ps, N, hd), F32)
+                                  (P, N, ps, hd), F32)
     q = jax.random.normal(jax.random.fold_in(rng, 3), (B, N, G, 1, hd), F32)
     table = jnp.arange(1, P, dtype=jnp.int32).reshape(B, nP)
     pos = jnp.full((B, 1), Sk - 1, jnp.int32)
@@ -417,10 +418,12 @@ def test_absmax_decode_drift_gate_vs_fp32_oracle():
     out_fp, _ = hdp_paged_decode_attention(
         q, ks, vs, scout_int8(ks, hdp), table, **kw)
 
-    ksc, vsc = absmax_page_scale(ks, ib), absmax_page_scale(vs, ib)
+    # head-major pools: absmax over a page's [ps, hd] per kv head
+    ksc = absmax_page_scale(jnp.swapaxes(ks, 1, 2), ib)
+    vsc = absmax_page_scale(jnp.swapaxes(vs, 1, 2), ib)
     out_a, _ = hdp_paged_decode_attention(
-        q, encode_pool_scaled(ks, ksc[:, None, :, None]),
-        encode_pool_scaled(vs, vsc[:, None, :, None]), None, table,
+        q, encode_pool_scaled(ks, ksc[:, :, None, None]),
+        encode_pool_scaled(vs, vsc[:, :, None, None]), None, table,
         k_scale=ksc, v_scale=vsc, kv_scale="absmax", **kw)
 
     g = jnp.full((P, N), pool_scale(ib), F32)

@@ -140,8 +140,8 @@ def test_pruned_pages_never_gathered():
     P = 1 + B * nP
     hdp = HDPConfig(block_q=1, block_k=ps, rho_b=0.5, causal=True,
                     head_pruning=False, calib="none")
-    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, ps, N, hd), F32)
-    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, ps, N, hd), F32)
+    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, N, ps, hd), F32)
+    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, N, ps, hd), F32)
     ik = scout_int8(ks, hdp)
     q = jax.random.normal(jax.random.fold_in(rng, 3), (B, N, G, 1, hd), F32)
     table = jnp.arange(1, P, dtype=jnp.int32).reshape(B, nP)
@@ -154,7 +154,8 @@ def test_pruned_pages_never_gathered():
         q, ks, vs, ik, table, q_pos=q_pos, k_pos=k_pos, hdp=hdp)
 
     # reconstruct the keep mask exactly as the kernel does
-    ik_full = ik[table].reshape(B, nP * ps, N, hd).astype(F32)
+    ik_full = jnp.swapaxes(ik[table], 2, 3).reshape(
+        B, nP * ps, N, hd).astype(F32)
     _, iq, _ = _fixed_split(q, hdp)
     s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik_full,
                        preferred_element_type=F32)

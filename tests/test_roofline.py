@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import subprocess
 import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -93,8 +94,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_host_mesh
 from repro.roofline import hlo_cost
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_host_mesh(model=8)
 x = jax.ShapeDtypeStruct((256, 1024), jnp.float32,
                          sharding=NamedSharding(mesh, P(None, "model")))
 w = jax.ShapeDtypeStruct((1024, 2048), jnp.float32,
@@ -110,7 +112,8 @@ print("OK")
                              capture_output=True, text=True,
                              env={**__import__("os").environ,
                                   "PYTHONPATH": "src"},
-                             cwd="/root/repo", timeout=300)
+                             cwd=Path(__file__).resolve().parents[1],
+                             timeout=300)
         assert "OK" in out.stdout, out.stderr[-2000:]
 
 

@@ -201,8 +201,8 @@ def _paged_inputs(seed, hdp, n_pages, B=2, N=2, G=2, hd=8, Sq=1):
     P = 1 + B * n_pages
     rng = jax.random.PRNGKey(seed)
     q = jax.random.normal(jax.random.fold_in(rng, 0), (B, N, G, Sq, hd), F32)
-    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, ps, N, hd), F32)
-    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, ps, N, hd), F32)
+    ks = jax.random.normal(jax.random.fold_in(rng, 1), (P, N, ps, hd), F32)
+    vs = jax.random.normal(jax.random.fold_in(rng, 2), (P, N, ps, hd), F32)
     ik = scout_int8(ks, hdp)
     table = jnp.arange(1, P, dtype=jnp.int32).reshape(B, n_pages)
     base = n_pages * ps - Sq
@@ -324,11 +324,11 @@ def test_rejected_speculative_writes_are_poisoned():
     poisoned = np.asarray(eng.pages.poison_view())   # dtype-independent
     for p in range(start + committed, start + k):
         page, off = pages[p // ps], p % ps
-        assert poisoned[:, page, off].all(), \
+        assert poisoned[:, page, :, off].all(), \
             f"rejected staged position {p} not poisoned"
     # committed frontier (last committed token's write) stays clean
     last = start + committed - 1
-    assert not poisoned[:, pages[last // ps], last % ps].any()
+    assert not poisoned[:, pages[last // ps], :, last % ps].any()
     assert eng.run()[0].tokens == ref
 
 

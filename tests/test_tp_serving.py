@@ -13,10 +13,9 @@ Load-bearing guarantees pinned here:
   NamedSharding that splits its kv-head axis across "model";
 * the FUM/no-DMA contract holds per shard: NaN-poisoning free pages of
   the SHARDED pool (both sentinel channels) cannot change a token;
-* mesh resolution: explicit ``tp=`` must divide the kv heads and fit
-  the device count (errors), a ``mesh=`` disagreeing with ``tp=``
-  errors, while the REPRO_MESH_TP env default DEGRADES silently so a
-  CI matrix can run the whole suite under it;
+* mesh resolution: ``tp=`` (kwarg or the REPRO_MESH_TP env default)
+  must divide the kv heads, suit the layout and fit the device count,
+  or the engine raises; a ``mesh=`` disagreeing with ``tp=`` errors;
 * DP replicas behind ``ReplicaSet`` share one params tree, dispatch by
   prefix affinity then least-loaded, and their merged stream yields
   the same tokens the single engine produces.
@@ -90,17 +89,21 @@ def test_engine_tp_validation():
         Engine(cfg, max_batch=1, max_len=32, tp=1, mesh=mesh)
 
 
-def test_env_default_degrades_silently(monkeypatch):
+def test_env_default_raises_when_unhonourable(monkeypatch):
     cfg = _cfg()
     monkeypatch.setenv("REPRO_MESH_TP", "2")
     assert Engine(cfg, max_batch=1, max_len=32).tp == 2
-    # non-divisible head count: degrade, don't error (CI runs the whole
-    # suite under the env)
+    # a setting the engine cannot honour is refused, never served at tp=1
     monkeypatch.setenv("REPRO_MESH_TP", "3")
-    assert Engine(cfg, max_batch=1, max_len=32).tp == 1
+    with pytest.raises(ValueError, match="divisible"):
+        Engine(cfg, max_batch=1, max_len=32)
     monkeypatch.setenv("REPRO_MESH_TP", "2")
-    assert Engine(cfg, max_batch=1, max_len=32,
-                  attn=AttnSpec(layout="dense")).tp == 1
+    with pytest.raises(ValueError, match="paged"):
+        Engine(cfg, max_batch=1, max_len=32, attn=AttnSpec(layout="dense"))
+    monkeypatch.setenv("REPRO_MESH_TP", "8")
+    with pytest.raises(RuntimeError, match="devices"):
+        Engine(cfg.replace(n_kv_heads=8, n_heads=8), max_batch=1,
+               max_len=32)
     # explicit kwarg wins over the env
     monkeypatch.delenv("REPRO_MESH_TP")
     assert Engine(cfg, max_batch=1, max_len=32, tp=2).tp == 2
@@ -242,6 +245,25 @@ def test_replicaset_dp2_tp2_compose():
         == rs.engines[0].pages.pool_bytes()
 
 
+def test_replicas_placed_on_distinct_devices():
+    """dp=4 on a 4-device host: replica i holds its params and page pool
+    on device i, and the fleet's tokens equal the single engine's."""
+    cfg = _cfg()
+    prompts = _prompts(4, seed=15)
+    eng, ref = _serve(cfg, None, prompts)
+    rs = ReplicaSet.build(cfg, 4, params=eng.params, max_batch=2,
+                          max_len=96, prefill_buckets=(16, 32, 64), tp=1)
+    got = {r.uid: r.tokens
+           for r in rs.serve([Request(u, p, max_new_tokens=5)
+                              for u, p in enumerate(prompts)])}
+    assert got == ref, "replica placement changed the generated tokens"
+    devs = jax.devices()
+    for i, e in enumerate(rs.engines):
+        leaf = jax.tree.leaves(e.params)[0]
+        assert leaf.devices() == {devs[i]}, f"replica {i} params"
+    assert rs.summary()["replica_devices"] == [[d.id] for d in devs[:4]]
+
+
 def test_replicaset_least_loaded_dispatch():
     cfg = _cfg()
     rs = ReplicaSet.build(cfg, 2, max_batch=2, max_len=64,
@@ -253,3 +275,31 @@ def test_replicaset_least_loaded_dispatch():
     assert picked[0] is not picked[1]
     rs.run()
     assert sorted(rs.results()) == [0, 1, 2, 3]
+
+
+# ------------------------------------------------- chip_smoke --chips 4 paths
+def _chip_smoke():
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    import chip_smoke
+    return chip_smoke
+
+
+def test_chip_smoke_tp_phase():
+    """The four-chip smoke's tp=4 vs tp=1 comparison at reduced width,
+    on a pool four times the tp=1 pool."""
+    cs = _chip_smoke()
+    cfg = reduced(get_config("h2o-danube-1.8b"))
+    cfg = cfg.replace(n_kv_heads=4)
+    plan = cs.Plan(requests=2, max_new=4, max_len=64)
+    out = cs.tp_vs_single(cfg, plan, tp=4, pool_bytes_min=1 << 20)
+    assert out["pool_bytes"] >= 1 << 20
+    assert out["pool_bytes_per_shard"] * 4 == out["pool_bytes"]
+
+
+def test_chip_smoke_dp_phase():
+    cs = _chip_smoke()
+    plan = cs.Plan(requests=4, max_new=4, max_len=64)
+    out = cs.dp_vs_single(_cfg(), plan, dp=4)
+    assert out["placed"] == [[d.id] for d in jax.devices()[:4]]
