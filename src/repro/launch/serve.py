@@ -368,7 +368,10 @@ def run(args) -> dict:
         "attn_prefill": s["attn_backend_prefill"],
         "attn_decode": s["attn_backend_decode"],
         "decode_horizon": eng0.horizon,
+        # tokens over decode_s: host time from each decode dispatch to its
+        # results, which also waits out prefills queued on the device
         "decode_tok_s": round(s.get("decode_tok_s", 0.0), 2),
+        # host time dispatching prefill programs (not their device time)
         "prefill_s_total": round(s["prefill_s"], 3),
         "prefill_calls": s["prefill_calls"],
         # tokens run through prefill forwards (padded size) — the

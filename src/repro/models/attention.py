@@ -211,57 +211,59 @@ def hdp_prefill_attention(q, k, v, *, q_pos, k_pos, hdp: HDPConfig,
     qp_b = qp.reshape(nq, bq)
 
     # ---- Pass A: integer scout -> keep mask, head importance ----
-    def scout(carry, xs):
-        th_acc, n_acc, nb_acc = carry
-        iq_i, qp_i = xs
-        s_int = jnp.einsum("bngqh,bsnh->bngqs", iq_i, ik,
-                           preferred_element_type=F32)
-        valid = _mask_bias(qp_i, kp, hdp.causal, window)
-        theta, bvalid = blocking.pooled_block_theta(s_int, valid, bk)
-        if hdp.block_pruning:
-            thr = blocking.row_threshold(theta, hdp.rho_b, bvalid)
-            keep = blocking.block_keep_mask(theta, thr, bvalid)
-        else:
-            keep = jnp.broadcast_to(bvalid, theta.shape)
-        th_acc = th_acc + jnp.where(bvalid, theta, 0.0).sum(-1)
-        n_acc = n_acc + valid.sum().astype(F32)
-        nb_acc = nb_acc + bvalid.sum().astype(F32)
-        return (th_acc, n_acc, nb_acc), keep
+    with jax.named_scope("hdp.scout"):
+        def scout(carry, xs):
+            th_acc, n_acc, nb_acc = carry
+            iq_i, qp_i = xs
+            s_int = jnp.einsum("bngqh,bsnh->bngqs", iq_i, ik,
+                               preferred_element_type=F32)
+            valid = _mask_bias(qp_i, kp, hdp.causal, window)
+            theta, bvalid = blocking.pooled_block_theta(s_int, valid, bk)
+            if hdp.block_pruning:
+                thr = blocking.row_threshold(theta, hdp.rho_b, bvalid)
+                keep = blocking.block_keep_mask(theta, thr, bvalid)
+            else:
+                keep = jnp.broadcast_to(bvalid, theta.shape)
+            th_acc = th_acc + jnp.where(bvalid, theta, 0.0).sum(-1)
+            n_acc = n_acc + valid.sum().astype(F32)
+            nb_acc = nb_acc + bvalid.sum().astype(F32)
+            return (th_acc, n_acc, nb_acc), keep
 
-    (theta_head, n_valid, n_blocks), keep_rows = jax.lax.scan(
-        scout, (jnp.zeros((B, N, G), F32), jnp.zeros((), F32),
-                jnp.zeros((), F32)), (iq_b, qp_b))
-    if hdp.normalize_head_score:
-        theta_head = theta_head / jnp.maximum(n_valid, 1.0)
-    head_kept = (theta_head > hdp.tau_h) if hdp.head_pruning \
-        else jnp.ones_like(theta_head, bool)
+        (theta_head, n_valid, n_blocks), keep_rows = jax.lax.scan(
+            scout, (jnp.zeros((B, N, G), F32), jnp.zeros((), F32),
+                    jnp.zeros((), F32)), (iq_b, qp_b))
+        if hdp.normalize_head_score:
+            theta_head = theta_head / jnp.maximum(n_valid, 1.0)
+        head_kept = (theta_head > hdp.tau_h) if hdp.head_pruning \
+            else jnp.ones_like(theta_head, bool)
 
     # ---- Pass B: approximate attention on surviving blocks ----
-    def attend(_, xs):
-        qq_i, fq_i, qp_i, keep_i = xs
-        s = jnp.einsum("bngqh,bsnh->bngqs", qq_i, kq,
-                       preferred_element_type=F32)
-        if hdp.approx:
-            s = s - jnp.einsum("bngqh,bsnh->bngqs", fq_i, fk,
-                               preferred_element_type=F32)
-        s = s * (scale * score_rescale)
-        valid = _mask_bias(qp_i, kp, hdp.causal, window)
-        keep_e = jnp.repeat(keep_i, bk, axis=-1)[..., None, :] & valid
-        s = jnp.where(keep_e, s, _NEG)
-        softmax = blocking.approx_softmax if hdp.approx_softmax else None
-        if softmax is not None:
-            p = softmax(s, keep_e)
-        else:
-            mx = s.max(-1, keepdims=True)
-            p = jnp.exp(s - mx)
-            p = jnp.where(keep_e, p, 0.0)
-            p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
-        o = jnp.einsum("bngqs,bsnh->bngqh", p.astype(vp.dtype), vp,
-                       preferred_element_type=F32)
-        return (), o
+    with jax.named_scope("hdp.attend"):
+        def attend(_, xs):
+            qq_i, fq_i, qp_i, keep_i = xs
+            s = jnp.einsum("bngqh,bsnh->bngqs", qq_i, kq,
+                           preferred_element_type=F32)
+            if hdp.approx:
+                s = s - jnp.einsum("bngqh,bsnh->bngqs", fq_i, fk,
+                                   preferred_element_type=F32)
+            s = s * (scale * score_rescale)
+            valid = _mask_bias(qp_i, kp, hdp.causal, window)
+            keep_e = jnp.repeat(keep_i, bk, axis=-1)[..., None, :] & valid
+            s = jnp.where(keep_e, s, _NEG)
+            softmax = blocking.approx_softmax if hdp.approx_softmax else None
+            if softmax is not None:
+                p = softmax(s, keep_e)
+            else:
+                mx = s.max(-1, keepdims=True)
+                p = jnp.exp(s - mx)
+                p = jnp.where(keep_e, p, 0.0)
+                p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+            o = jnp.einsum("bngqs,bsnh->bngqh", p.astype(vp.dtype), vp,
+                           preferred_element_type=F32)
+            return (), o
 
-    _, outs = jax.lax.scan(attend, (), (qq_b, fq_b, qp_b, keep_rows))
-    out = jnp.moveaxis(outs, 0, 3).reshape(B, N, G, Sqp, hd)[:, :, :, :Sq]
+        _, outs = jax.lax.scan(attend, (), (qq_b, fq_b, qp_b, keep_rows))
+        out = jnp.moveaxis(outs, 0, 3).reshape(B, N, G, Sqp, hd)[:, :, :, :Sq]
     out = out * head_kept[..., None, None].astype(out.dtype)
 
     stats = None
@@ -720,131 +722,96 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
             layer = None
 
     # ---- stage 1: integer scout on the always-streamed int8 copy ----
-    if absmax:
-        # calibrated scales: dequantize the scout stream through a
-        # sanitized scale copy — poison codes -> 0 and NaN freed-page
-        # scales -> the static step, preserving the scout-always-finite
-        # contract of the static-grid view
-        codes = gather_pages(k_pool, table, layer)              # [B,nP,ps,N,hd]
-        ksc = k_scale[table]                             # [B,nP,N]
-        ksc = jnp.where(jnp.isfinite(ksc), ksc, pool_scale(hdp.int_bits))
-        cf = jnp.where(codes == POISON_CODE, 0, codes).astype(F32)
-        k_fin = (cf * ksc[:, :, None, :, None]).reshape(B, Sk, N, hd)
-        ik = jnp.trunc(k_fin)
-    elif quantized:
-        # the pool's codes ARE the scout stream: the finite static-grid
-        # view (poison sentinels -> 0, masked anyway) truncates to the
-        # same integer parts the fp32 pools' write-time copy stored
-        k_fin = pool_view_finite(gather_pages(k_pool, table, layer), hdp.int_bits)
-        k_fin = k_fin.reshape(B, Sk, N, hd)
-        ik = jnp.trunc(k_fin)
-    else:
-        ik = gather_pages(ik_pool, table, layer).reshape(B, Sk, N, hd).astype(F32)
-    qq, iq, fq = _fixed_split(q, hdp)
-    s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik, preferred_element_type=F32)
-    valid = _mask_bias(q_pos, k_pos, hdp.causal, window)
-    keep, bvalid, theta, theta_head, head_kept = decode_scout(
-        s_int, valid, hdp, per_query=per_query)
+    with jax.named_scope("hdp.scout"):
+        if absmax:
+            # calibrated scales: dequantize the scout stream through a
+            # sanitized scale copy — poison codes -> 0 and NaN freed-page
+            # scales -> the static step, preserving the scout-always-finite
+            # contract of the static-grid view
+            codes = gather_pages(k_pool, table, layer)              # [B,nP,ps,N,hd]
+            ksc = k_scale[table]                             # [B,nP,N]
+            ksc = jnp.where(jnp.isfinite(ksc), ksc, pool_scale(hdp.int_bits))
+            cf = jnp.where(codes == POISON_CODE, 0, codes).astype(F32)
+            k_fin = (cf * ksc[:, :, None, :, None]).reshape(B, Sk, N, hd)
+            ik = jnp.trunc(k_fin)
+        elif quantized:
+            # the pool's codes ARE the scout stream: the finite static-grid
+            # view (poison sentinels -> 0, masked anyway) truncates to the
+            # same integer parts the fp32 pools' write-time copy stored
+            k_fin = pool_view_finite(gather_pages(k_pool, table, layer), hdp.int_bits)
+            k_fin = k_fin.reshape(B, Sk, N, hd)
+            ik = jnp.trunc(k_fin)
+        else:
+            ik = gather_pages(ik_pool, table, layer).reshape(B, Sk, N, hd).astype(F32)
+        qq, iq, fq = _fixed_split(q, hdp)
+        s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik, preferred_element_type=F32)
+        valid = _mask_bias(q_pos, k_pos, hdp.causal, window)
+        keep, bvalid, theta, theta_head, head_kept = decode_scout(
+            s_int, valid, hdp, per_query=per_query)
 
-    # ---- stage 2: fetch-upon-mask page selection ----
-    # page fetch granularity is OR-over-heads (a page holds all kv heads)
-    # and, under multi-query verify, OR-over-query-rows (the pool is read
-    # once per round); the per-head/per-row keep mask still applies inside
-    # the softmax below. Early head-gated heads (output zeroed) don't
-    # demand their pages at all.
-    fetched = (keep & head_kept[..., None]).any(
-        axis=tuple(range(1, keep.ndim - 1)))                  # [B, nP]
+    with jax.named_scope("hdp.attend"):
+        # ---- stage 2: fetch-upon-mask page selection ----
+        # page fetch granularity is OR-over-heads (a page holds all kv heads)
+        # and, under multi-query verify, OR-over-query-rows (the pool is read
+        # once per round); the per-head/per-row keep mask still applies inside
+        # the softmax below. Early head-gated heads (output zeroed) don't
+        # demand their pages at all.
+        fetched = (keep & head_kept[..., None]).any(
+            axis=tuple(range(1, keep.ndim - 1)))                  # [B, nP]
 
-    if draft is not None and draft.scores != "approx":
-        # draft stage 3: scores from the int8 scout copies — s_int alone
-        # ("int") or QQ·IK + IQ·FK^ ("scout": the quantized-fraction copy
-        # recovers the exact pass's scores to within its 2^-6 grid);
-        # k_pool is never touched, and V is gathered only for surviving
-        # pages (scratch-redirect)
-        s = s_int
-        if draft.scores == "scout":
+        if draft is not None and draft.scores != "approx":
+            # draft stage 3: scores from the int8 scout copies — s_int alone
+            # ("int") or QQ·IK + IQ·FK^ ("scout": the quantized-fraction copy
+            # recovers the exact pass's scores to within its 2^-6 grid);
+            # k_pool is never touched, and V is gathered only for surviving
+            # pages (scratch-redirect)
+            s = s_int
+            if draft.scores == "scout":
+                if quantized:
+                    # the fraction view comes straight off the codes (exact:
+                    # the coarse pool grid is a subset of the 2^-6 scout
+                    # grid), so no separate fraction pool exists to read
+                    fkh = k_fin - ik
+                elif fk_pool is None:
+                    # the IQ·FK^ term cannot be derived without reading the
+                    # full-precision pool — which is exactly what this score
+                    # mode promises never to do; surface the misuse instead
+                    # of silently serving lower-fidelity drafts
+                    raise ValueError(
+                        'draft scores="scout" needs the f_scout pool '
+                        "(PagedKVCache(draft_scout=True)); pass fk_pool or "
+                        'use scores="int"')
+                else:
+                    fkh = gather_pages(fk_pool, table, layer).reshape(
+                        B, Sk, N, hd).astype(F32) \
+                        / FRAC_SCOUT_SCALE
+                s = s + jnp.einsum("bngqh,bsnh->bngqs", fq, ik,
+                                   preferred_element_type=F32) \
+                      + jnp.einsum("bngqh,bsnh->bngqs", iq, fkh,
+                                   preferred_element_type=F32)
+            gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
             if quantized:
-                # the fraction view comes straight off the codes (exact:
-                # the coarse pool grid is a subset of the 2^-6 scout
-                # grid), so no separate fraction pool exists to read
-                fkh = k_fin - ik
-            elif fk_pool is None:
-                # the IQ·FK^ term cannot be derived without reading the
-                # full-precision pool — which is exactly what this score
-                # mode promises never to do; surface the misuse instead
-                # of silently serving lower-fidelity drafts
-                raise ValueError(
-                    'draft scores="scout" needs the f_scout pool '
-                    "(PagedKVCache(draft_scout=True)); pass fk_pool or "
-                    'use scores="int"')
+                v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
+                                   v_scale[gather_idx])
+                v = v.reshape(B, Sk, N, hd)
             else:
-                fkh = gather_pages(fk_pool, table, layer).reshape(
-                    B, Sk, N, hd).astype(F32) \
-                    / FRAC_SCOUT_SCALE
-            s = s + jnp.einsum("bngqh,bsnh->bngqs", fq, ik,
-                               preferred_element_type=F32) \
-                  + jnp.einsum("bngqh,bsnh->bngqs", iq, fkh,
-                               preferred_element_type=F32)
-        gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
-        if quantized:
-            v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
-                               v_scale[gather_idx])
-            v = v.reshape(B, Sk, N, hd)
-        else:
-            v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
-        out = _approx_block_attention(None, None, None, None, v, keep, valid,
-                                      head_kept, block_k=ps, scale=scale,
-                                      approx=False, scores=s)
-    elif stage3 == "pallas_paged":
-        out = _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep,
-                                       head_kept, q_pos, fetched,
-                                       hdp=hdp, ps=ps,
-                                       k_scale=k_scale if quantized else None,
-                                       v_scale=v_scale if quantized else None,
-                                       layer=layer)
-    elif stage3 == "pallas_block":
-        from repro.kernels.hdp_block_attn import hdp_block_sparse_attention
-        from repro.kernels.ops import _auto_interpret
-        from repro.kernels.ref import keep_mask_to_indices
+                v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
+            out = _approx_block_attention(None, None, None, None, v, keep, valid,
+                                          head_kept, block_k=ps, scale=scale,
+                                          approx=False, scores=s)
+        elif stage3 == "pallas_paged":
+            out = _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep,
+                                           head_kept, q_pos, fetched,
+                                           hdp=hdp, ps=ps,
+                                           k_scale=k_scale if quantized else None,
+                                           v_scale=v_scale if quantized else None,
+                                           layer=layer)
+        elif stage3 == "pallas_block":
+            from repro.kernels.hdp_block_attn import hdp_block_sparse_attention
+            from repro.kernels.ops import _auto_interpret
+            from repro.kernels.ref import keep_mask_to_indices
 
-        gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
-        if quantized:
-            k = _dequant_pages(gather_pages(k_pool, gather_idx, layer),
-                               k_scale[gather_idx])
-            v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
-                               v_scale[gather_idx])
-            k = k.reshape(B, Sk, N, hd)
-            v = v.reshape(B, Sk, N, hd)
-        else:
-            k = gather_pages(k_pool, gather_idx, layer).reshape(B, Sk, N, hd)
-            v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
-        H = N * G
-        def per_head(x):  # [B,Sk,N,hd] -> [B,H,Sk,hd]
-            xh = jnp.repeat(x.transpose(0, 2, 1, 3), G, axis=1)
-            return xh
-        kq_h = per_head(quantize_fixed(k.astype(F32), hdp.int_bits,
-                                       hdp.frac_bits))
-        v_h = per_head(v)
-        qq_h = qq.reshape(B, H, Sq, hd)
-        keep_h = keep.reshape(B, H, 1, nP)
-        kv_idx, counts = keep_mask_to_indices(
-            keep_h, theta.reshape(B, H, 1, nP), nP)
-        # per-row validity: cols <= current position (replaces the kernel's
-        # aligned-self-attention causal mask, wrong for cached decode)
-        lens = (q_pos.reshape(B)[:, None] + 1) * jnp.ones((B, H), jnp.int32)
-        out = hdp_block_sparse_attention(
-            qq_h, kq_h, v_h, kv_idx, counts, head_kept.reshape(B, H),
-            causal=False, approx=hdp.approx, block_q=max(8, Sq),
-            block_k=ps, score_scale=1.0, kv_len=lens,
-            interpret=_auto_interpret(None))
-        out = out.reshape(B, N, G, Sq, hd)
-    else:
-        gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
-        cpp = max(1, page_chunk // ps)                    # pages per chunk
-        if nP <= cpp:
-            # one chunk covers the context: gather kept pages into a slab
-            # and reduce exactly like the dense-layout decode (keeps paged
-            # and dense engines token-identical on short contexts)
+            gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
             if quantized:
                 k = _dequant_pages(gather_pages(k_pool, gather_idx, layer),
                                    k_scale[gather_idx])
@@ -855,17 +822,54 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
             else:
                 k = gather_pages(k_pool, gather_idx, layer).reshape(B, Sk, N, hd)
                 v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
-            kq, _, fk = _fixed_split(k, hdp)
-            out = _approx_block_attention(qq, fq, kq, fk, v, keep, valid,
-                                          head_kept, block_k=ps, scale=scale,
-                                          approx=hdp.approx)
+            H = N * G
+            def per_head(x):  # [B,Sk,N,hd] -> [B,H,Sk,hd]
+                xh = jnp.repeat(x.transpose(0, 2, 1, 3), G, axis=1)
+                return xh
+            kq_h = per_head(quantize_fixed(k.astype(F32), hdp.int_bits,
+                                           hdp.frac_bits))
+            v_h = per_head(v)
+            qq_h = qq.reshape(B, H, Sq, hd)
+            keep_h = keep.reshape(B, H, 1, nP)
+            kv_idx, counts = keep_mask_to_indices(
+                keep_h, theta.reshape(B, H, 1, nP), nP)
+            # per-row validity: cols <= current position (replaces the kernel's
+            # aligned-self-attention causal mask, wrong for cached decode)
+            lens = (q_pos.reshape(B)[:, None] + 1) * jnp.ones((B, H), jnp.int32)
+            out = hdp_block_sparse_attention(
+                qq_h, kq_h, v_h, kv_idx, counts, head_kept.reshape(B, H),
+                causal=False, approx=hdp.approx, block_q=max(8, Sq),
+                block_k=ps, score_scale=1.0, kv_len=lens,
+                interpret=_auto_interpret(None))
+            out = out.reshape(B, N, G, Sq, hd)
         else:
-            out = _paged_scan_attention(qq, fq, k_pool, v_pool, gather_idx,
-                                        keep, valid, head_kept, hdp=hdp,
-                                        ps=ps, cpp=cpp, scale=scale,
-                                        k_scale=k_scale if quantized else None,
-                                        v_scale=v_scale if quantized else None,
-                                        layer=layer)
+            gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
+            cpp = max(1, page_chunk // ps)                    # pages per chunk
+            if nP <= cpp:
+                # one chunk covers the context: gather kept pages into a slab
+                # and reduce exactly like the dense-layout decode (keeps paged
+                # and dense engines token-identical on short contexts)
+                if quantized:
+                    k = _dequant_pages(gather_pages(k_pool, gather_idx, layer),
+                                       k_scale[gather_idx])
+                    v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
+                                       v_scale[gather_idx])
+                    k = k.reshape(B, Sk, N, hd)
+                    v = v.reshape(B, Sk, N, hd)
+                else:
+                    k = gather_pages(k_pool, gather_idx, layer).reshape(B, Sk, N, hd)
+                    v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
+                kq, _, fk = _fixed_split(k, hdp)
+                out = _approx_block_attention(qq, fq, kq, fk, v, keep, valid,
+                                              head_kept, block_k=ps, scale=scale,
+                                              approx=hdp.approx)
+            else:
+                out = _paged_scan_attention(qq, fq, k_pool, v_pool, gather_idx,
+                                            keep, valid, head_kept, hdp=hdp,
+                                            ps=ps, cpp=cpp, scale=scale,
+                                            k_scale=k_scale if quantized else None,
+                                            v_scale=v_scale if quantized else None,
+                                            layer=layer)
 
     stats = None
     if return_stats:
@@ -960,14 +964,15 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
     H, N, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     G = H // N
 
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    if cfg.qkv_bias:
-        q = q + p["bq"]
-    if cfg.qk_norm:
-        q = L.rms_norm(q, p["q_norm"])
-    q = shd(q, "batch", "seq_act", "heads_act", None)
-    if cfg.pos_emb == "rope" and enc_out is None and not static_cache:
-        q = L.apply_rope(q, positions, cfg.rope_theta)
+    with jax.named_scope("attn.proj"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+        if cfg.qkv_bias:
+            q = q + p["bq"]
+        if cfg.qk_norm:
+            q = L.rms_norm(q, p["q_norm"])
+        q = shd(q, "batch", "seq_act", "heads_act", None)
+        if cfg.pos_emb == "rope" and enc_out is None and not static_cache:
+            q = L.apply_rope(q, positions, cfg.rope_theta)
 
     new_cache = cache
     if static_cache:
@@ -975,16 +980,17 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
         k_full, v_full = cache["k"], cache["v"]
         k_pos = jnp.arange(k_full.shape[1])
     else:
-        kv_src = enc_out if enc_out is not None else x
-        k = jnp.einsum("bsd,dnk->bsnk", kv_src, p["wk"])
-        v = jnp.einsum("bsd,dnk->bsnk", kv_src, p["wv"])
-        if cfg.qkv_bias:
-            k, v = k + p["bk"], v + p["bv"]
-        if cfg.qk_norm:
-            k = L.rms_norm(k, p["k_norm"])
-        k = shd(k, "batch", "seq_act", "kv_heads", None)
-        if cfg.pos_emb == "rope" and enc_out is None:
-            k = L.apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("attn.proj"):
+            kv_src = enc_out if enc_out is not None else x
+            k = jnp.einsum("bsd,dnk->bsnk", kv_src, p["wk"])
+            v = jnp.einsum("bsd,dnk->bsnk", kv_src, p["wv"])
+            if cfg.qkv_bias:
+                k, v = k + p["bk"], v + p["bv"]
+            if cfg.qk_norm:
+                k = L.rms_norm(k, p["k_norm"])
+            k = shd(k, "batch", "seq_act", "kv_heads", None)
+            if cfg.pos_emb == "rope" and enc_out is None:
+                k = L.apply_rope(k, positions, cfg.rope_theta)
 
         if (attn is not None and attn.kv_dtype in ("int8", "fp8_v")
                 and getattr(attn, "kv_scale", "grid") != "absmax"
@@ -1001,12 +1007,13 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
             # inserted, so no write-time snap can anticipate them —
             # hot/cold bit parity is forfeited by that mode's contract
             # and the fp32 drift gate bounds the error instead
-            ib = pool_int_bits(cfg.hdp)
-            k = roundtrip_pool(k, ib).astype(k.dtype)
-            if attn.kv_dtype == "fp8_v":
-                v = v.astype(jnp.float8_e4m3fn).astype(v.dtype)
-            else:
-                v = roundtrip_pool(v, ib).astype(v.dtype)
+            with jax.named_scope("kv.write"):
+                ib = pool_int_bits(cfg.hdp)
+                k = roundtrip_pool(k, ib).astype(k.dtype)
+                if attn.kv_dtype == "fp8_v":
+                    v = v.astype(jnp.float8_e4m3fn).astype(v.dtype)
+                else:
+                    v = roundtrip_pool(v, ib).astype(v.dtype)
 
         if cache is not None and "k_pages" in cache:
             # block-paged serving cache (decode only): write the S
@@ -1017,66 +1024,67 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
             # written and read in place: no per-layer slice is copied.
             assert mode == "decode" and positions.ndim == 2, \
                 "paged cache is a decode-time serving layout"
-            li = cache.get("layer")
-            ps = cache["k_pages"].shape[-2]
-            nP = page_table.shape[1]
-            pidx = resolve_write_pages(positions, page_table, ps,
-                                       write_floor)
-            off = positions % ps
-            pool_q = cache["k_pages"].dtype == jnp.int8
-            kv_scale = getattr(attn, "kv_scale", "grid") if attn else "grid"
-            if pool_q and kv_scale == "absmax":
-                # calibrated pool: encode against the destination page's
-                # CURRENT scale (set by the prefill insert; fresh decode
-                # pages keep the static step), sanitizing NaN freed-page
-                # poison back to the static step so the encode is finite
-                ib = pool_int_bits(cfg.hdp)
-                s0 = pool_scale(ib)
-                sc = layer_view(cache)
-                ks = sc["k_scale"][pidx]                       # [B,S,N]
-                ks = jnp.where(jnp.isfinite(ks), ks, s0)[..., None]
-                k_store = encode_pool_scaled(k, ks)
-                if cache["v_pages"].dtype != jnp.int8:
-                    v_store = v.astype(cache["v_pages"].dtype)
+            with jax.named_scope("kv.write"):
+                li = cache.get("layer")
+                ps = cache["k_pages"].shape[-2]
+                nP = page_table.shape[1]
+                pidx = resolve_write_pages(positions, page_table, ps,
+                                           write_floor)
+                off = positions % ps
+                pool_q = cache["k_pages"].dtype == jnp.int8
+                kv_scale = getattr(attn, "kv_scale", "grid") if attn else "grid"
+                if pool_q and kv_scale == "absmax":
+                    # calibrated pool: encode against the destination page's
+                    # CURRENT scale (set by the prefill insert; fresh decode
+                    # pages keep the static step), sanitizing NaN freed-page
+                    # poison back to the static step so the encode is finite
+                    ib = pool_int_bits(cfg.hdp)
+                    s0 = pool_scale(ib)
+                    sc = layer_view(cache)
+                    ks = sc["k_scale"][pidx]                       # [B,S,N]
+                    ks = jnp.where(jnp.isfinite(ks), ks, s0)[..., None]
+                    k_store = encode_pool_scaled(k, ks)
+                    if cache["v_pages"].dtype != jnp.int8:
+                        v_store = v.astype(cache["v_pages"].dtype)
+                    else:
+                        vs = sc["v_scale"][pidx]
+                        vs = jnp.where(jnp.isfinite(vs), vs, s0)[..., None]
+                        v_store = encode_pool_scaled(v, vs)
+                elif pool_q:
+                    ib = pool_int_bits(cfg.hdp)
+                    k_store = encode_pool(k, ib)
+                    v_store = (v.astype(cache["v_pages"].dtype)
+                               if cache["v_pages"].dtype != jnp.int8
+                               else encode_pool(v, ib))
                 else:
-                    vs = sc["v_scale"][pidx]
-                    vs = jnp.where(jnp.isfinite(vs), vs, s0)[..., None]
-                    v_store = encode_pool_scaled(v, vs)
-            elif pool_q:
-                ib = pool_int_bits(cfg.hdp)
-                k_store = encode_pool(k, ib)
-                v_store = (v.astype(cache["v_pages"].dtype)
-                           if cache["v_pages"].dtype != jnp.int8
-                           else encode_pool(v, ib))
-            else:
-                k_store = k.astype(cache["k_pages"].dtype)
-                v_store = v.astype(cache["v_pages"].dtype)
-            new_cache = {**cache,
-                         "k_pages": write_pages(cache["k_pages"], pidx, off,
-                                                k_store, li),
-                         "v_pages": write_pages(cache["v_pages"], pidx, off,
-                                                v_store, li)}
-            if not pool_q and draft is not None \
-                    and draft.scores != "approx" \
-                    and cfg.hdp is not None and cfg.hdp.enabled:
-                # a scout-scores draft neither reads nor needs the
-                # full-precision K it would stage: later draft steps
-                # score against the scout copies, and the verify rewrites
-                # every staged position with exact K before anything else
-                # can read it — skip the dead scatter. Gated on HDP like
-                # the call descriptor (build_attn_call nulls draft
-                # without a scout): the HDP-off degraded draft runs
-                # exact attention and DOES read this K. A QUANTIZED pool
-                # inverts the optimization: the codes ARE the scout copy
-                # later draft steps stream, so the scatter is live
-                new_cache["k_pages"] = cache["k_pages"]
-            if "k_scout" in cache:
-                new_cache["k_scout"] = write_pages(
-                    cache["k_scout"], pidx, off, scout_int8(k, cfg.hdp), li)
-            if "f_scout" in cache:
-                new_cache["f_scout"] = write_pages(
-                    cache["f_scout"], pidx, off, scout_frac_int8(k, cfg.hdp),
-                    li)
+                    k_store = k.astype(cache["k_pages"].dtype)
+                    v_store = v.astype(cache["v_pages"].dtype)
+                new_cache = {**cache,
+                             "k_pages": write_pages(cache["k_pages"], pidx, off,
+                                                    k_store, li),
+                             "v_pages": write_pages(cache["v_pages"], pidx, off,
+                                                    v_store, li)}
+                if not pool_q and draft is not None \
+                        and draft.scores != "approx" \
+                        and cfg.hdp is not None and cfg.hdp.enabled:
+                    # a scout-scores draft neither reads nor needs the
+                    # full-precision K it would stage: later draft steps
+                    # score against the scout copies, and the verify rewrites
+                    # every staged position with exact K before anything else
+                    # can read it — skip the dead scatter. Gated on HDP like
+                    # the call descriptor (build_attn_call nulls draft
+                    # without a scout): the HDP-off degraded draft runs
+                    # exact attention and DOES read this K. A QUANTIZED pool
+                    # inverts the optimization: the codes ARE the scout copy
+                    # later draft steps stream, so the scatter is live
+                    new_cache["k_pages"] = cache["k_pages"]
+                if "k_scout" in cache:
+                    new_cache["k_scout"] = write_pages(
+                        cache["k_scout"], pidx, off, scout_int8(k, cfg.hdp), li)
+                if "f_scout" in cache:
+                    new_cache["f_scout"] = write_pages(
+                        cache["f_scout"], pidx, off, scout_frac_int8(k, cfg.hdp),
+                        li)
             ar = jnp.arange(nP * ps)
             k_pos = jnp.where(ar[None, :] <= positions[:, -1:], ar, -1)
             k_pos = k_pos[:, None, None, :]              # [B,1,1,nP*ps]
@@ -1085,26 +1093,28 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
             if positions.ndim == 2 and enc_out is None:
                 # per-slot positions (continuous batching): each sequence
                 # writes its cache at its own offset
-                def upd(c, kv, p0):
-                    return jax.lax.dynamic_update_slice_in_dim(c, kv, p0, 0)
-                new_cache = {
-                    "k": jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype),
-                                       positions[:, 0]),
-                    "v": jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype),
-                                       positions[:, 0]),
-                }
+                with jax.named_scope("kv.write"):
+                    def upd(c, kv, p0):
+                        return jax.lax.dynamic_update_slice_in_dim(c, kv, p0, 0)
+                    new_cache = {
+                        "k": jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype),
+                                           positions[:, 0]),
+                        "v": jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype),
+                                           positions[:, 0]),
+                    }
                 k_full, v_full = new_cache["k"], new_cache["v"]
                 ar = jnp.arange(k_full.shape[1])
                 k_pos = jnp.where(ar[None, :] <= positions[:, -1:], ar, -1)
                 k_pos = k_pos[:, None, None, :]          # [B,1,1,Smax]
             else:
-                pos0 = positions[0] if enc_out is None else 0
-                new_cache = {
-                    "k": jax.lax.dynamic_update_slice_in_dim(
-                        cache["k"], k.astype(cache["k"].dtype), pos0, 1),
-                    "v": jax.lax.dynamic_update_slice_in_dim(
-                        cache["v"], v.astype(cache["v"].dtype), pos0, 1),
-                }
+                with jax.named_scope("kv.write"):
+                    pos0 = positions[0] if enc_out is None else 0
+                    new_cache = {
+                        "k": jax.lax.dynamic_update_slice_in_dim(
+                            cache["k"], k.astype(cache["k"].dtype), pos0, 1),
+                        "v": jax.lax.dynamic_update_slice_in_dim(
+                            cache["v"], v.astype(cache["v"].dtype), pos0, 1),
+                    }
                 k_full, v_full = new_cache["k"], new_cache["v"]
                 k_pos = jnp.arange(k_full.shape[1])
                 if enc_out is None:
@@ -1144,7 +1154,8 @@ def attn_apply(cfg, p, x, *, mode: str, positions, cache=None,
             qg, k_full, v_full, call, spec=attn, q_pos=q_pos, k_pos=k_pos,
             cache=new_cache if paged else None, page_table=page_table)
 
-    o = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
-    y = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    y = shd(y, "batch", "seq_act", "embed_act")
+    with jax.named_scope("attn.proj"):
+        o = o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
+        y = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        y = shd(y, "batch", "seq_act", "embed_act")
     return y, new_cache, stats

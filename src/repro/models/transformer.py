@@ -55,10 +55,11 @@ def _block(cfg, lp, x, *, mode, positions, cache, collect_stats,
         write_floor=write_floor, attn=attn, draft=draft)
     x = x + a
     h = L.apply_norm(cfg, lp["ln2"], x)
-    if cfg.n_experts:
-        m, aux = M.moe_apply(cfg, lp["ffn"], h)
-    else:
-        m, aux = L.mlp_apply(cfg, lp["ffn"], h), jnp.zeros((), F32)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            m, aux = M.moe_apply(cfg, lp["ffn"], h)
+        else:
+            m, aux = L.mlp_apply(cfg, lp["ffn"], h), jnp.zeros((), F32)
     return x + m, new_cache, stats, aux
 
 
@@ -164,8 +165,9 @@ def apply_prefill(cfg, params, batch, cache, *, collect_stats: bool = False,
     x, new_cache, stats, _ = _stack(cfg, params, x, mode="prefill",
                                     positions=positions, cache=cache,
                                     collect_stats=collect_stats, attn=attn)
-    x = L.apply_norm(cfg, params["final_norm"], x[:, -1:])
-    logits = L.lm_logits_sharded(params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(cfg, params["final_norm"], x[:, -1:])
+        logits = L.lm_logits_sharded(params["embed"], x)
     return logits, new_cache, stats
 
 
@@ -194,8 +196,9 @@ def apply_decode(cfg, params, token, cache, pos, *, collect_stats: bool = False,
                                     page_table=page_table,
                                     write_floor=write_floor, attn=attn,
                                     draft=draft)
-    x = L.apply_norm(cfg, params["final_norm"], x)
-    logits = L.lm_logits(params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        logits = L.lm_logits(params["embed"], x)
     return logits, new_cache, stats
 
 

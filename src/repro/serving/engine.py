@@ -119,6 +119,7 @@ from repro.serving.allocator import PoolExhausted, RadixPrefixCache
 from repro.serving.faults import FaultInjector, FaultPlan, coerce_injector
 from repro.serving.scheduler import (QueueFull, SchedulerConfig,
                                      StreamScheduler)
+from repro.serving.tracing import SpanRecorder
 
 I32 = jnp.int32
 
@@ -241,6 +242,9 @@ class Result:
     uid: int
     prompt_len: int
     tokens: List[int]
+    #: host seconds of the request's ``engine.prefill`` span (a batched
+    #: group's span shared equally): the time to dispatch its prefill
+    #: programs, which run on the device asynchronously
     prefill_s: float = 0.0
     decode_steps: int = 0
     #: False when Engine.run exhausted its step budget before this request
@@ -552,6 +556,9 @@ class Engine:
         # shared read-only prefix pages; the decode write path redirects
         # anything below the floor to the scratch page
         self._floor_dev = jnp.zeros((max_batch,), I32)
+        #: host spans of the engine's phases (``engine.*``): the one
+        #: timer behind ``summary()['prefill_s']``/``['decode_s']``
+        self.spans = SpanRecorder()
         self.metrics: Dict[str, float] = self._fresh_metrics()
         #: submit() timestamps per uid (popped at finish) and the finish
         #: order log the streaming serve() generator drains
@@ -696,16 +703,17 @@ class Engine:
             logits, new_cache, stats = registry.apply_decode(
                 self.cfg, params, token, cache, pos[:, None],
                 collect_stats=self.collect_stats, attn=self.attn_spec)
-        if inject is not None:
-            # fault harness: poison the selected rows' logits so the
-            # tripwire below fires exactly as it would for organic NaNs
-            logits = jnp.where(inject[:, None, None], jnp.nan, logits)
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(I32)[:, None]
-        # per-slot tripwire: a non-finite logit row means this request's
-        # state is poisoned (overflow, stale staging read, bad page) —
-        # flag it so the host can abort ONLY that request while the rest
-        # of the batch keeps its token-identical stream
-        bad = ~jnp.isfinite(logits[:, -1]).all(axis=-1)
+        with jax.named_scope("lm_head"):
+            if inject is not None:
+                # fault harness: poison the selected rows' logits so the
+                # tripwire below fires exactly as it would for organic NaNs
+                logits = jnp.where(inject[:, None, None], jnp.nan, logits)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(I32)[:, None]
+            # per-slot tripwire: a non-finite logit row means this request's
+            # state is poisoned (overflow, stale staging read, bad page) —
+            # flag it so the host can abort ONLY that request while the rest
+            # of the batch keeps its token-identical stream
+            bad = ~jnp.isfinite(logits[:, -1]).all(axis=-1)
         return nxt, bad, new_cache, stats
 
     def _decode_loop(self, length, params, tok, cache, table, floors, pos,
@@ -1168,31 +1176,31 @@ class Engine:
             store, scatter = self.pages, jnp.asarray(page_idx)
         else:
             store, scatter = self.slots, jnp.asarray(slots, I32)
-        t0 = time.perf_counter()
-        cache = store.take()                       # donated to the jit below
-        try:
-            with self._mesh_ctx():
-                new_cache, stats = self._prefill_jit(
-                    self.params, jnp.asarray(toks), bucket, self._attn_epoch,
-                    cache, scatter)
-        except BaseException:
-            store.restore_if_undonated(cache)
-            for slot in slots:                     # roll admission back
-                if self.paged:
-                    self.pages.free(slot)
-            self._free[:0] = slots
-            self._queue[:0] = reqs
-            raise
-        store.put(new_cache)
-        self._record_stats(stats)
-        dt = time.perf_counter() - t0
-        self.metrics["prefill_s"] += dt
+        with self.spans.span("engine.prefill") as sp:
+            cache = store.take()                   # donated to the jit below
+            try:
+                with self._mesh_ctx():
+                    new_cache, stats = self._prefill_jit(
+                        self.params, jnp.asarray(toks), bucket, self._attn_epoch,
+                        cache, scatter)
+            except BaseException:
+                store.restore_if_undonated(cache)
+                for slot in slots:                 # roll admission back
+                    if self.paged:
+                        self.pages.free(slot)
+                self._free[:0] = slots
+                self._queue[:0] = reqs
+                raise
+            store.put(new_cache)
+            self._record_stats(stats)
         self.metrics["prefill_calls"] += 1
         # padded forward size — the prefill-FLOPs proxy the prefix-cache
         # A/B asserts on (wall time is too load-sensitive for CI)
         self.metrics["prefill_tokens"] += nb * bucket
+        self.metrics["prefill_prompt_tokens"] += sum(len(r.prompt)
+                                                     for r in reqs)
         for r, (req, slot) in enumerate(zip(reqs, slots)):
-            self._activate(req, slot, dt / nb)
+            self._activate(req, slot, sp.s / nb)
             if self.prefix is not None:
                 self._register_prefix(req, slot)
 
@@ -1218,6 +1226,7 @@ class Engine:
                 jnp.asarray(off, I32))
         self._record_stats(stats)
         self.metrics["prefill_tokens"] += clen
+        self.metrics["prefill_prompt_tokens"] += min(rem, clen)
         return cache, off + clen
 
     def _chunk_loop(self, prompt: np.ndarray, cache, off: int):
@@ -1235,13 +1244,11 @@ class Engine:
         registered configs serve with tau_h = 0, where the paths are
         token-identical — pinned in tests/test_paged_cache.py)."""
         prompt = np.asarray(req.prompt, np.int32)
-        t0 = time.perf_counter()
-        cache = registry.init_cache(self.cfg, 1, max_len=self.max_len)
-        cache = self._chunk_loop(prompt, cache, 0)
-        dt = time.perf_counter() - t0
-        self.metrics["prefill_s"] += dt
+        with self.spans.span("engine.prefill") as sp:
+            cache = registry.init_cache(self.cfg, 1, max_len=self.max_len)
+            cache = self._chunk_loop(prompt, cache, 0)
         self.metrics["prefill_calls"] += 1
-        self._install(req, cache, 0, dt)
+        self._install(req, cache, 0, sp.s)
 
     # ------------------------------------------------- interleaved prefill
     def _begin_stream_prefill(self, req: Request) -> Dict[str, Any]:
@@ -1270,18 +1277,17 @@ class Engine:
         identical — only the pacing differs."""
         prompt = st["prompt"]
         plen = len(prompt)
-        t0 = time.perf_counter()
-        done = 0
-        while st["off"] < plen and done < budget:
-            off0 = st["off"]
-            st["cache"], st["off"] = self._chunk_step(
-                prompt, st["cache"], off0)
-            done += st["off"] - off0
-            self.metrics["sched_chunk_tokens"] += st["off"] - off0
-        st["spent"] += time.perf_counter() - t0
+        with self.spans.span("engine.prefill") as sp:
+            done = 0
+            while st["off"] < plen and done < budget:
+                off0 = st["off"]
+                st["cache"], st["off"] = self._chunk_step(
+                    prompt, st["cache"], off0)
+                done += st["off"] - off0
+                self.metrics["sched_chunk_tokens"] += st["off"] - off0
+        st["spent"] += sp.s
         if st["off"] < plen:
             return False
-        self.metrics["prefill_s"] += st["spent"]
         self.metrics["prefill_calls"] += 1
         req, slot = st["req"], st["slot"]
         try:
@@ -1334,16 +1340,14 @@ class Engine:
         the shared span of the insert scatter is scratch-redirected."""
         m = len(shared) * self.pages.page_size
         prompt = np.asarray(req.prompt, np.int32)
-        t0 = time.perf_counter()
-        cache = self.pages.gather_prefix(shared)
-        cache = self._chunk_loop(prompt, cache, m)
-        dt = time.perf_counter() - t0
-        self.metrics["prefill_s"] += dt
+        with self.spans.span("engine.prefill") as sp:
+            cache = self.pages.gather_prefix(shared)
+            cache = self._chunk_loop(prompt, cache, m)
         self.metrics["prefill_calls"] += 1
         self.pages.assign(slot, shared + fresh, first_owned=len(shared))
         assigned.append(slot)              # slot owns every page from here
         self.pages.insert(cache, slot, 0, first_page=len(shared))
-        self._activate(req, slot, dt, floor=len(shared))
+        self._activate(req, slot, sp.s, floor=len(shared))
         self._register_prefix(req, slot)
 
     def _install_hit(self, req: Request, shared: List[int],
@@ -1397,32 +1401,34 @@ class Engine:
         lands in a slot-owned page — `floor` fences the shared prefix)
         and yields the first generated token — identical for aligned,
         bucket-padded and prefix-shared prompts."""
-        plen = len(req.prompt)
-        self._active[slot] = {"req": req, "generated": [],
-                              "act_seq": self._act_seq}
-        self._act_seq += 1
-        # prompt_len reports the ORIGINAL submission's prompt (restore
-        # resumes fold generated tokens into req.prompt)
-        res = Result(req.uid, req.orig_prompt_len or plen, [],
-                     prefill_s=prefill_s, preemptions=req.preemptions)
-        t_sub = self._t_submit.get(req.uid)
-        if t_sub is not None:
-            res.queue_wait_s = time.perf_counter() - t_sub
-        self._results[req.uid] = res
-        self._last_tok = self._last_tok.at[slot, 0].set(int(req.prompt[-1]))
-        self._pos = self._pos.at[slot].set(plen - 1)
-        self._active_dev = self._active_dev.at[slot].set(True)
-        self._remaining_dev = self._remaining_dev.at[slot].set(
-            req.max_new_tokens)
-        self._eos_dev = self._eos_dev.at[slot].set(
-            -1 if req.eos_id is None else req.eos_id)
-        self._floor_dev = self._floor_dev.at[slot].set(floor)
+        with self.spans.span("engine.activate"):
+            plen = len(req.prompt)
+            self._active[slot] = {"req": req, "generated": [],
+                                  "act_seq": self._act_seq}
+            self._act_seq += 1
+            # prompt_len reports the ORIGINAL submission's prompt (restore
+            # resumes fold generated tokens into req.prompt)
+            res = Result(req.uid, req.orig_prompt_len or plen, [],
+                         prefill_s=prefill_s, preemptions=req.preemptions)
+            t_sub = self._t_submit.get(req.uid)
+            if t_sub is not None:
+                res.queue_wait_s = time.perf_counter() - t_sub
+            self._results[req.uid] = res
+            self._last_tok = self._last_tok.at[slot, 0].set(int(req.prompt[-1]))
+            self._pos = self._pos.at[slot].set(plen - 1)
+            self._active_dev = self._active_dev.at[slot].set(True)
+            self._remaining_dev = self._remaining_dev.at[slot].set(
+                req.max_new_tokens)
+            self._eos_dev = self._eos_dev.at[slot].set(
+                -1 if req.eos_id is None else req.eos_id)
+            self._floor_dev = self._floor_dev.at[slot].set(floor)
 
     # -------------------------------------------------------------- metrics
     @staticmethod
     def _fresh_metrics() -> Dict[str, float]:
-        return {"prefill_s": 0.0, "prefill_calls": 0, "prefill_tokens": 0,
-                "decode_s": 0.0, "decode_steps": 0, "tokens_out": 0,
+        return {"prefill_calls": 0, "prefill_tokens": 0,
+                "prefill_prompt_tokens": 0, "decode_steps": 0,
+                "tokens_out": 0,
                 "block_sparsity": 0.0, "head_sparsity": 0.0,
                 "page_sparsity": 0.0, "stat_samples": 0, "page_samples": 0,
                 "cow_copies": 0, "spec_rounds": 0, "draft_tokens": 0,
@@ -1438,9 +1444,11 @@ class Engine:
                 "req_cancelled": 0, "req_deadline": 0, "req_errors": 0}
 
     def reset_metrics(self) -> None:
-        """Zero the aggregated serving metrics (e.g. after a warmup pass,
-        so reported throughput is steady-state rather than compile time)."""
+        """Zero the aggregated serving metrics and the span totals (e.g.
+        after a warmup pass, so reported throughput is steady-state rather
+        than compile time)."""
         self.metrics = self._fresh_metrics()
+        self.spans.reset()
 
     @staticmethod
     def _masked_mean(x, mask) -> float:
@@ -1460,6 +1468,13 @@ class Engine:
         not dilute the batchwise sparsity means."""
         if not self.collect_stats or stats is None:
             return
+        # engine.stats: the observer's own cost, which the benchmark takes
+        # out of the host loop's time; a prefill's sample waits here for
+        # its program to finish
+        with self.spans.span("engine.stats"):
+            self._add_stats(stats, mask)
+
+    def _add_stats(self, stats, mask) -> None:
         if mask is not None and not mask.any():
             return
         bs = getattr(stats, "block_sparsity", None)
@@ -1659,7 +1674,8 @@ class Engine:
         horizon/round), so the watchdog can only trip while the batch is
         empty with requests stuck waiting."""
         try:
-            return self._step_inner(self._cur_step)
+            with self.spans.span("engine.step"):
+                return self._step_inner(self._cur_step)
         finally:
             # one increment per step() call, raise or return — the fault
             # injector keys every hook off this counter, and _reserve
@@ -1687,11 +1703,12 @@ class Engine:
             self.faults.sleep(step_no)
         self._enforce_deadlines()
         self._maybe_retune()
-        if self.sched is not None:
-            ticked = self.sched.tick()
-            self._sample_queue_depth()
-        else:
-            self._admit()
+        with self.spans.span("engine.admit"):
+            if self.sched is not None:
+                ticked = self.sched.tick()
+                self._sample_queue_depth()
+            else:
+                self._admit()
         if not self._active:
             if self.sched is not None:
                 self.sched.watchdog(ticked)
@@ -1707,44 +1724,43 @@ class Engine:
         length = min(self.horizon, rem_max)
 
         inject = self._inject_mask(step_no)
-        t0 = time.perf_counter()
-        store = self.pages if self.paged else self.slots
-        cache = store.take()                       # donated to the jit below
-        try:
-            if self.faults is not None:
-                # the harshest crash point: the donated handle is already
-                # taken, so the unwind below must restore it or the engine
-                # dies of DonatedCacheError on the next step
-                self.faults.step_error(step_no)
-            if self.paged:
-                with self._mesh_ctx():
-                    ys, tok, new_cache, pos, active, remaining = \
-                        self._decode_jit(
-                            length, self._attn_epoch, self.params,
-                            self._last_tok, cache, self.pages.table(),
-                            self._floor_dev, self._pos, self._active_dev,
-                            self._remaining_dev, self._eos_dev, inject)
-            else:
-                ys, tok, new_cache, pos, active, remaining = self._decode_jit(
-                    length, self._attn_epoch, self.params, self._last_tok,
-                    cache, self._pos, self._active_dev, self._remaining_dev,
-                    self._eos_dev, inject)
-        except BaseException:
-            # trace/compile failures leave the donated input untouched —
-            # restore the handle so the engine stays usable and the real
-            # error surfaces instead of a later DonatedCacheError
-            store.restore_if_undonated(cache)
-            raise
-        store.put(new_cache)
-        toks_t, act_t, fault_t, stats_t = ys
-        # the single host sync of the horizon: tokens, active masks and
-        # the (tiny) per-step stats leaves come down in one device_get,
-        # and the decode clock stops after it so the stats transfer is
-        # billed to decode_s exactly like the per-token path did
-        toks_np, act_np, fault_np, stats_np = jax.device_get(
-            (toks_t, act_t, fault_t, stats_t))
+        # engine.decode is decode_s: dispatch, then the horizon's single
+        # host sync (tokens, active masks and the tiny per-step stats
+        # leaves in one device_get), which also waits out every prefill
+        # still queued on the device ahead of this decode
+        with self.spans.span("engine.decode"):
+            with self.spans.span("engine.decode.dispatch"):
+                store = self.pages if self.paged else self.slots
+                cache = store.take()               # donated to the jit below
+                try:
+                    if self.faults is not None:
+                        # the harshest crash point: the donated handle is already
+                        # taken, so the unwind below must restore it or the engine
+                        # dies of DonatedCacheError on the next step
+                        self.faults.step_error(step_no)
+                    if self.paged:
+                        with self._mesh_ctx():
+                            ys, tok, new_cache, pos, active, remaining = \
+                                self._decode_jit(
+                                    length, self._attn_epoch, self.params,
+                                    self._last_tok, cache, self.pages.table(),
+                                    self._floor_dev, self._pos, self._active_dev,
+                                    self._remaining_dev, self._eos_dev, inject)
+                    else:
+                        ys, tok, new_cache, pos, active, remaining = self._decode_jit(
+                            length, self._attn_epoch, self.params, self._last_tok,
+                            cache, self._pos, self._active_dev, self._remaining_dev,
+                            self._eos_dev, inject)
+                except BaseException:
+                    # trace/compile failures leave the donated input untouched —
+                    # restore the handle so the engine stays usable and the real
+                    # error surfaces instead of a later DonatedCacheError
+                    store.restore_if_undonated(cache)
+                    raise
+                store.put(new_cache)
+            with self.spans.span("engine.decode.wait"):
+                toks_np, act_np, fault_np, stats_np = jax.device_get(ys)
         t_sync = time.perf_counter()
-        self.metrics["decode_s"] += t_sync - t0
         any_act = act_np.any(axis=1)
         ran = int(any_act.sum())                   # steps with any active slot
         self.metrics["decode_steps"] += ran
@@ -1757,32 +1773,33 @@ class Engine:
                 self._record_stats(jax.tree.map(lambda x: x[t], stats_np),
                                    mask=act_np[t])
 
-        for t in range(length):
-            if not any_act[t]:
-                break
-            for slot in list(self._active):
-                if not act_np[t, slot]:
-                    continue
-                if fault_np[t, slot]:
-                    # tripwire: this slot's logits went non-finite — its
-                    # emitted token is garbage; abort just this request
-                    self._finish(slot, t_sync, status="error",
-                                 error="non-finite logits (per-slot "
-                                       "NaN/poison tripwire)")
-                    continue
-                st = self._active[slot]
-                req = st["req"]
-                tokn = int(toks_np[t, slot])
-                if not st["generated"]:
-                    st["t_first"] = t_sync     # TTFT at sync granularity
-                st["generated"].append(tokn)
-                self.metrics["tokens_out"] += 1
-                done = (len(st["generated"]) >= req.max_new_tokens
-                        or (req.eos_id is not None and tokn == req.eos_id))
-                if done:
-                    self._finish(slot, t_sync)
-        if self.sched is not None:
-            self.sched.watchdog(True)      # decode progressed
+        with self.spans.span("engine.drain"):
+            for t in range(length):
+                if not any_act[t]:
+                    break
+                for slot in list(self._active):
+                    if not act_np[t, slot]:
+                        continue
+                    if fault_np[t, slot]:
+                        # tripwire: this slot's logits went non-finite — its
+                        # emitted token is garbage; abort just this request
+                        self._finish(slot, t_sync, status="error",
+                                     error="non-finite logits (per-slot "
+                                           "NaN/poison tripwire)")
+                        continue
+                    st = self._active[slot]
+                    req = st["req"]
+                    tokn = int(toks_np[t, slot])
+                    if not st["generated"]:
+                        st["t_first"] = t_sync  # TTFT at sync granularity
+                    st["generated"].append(tokn)
+                    self.metrics["tokens_out"] += 1
+                    done = (len(st["generated"]) >= req.max_new_tokens
+                            or (req.eos_id is not None and tokn == req.eos_id))
+                    if done:
+                        self._finish(slot, t_sync)
+            if self.sched is not None:
+                self.sched.watchdog(True)  # decode progressed
         return n_stepped
 
     def _spec_step(self, n_stepped: int, step_no: int) -> int:
@@ -1805,34 +1822,33 @@ class Engine:
         else:
             k, profile = min(self.draft_len, rem_max), self.draft_profile
         inject = self._inject_mask(step_no)
-        t0 = time.perf_counter()
-        store = self.pages if self.paged else self.slots
-        cache = store.take()                       # donated to the jit below
-        try:
-            if self.faults is not None:
-                self.faults.step_error(step_no)
-            if self.paged:
-                with self._mesh_ctx():
-                    ys, tok, new_cache, pos, active, remaining = \
-                        self._spec_jit(
+        with self.spans.span("engine.decode"):
+            with self.spans.span("engine.decode.dispatch"):
+                store = self.pages if self.paged else self.slots
+                cache = store.take()               # donated to the jit below
+                try:
+                    if self.faults is not None:
+                        self.faults.step_error(step_no)
+                    if self.paged:
+                        with self._mesh_ctx():
+                            ys, tok, new_cache, pos, active, remaining = \
+                                self._spec_jit(
+                                    k, profile, self._attn_epoch, self.params,
+                                    self._last_tok, cache, self.pages.table(),
+                                    self._floor_dev, self._pos, self._active_dev,
+                                    self._remaining_dev, self._eos_dev, inject)
+                    else:
+                        ys, tok, new_cache, pos, active, remaining = self._spec_jit(
                             k, profile, self._attn_epoch, self.params,
-                            self._last_tok, cache, self.pages.table(),
-                            self._floor_dev, self._pos, self._active_dev,
+                            self._last_tok, cache, self._pos, self._active_dev,
                             self._remaining_dev, self._eos_dev, inject)
-            else:
-                ys, tok, new_cache, pos, active, remaining = self._spec_jit(
-                    k, profile, self._attn_epoch, self.params,
-                    self._last_tok, cache, self._pos, self._active_dev,
-                    self._remaining_dev, self._eos_dev, inject)
-        except BaseException:
-            store.restore_if_undonated(cache)
-            raise
-        store.put(new_cache)
-        toks_t, com_t, fault_t, stats_t = ys
-        toks_np, com_np, fault_np, stats_np = jax.device_get(
-            (toks_t, com_t, fault_t, stats_t))
+                except BaseException:
+                    store.restore_if_undonated(cache)
+                    raise
+                store.put(new_cache)
+            with self.spans.span("engine.decode.wait"):
+                toks_np, com_np, fault_np, stats_np = jax.device_get(ys)
         t_sync = time.perf_counter()
-        self.metrics["decode_s"] += t_sync - t0
         n_act = len(self._active)
         n_fault = int(fault_np.sum())
         self.metrics["spec_rounds"] += 1
@@ -1855,32 +1871,33 @@ class Engine:
             # one verify sample per round, masked to the slots that
             # actually decoded (com_np.any(0) == the pre-round active set)
             self._record_stats(stats_np, mask=com_np.any(axis=0))
-        for t in range(k):
-            if not com_np[t].any():
-                break
+        with self.spans.span("engine.drain"):
+            for t in range(k):
+                if not com_np[t].any():
+                    break
+                for slot in list(self._active):
+                    if not com_np[t, slot]:
+                        continue
+                    st = self._active[slot]
+                    req = st["req"]
+                    tokn = int(toks_np[t, slot])
+                    if not st["generated"]:
+                        st["t_first"] = t_sync  # TTFT at sync granularity
+                    st["generated"].append(tokn)
+                    self.metrics["tokens_out"] += 1
+                    done = (len(st["generated"]) >= req.max_new_tokens
+                            or (req.eos_id is not None and tokn == req.eos_id))
+                    if done:
+                        self._finish(slot, t_sync)
+            # faulted rows committed nothing this round (the tripwire fires at
+            # verify, before any accept) — abort them after the commit drain
             for slot in list(self._active):
-                if not com_np[t, slot]:
-                    continue
-                st = self._active[slot]
-                req = st["req"]
-                tokn = int(toks_np[t, slot])
-                if not st["generated"]:
-                    st["t_first"] = t_sync     # TTFT at sync granularity
-                st["generated"].append(tokn)
-                self.metrics["tokens_out"] += 1
-                done = (len(st["generated"]) >= req.max_new_tokens
-                        or (req.eos_id is not None and tokn == req.eos_id))
-                if done:
-                    self._finish(slot, t_sync)
-        # faulted rows committed nothing this round (the tripwire fires at
-        # verify, before any accept) — abort them after the commit drain
-        for slot in list(self._active):
-            if fault_np[slot]:
-                self._finish(slot, t_sync, status="error",
-                             error="non-finite logits (per-slot NaN/poison "
-                                   "tripwire)")
-        if self.sched is not None:
-            self.sched.watchdog(True)      # decode progressed
+                if fault_np[slot]:
+                    self._finish(slot, t_sync, status="error",
+                                 error="non-finite logits (per-slot NaN/poison "
+                                       "tripwire)")
+            if self.sched is not None:
+                self.sched.watchdog(True)  # decode progressed
         return n_stepped
 
     def _n_pending(self) -> int:
@@ -2005,7 +2022,18 @@ class Engine:
 
     # ------------------------------------------------------------- reporting
     def summary(self) -> Dict[str, float]:
+        """The metrics, with the span totals (``spans``: name -> seconds
+        and count) and what derives from them. ``prefill_s`` is host time
+        in the ``engine.prefill`` spans: the time to dispatch the prefill
+        programs (they run on the device asynchronously; with
+        ``collect_stats`` their stats fetch waits for them). ``decode_s`` is
+        host time in the ``engine.decode`` spans, dispatch to the end of
+        the blocking fetch: the decode programs' device time plus that of
+        every prefill queued on the device before them."""
         m = dict(self.metrics)
+        m["prefill_s"] = self.spans.total("engine.prefill")
+        m["decode_s"] = self.spans.total("engine.decode")
+        m["spans"] = self.spans.totals()
         if m["decode_s"] > 0:
             m["decode_tok_s"] = m["tokens_out"] / m["decode_s"]
         if m["stat_samples"]:

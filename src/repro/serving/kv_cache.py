@@ -494,6 +494,11 @@ class PagedKVCache(_DonatableCache):
         return kr[:, :npg * ps].reshape(L, npg, ps, N, hd)
 
     def _insert_fn(self, pool, k, v, idx, row):
+        """`_insert_pages` under the ``kv.write`` named scope."""
+        with jax.named_scope("kv.write"):
+            return self._insert_pages(pool, k, v, idx, row)
+
+    def _insert_pages(self, pool, k, v, idx, row):
         """Scatter one request-cache row into the (donated) pool.
 
         `idx` [pages_per_slot] holds the destination pool page per cache
