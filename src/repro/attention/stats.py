@@ -25,12 +25,18 @@ class AttnStats:
     head_sparsity: scalar pruned-head fraction.
     theta_head: per-head importances [..., heads-shaped] (optional).
     page_sparsity: scalar never-fetched page fraction (paged decode only).
+    kernel_pages: kept pages the Pallas paged decode kernel visits, per
+        row (that kernel only).
+    kernel_block_pages: pages of the compute blocks it visits, per row:
+        ``ceil(kernel_pages / ppb) * ppb``.
     """
 
     block_sparsity: jnp.ndarray
     head_sparsity: jnp.ndarray
     theta_head: Optional[jnp.ndarray] = None
     page_sparsity: Optional[jnp.ndarray] = None
+    kernel_pages: Optional[jnp.ndarray] = None
+    kernel_block_pages: Optional[jnp.ndarray] = None
 
     # dict-style compat with the pre-registry stats consumers
     def __getitem__(self, key: str):
@@ -52,7 +58,7 @@ class AttnStats:
 jax.tree_util.register_dataclass(
     AttnStats,
     data_fields=("block_sparsity", "head_sparsity", "theta_head",
-                 "page_sparsity"),
+                 "page_sparsity", "kernel_pages", "kernel_block_pages"),
     meta_fields=())
 
 
@@ -65,7 +71,9 @@ def normalize_stats(raw: Any) -> Optional[AttnStats]:
             block_sparsity=jnp.asarray(raw["block_sparsity"]),
             head_sparsity=jnp.asarray(raw["head_sparsity"]),
             theta_head=raw.get("theta_head"),
-            page_sparsity=raw.get("page_sparsity"))
+            page_sparsity=raw.get("page_sparsity"),
+            kernel_pages=raw.get("kernel_pages"),
+            kernel_block_pages=raw.get("kernel_block_pages"))
     # core.hdp.HDPStats-shaped object (attribute access)
     return AttnStats(
         block_sparsity=jnp.asarray(raw.block_sparsity),

@@ -147,7 +147,13 @@ def tp_paged_attention(q, call, spec, *, q_pos, k_pos, cache, page_table,
                             gather(stats.theta_head, "model", axis=1,
                                    tiled=True)),
                 page_sparsity=(None if stats.page_sparsity is None else
-                               jax.lax.pmean(stats.page_sparsity, "model")))
+                               jax.lax.pmean(stats.page_sparsity, "model")),
+                # each shard's kernel walks its own kept pages
+                kernel_pages=(None if stats.kernel_pages is None else
+                              jax.lax.psum(stats.kernel_pages, "model")),
+                kernel_block_pages=(
+                    None if stats.kernel_block_pages is None else
+                    jax.lax.psum(stats.kernel_block_pages, "model")))
         return out, stats
 
     # stats presence/fields are call-static — derive the output pytree

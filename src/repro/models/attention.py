@@ -581,11 +581,14 @@ def _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep, head_kept,
 
     Compresses the OR-over-heads (and, for multi-query verify, OR-over-
     query-rows) page fetch list to (pool page ids, logical slot
-    positions, counts) — the scalar-prefetch arrays whose values drive
-    the kernel's K/V BlockSpec index maps, so surviving pages stream
-    straight from the pool and pruned pages are never DMA'd (no gathered
-    intermediate at all). A verify call streams each surviving page once
-    for ALL Sq query rows — the pool is read once per round.
+    positions, counts) — the scalar-prefetch arrays from which the
+    kernel DMAs each slot's kept pages, a compute block of pages at a
+    time, straight from the pool: pruned pages and table columns past a
+    row's count are never DMA'd (no gathered intermediate at all). The
+    per-head, per-row keep of each listed page reaches the kernel in list
+    order ([B, N, G, Sq, mk], through VMEM). A verify call streams each
+    surviving page once for ALL Sq query rows — the pool is read once per
+    round.
     """
     from repro.kernels.hdp_paged_decode import hdp_paged_fum_decode
     from repro.kernels.ops import _auto_interpret
@@ -608,12 +611,11 @@ def _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep, head_kept,
                          jnp.take_along_axis(table, logical, axis=1), 0)
     keep_sel = jnp.take_along_axis(
         keep_q, logical[:, None, None, None, :], axis=-1)
-    keep_in = keep_sel.transpose(0, 4, 1, 2, 3).astype(jnp.int32)
     # row 0's extent; the kernel adds the query index (consecutive rows)
     kv_len = (q_pos.reshape(B, Sq)[:, 0] + 1).astype(jnp.int32)
     out = hdp_paged_fum_decode(
         qq, k_pool, v_pool, page_ids, logical, counts,
-        keep_in, kv_len, approx=hdp.approx, int_bits=hdp.int_bits,
+        keep_sel, kv_len, approx=hdp.approx, int_bits=hdp.int_bits,
         frac_bits=hdp.frac_bits, k_scale=k_scale, v_scale=v_scale,
         layer=layer, interpret=_auto_interpret(None))
     return _head_gate(out, head_kept)
@@ -721,6 +723,7 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                 for x in (k_pool, v_pool, ik_pool, fk_pool))
             layer = None
 
+    kernel_stats = {}       # what the Pallas paged kernel walks, if it runs
     # ---- stage 1: integer scout on the always-streamed int8 copy ----
     with jax.named_scope("hdp.scout"):
         if absmax:
@@ -800,6 +803,12 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                           head_kept, block_k=ps, scale=scale,
                                           approx=False, scores=s)
         elif stage3 == "pallas_paged":
+            from repro.kernels.hdp_paged_decode import pages_per_block
+            ppb = pages_per_block(nP, k_pool, v_pool)
+            kernel_pages = fetched.sum(-1).astype(F32)
+            kernel_stats = {"kernel_pages": kernel_pages,
+                            "kernel_block_pages":
+                                jnp.ceil(kernel_pages / ppb) * ppb}
             out = _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep,
                                            head_kept, q_pos, fetched,
                                            hdp=hdp, ps=ps,
@@ -877,7 +886,7 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
         stats = {**_block_sparsity_stats(keep, bvalid, head_kept),
                  "page_sparsity": 1.0 - jnp.minimum(
                      (fetched & (table > 0)).astype(F32).sum(-1) / alloc, 1.0),
-                 "theta_head": theta_head}
+                 "theta_head": theta_head, **kernel_stats}
     return out.astype(q.dtype), stats
 
 

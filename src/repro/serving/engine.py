@@ -1431,6 +1431,7 @@ class Engine:
                 "tokens_out": 0,
                 "block_sparsity": 0.0, "head_sparsity": 0.0,
                 "page_sparsity": 0.0, "stat_samples": 0, "page_samples": 0,
+                "kernel_pages": 0.0, "kernel_block_pages": 0.0,
                 "cow_copies": 0, "spec_rounds": 0, "draft_tokens": 0,
                 "accepted_tokens": 0,
                 # stream-scheduler counters (zero when it is off)
@@ -1451,14 +1452,19 @@ class Engine:
         self.spans.reset()
 
     @staticmethod
-    def _masked_mean(x, mask) -> float:
-        """Mean over real samples: per-slot decode leaves are [L, B] and
-        the active mask drops parked slots; prefill leaves ([L] scalars
-        per layer, exact-size stacking — every row real) pass through."""
+    def _real_samples(x, mask) -> np.ndarray:
+        """The real samples of a stats leaf: per-slot decode leaves are
+        [L, B] and the active mask drops parked slots; prefill leaves ([L]
+        scalars per layer, exact-size stacking — every row real) pass
+        through."""
         x = np.asarray(x)
         if mask is not None and x.ndim >= 2 and x.shape[-1] == len(mask):
             x = x[..., mask]
-        return float(np.mean(x))
+        return x
+
+    @classmethod
+    def _masked_mean(cls, x, mask) -> float:
+        return float(np.mean(cls._real_samples(x, mask)))
 
     def _record_stats(self, stats, mask=None) -> None:
         """Accumulate one AttnStats sample (leaves carry a layer dim).
@@ -1499,6 +1505,10 @@ class Engine:
                 # decode sparsity (prefill samples carry no page field
                 # and would skew the decode-centric EMA)
                 self.tuner.observe_sparsity(b_mean, h_mean, p_mean)
+        if getattr(stats, "kernel_block_pages", None) is not None:
+            for k in ("kernel_pages", "kernel_block_pages"):
+                m[k] += float(np.sum(self._real_samples(getattr(stats, k),
+                                                        mask)))
         m["stat_samples"] += 1
 
     def _finish(self, slot: int, now: Optional[float] = None, *,
@@ -2029,7 +2039,12 @@ class Engine:
         ``collect_stats`` their stats fetch waits for them). ``decode_s`` is
         host time in the ``engine.decode`` spans, dispatch to the end of
         the blocking fetch: the decode programs' device time plus that of
-        every prefill queued on the device before them."""
+        every prefill queued on the device before them. With
+        ``collect_stats`` and the Pallas paged decode kernel,
+        ``paged_block_fill`` is the kept pages the kernel visited over the
+        pages of the compute blocks it visited (``kernel_pages`` /
+        ``kernel_block_pages``): the share of its block work that is not
+        padding of a row's last block."""
         m = dict(self.metrics)
         m["prefill_s"] = self.spans.total("engine.prefill")
         m["decode_s"] = self.spans.total("engine.decode")
@@ -2041,6 +2056,9 @@ class Engine:
             m["head_sparsity"] /= m["stat_samples"]
         if m["page_samples"]:
             m["page_sparsity"] /= m["page_samples"]
+        if m["kernel_block_pages"]:
+            m["paged_block_fill"] = (m["kernel_pages"]
+                                     / m["kernel_block_pages"])
         m["stream_sched"] = self.sched is not None
         if m.pop("queue_depth_samples") and self.sched is not None:
             m["queue_depth_mean"] = (m.pop("queue_depth_sum")

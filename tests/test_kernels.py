@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import HDPConfig, hdp_attention
-from repro.core.quant import quantize_fixed
+from repro.core.quant import POISON_CODE, quantize_fixed
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.hdp_block_attn import hdp_block_sparse_attention
@@ -159,3 +159,117 @@ class TestHDPPipeline:
         cos = float((exact * capped).sum() /
                     (jnp.linalg.norm(exact) * jnp.linalg.norm(capped) + 1e-9))
         assert cos > 0.8
+
+
+# ------------------------------------------------- paged FUM decode kernel
+# one batch holds rows of 0, 1, ppb-1, ppb, ppb+1 and every (mk) kept
+# page: mk = 9 table columns give ppb = 3 pages a compute block. The
+# full row comes first, so a later row's partial last block lands on the
+# double buffer that holds the full row's last page.
+PAGED_MK = 9
+PAGED_COUNTS = (9, 1, 2, 0, 3, 4)
+
+
+def _paged_case(pool_dtype, sq, seed=0):
+    """Stage-3 inputs over a page pool: per-head, per-row keep of each
+    row's ``PAGED_COUNTS`` fetched pages (row 0 keeps every page for
+    every head and row), extents ending inside each row's last page."""
+    from repro.core.quant import encode_pool, pool_scale
+    from repro.kernels.hdp_paged_decode import pages_per_block
+    from repro.models.attention import _fixed_split
+
+    B, N, G, hd, ps, nP = len(PAGED_COUNTS), 2, 2, 8, 4, PAGED_MK
+    P = 1 + B * nP
+    rng = np.random.default_rng(seed)
+    hdp = HDPConfig(block_q=1, block_k=ps, rho_b=0.5, causal=True,
+                    head_pruning=False, calib="none")
+    table = np.arange(1, P, dtype=np.int32).reshape(B, nP)
+    fetched = np.zeros((B, nP), bool)
+    for b, c in enumerate(PAGED_COUNTS):
+        fetched[b, rng.choice(nP, size=c, replace=False)] = True
+    keep = (rng.random((B, N, G, sq, nP)) < 0.6) & fetched[:, None, None, None]
+    keep[:, 0, 0, 0] |= fetched
+    keep[0] = True
+    q = rnd(B, N, G, sq, hd, seed=seed + 1)
+    ks = rnd(P, N, ps, hd, seed=seed + 2)
+    vs = rnd(P, N, ps, hd, seed=seed + 3)
+    kw = {}
+    if pool_dtype == "int8":
+        ks, vs = encode_pool(ks, hdp.int_bits), encode_pool(vs, hdp.int_bits)
+        s0 = jnp.full((P, N), pool_scale(hdp.int_bits), jnp.float32)
+        kw = dict(k_scale=s0, v_scale=s0)
+    else:
+        ks, vs = ks.astype(pool_dtype), vs.astype(pool_dtype)
+    assert pages_per_block(nP, ks, vs) == 3
+    base = rng.integers((nP - 1) * ps, nP * ps - sq + 1, size=B)
+    q_pos = jnp.asarray(base[:, None] + np.arange(sq), jnp.int32)[:, None, None]
+    k_pos = jnp.arange(nP * ps, dtype=jnp.int32)[None, None, None]
+    qq, _, fq = _fixed_split(q, hdp)
+    return dict(qq=qq, fq=fq, k=ks, v=vs, table=jnp.asarray(table),
+                keep=jnp.asarray(keep), fetched=jnp.asarray(fetched),
+                q_pos=q_pos, k_pos=k_pos, hdp=hdp, ps=ps, **kw)
+
+
+def _paged_kernel(c, **over):
+    from repro.models.attention import _paged_fum_kernel_stage3
+    c = {**c, **over}
+    B, N, G = c["qq"].shape[:3]
+    return _paged_fum_kernel_stage3(
+        c["qq"], c["k"], c["v"], c["table"], c["keep"],
+        jnp.ones((B, N, G), bool), c["q_pos"], c["fetched"], hdp=c["hdp"],
+        ps=c["ps"], k_scale=c.get("k_scale"), v_scale=c.get("v_scale"))
+
+
+PAGED_POOLS = [("int8", 1e-5), ("float32", 1e-5), ("bfloat16", 2e-2)]
+
+
+class TestPagedFumKernel:
+    @pytest.mark.parametrize("sq", [1, 4])
+    @pytest.mark.parametrize("pool_dtype,tol", PAGED_POOLS)
+    def test_matches_xla_stage3(self, pool_dtype, tol, sq):
+        """The block walk against the XLA stage 3 (the page-chunk scan in
+        one chunk) on the same fetched pages, keep masks and extents."""
+        from repro.models.attention import _mask_bias, _paged_scan_attention
+        c = _paged_case(pool_dtype, sq)
+        out = _paged_kernel(c)
+        B, N, G, _, hd = c["qq"].shape
+        want = _paged_scan_attention(
+            c["qq"], c["fq"], c["k"], c["v"],
+            jnp.where(c["fetched"], c["table"], 0), c["keep"],
+            _mask_bias(c["q_pos"], c["k_pos"], True, 0),
+            jnp.ones((B, N, G), bool), hdp=c["hdp"], ps=c["ps"],
+            cpp=PAGED_MK, scale=1.0 / hd ** 0.5, k_scale=c.get("k_scale"),
+            v_scale=c.get("v_scale"))
+        assert bool(jnp.isfinite(out).all())
+        np.testing.assert_array_equal(np.asarray(out[3]), 0.0)   # no page
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("sq", [1, 4])
+    @pytest.mark.parametrize("pool_dtype", [p for p, _ in PAGED_POOLS])
+    def test_poison(self, pool_dtype, sq):
+        """Pruned pages poisoned through every channel (NaN / sentinel
+        codes, NaN scales) leave every output bit unchanged; a poisoned
+        kept page trips NaN in its row alone — the rows after it whose
+        last block reuses its buffer stay exact."""
+        c = _paged_case(pool_dtype, sq)
+        clean = np.asarray(_paged_kernel(c))
+        table, fetched = np.asarray(c["table"]), np.asarray(c["fetched"])
+        pruned = jnp.asarray(table[~fetched])
+        kept = int(table[0, -1])              # row 0's last page, kept
+
+        def poison(pages):
+            if pool_dtype == "int8":
+                return dict(
+                    k=c["k"].at[pages].set(POISON_CODE),
+                    v=c["v"].at[pages].set(POISON_CODE),
+                    k_scale=c["k_scale"].at[pages].set(jnp.nan),
+                    v_scale=c["v_scale"].at[pages].set(jnp.nan))
+            return dict(k=c["k"].at[pages].set(jnp.nan),
+                        v=c["v"].at[pages].set(jnp.nan))
+
+        np.testing.assert_array_equal(
+            np.asarray(_paged_kernel(c, **poison(pruned))), clean)
+        bad = np.asarray(_paged_kernel(c, **poison(kept)))
+        assert np.isnan(bad[0]).all(), "a poisoned kept page did not trip"
+        np.testing.assert_array_equal(bad[1:], clean[1:])

@@ -132,6 +132,22 @@ def test_paged_engine_emits_page_stats():
     assert s["cache_bytes"] <= s["cache_bytes_pool"]
 
 
+def test_paged_kernel_reports_block_fill():
+    """With stats on, the Pallas paged kernel's route counts the kept
+    pages it walks and the pages of its compute blocks; ``summary()``
+    gives their ratio as ``paged_block_fill``. The XLA stage walks no
+    blocks and reports none."""
+    cfg = _qwen()
+    eng, toks = _serve(cfg, None, _prompts(3, seed=5), collect_stats=True,
+                       attn=AttnSpec(backend="pallas"))
+    s = eng.summary()
+    assert 0 < s["kernel_pages"] <= s["kernel_block_pages"]
+    assert s["paged_block_fill"] == s["kernel_pages"] / s["kernel_block_pages"]
+    eng, xla = _serve(cfg, eng.params, _prompts(3, seed=5), collect_stats=True)
+    assert "paged_block_fill" not in eng.summary()
+    assert xla == toks
+
+
 # ------------------------------------------------------------ FUM contract
 def test_pruned_pages_never_gathered():
     """Poisoning pruned pages' full-precision K/V cannot change the output."""
