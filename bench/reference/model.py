@@ -7,7 +7,9 @@ program under test: the configuration file gives the sizes, and
 ``bench.weights`` makes the weights again from the seed. It computes, for
 one prompt and the tokens the engine served for it, the logits at every
 served position, so the caller can read how far each served token lies
-below the reference's best.
+below the reference's best. It computes on one device, whatever layout
+the weights arrive in: the embedding, each layer's slice and the LM head
+are moved to that device before they are used.
 
 What it implements, beside the model (RMSNorm, RoPE, GQA attention with
 q/k/v biases where the model has them, SiLU-GLU MLP, the LM head):
@@ -318,19 +320,23 @@ def served_logits(config: dict, params, prompt: Sequence[int],
     static = (tuple(sorted(D.items())), tuple(sorted(hdp.items())), precision,
               len(chunks) + 1, config["torch_dtype"])
 
-    emb = params["embed"]["tok"]
+    # each piece moves to the one device before use (a no-op for weights
+    # already on it)
+    dev = min(params["embed"]["tok"].devices(), key=lambda d: d.id)
+    put = functools.partial(jax.device_put, device=dev)
+    emb = put(params["embed"]["tok"])
     with jax.default_matmul_precision("highest"):
-        x_pf = emb[jnp.asarray(tok_pf)].astype(F32)
-        x_d = emb[jnp.asarray(tok_d)].astype(F32)
-        args = (jnp.asarray(pos_pf), jnp.asarray(pos_d),
-                jnp.asarray(chunk_of_block), jnp.asarray(plen, jnp.int32))
+        x_pf = emb[put(tok_pf)].astype(F32)
+        x_d = emb[put(tok_d)].astype(F32)
+        args = (put(pos_pf), put(pos_d), put(chunk_of_block),
+                put(np.int32(plen)))
         for li in range(D["L"]):
-            lp = jax.tree.map(lambda w: w[li], params["layers"])
+            lp = jax.tree.map(lambda w: put(w[li]), params["layers"])
             x_pf, x_d = _layer(lp, x_pf, x_d, *args, static=static)
-        del x_pf
+        del x_pf, lp
         lm = params["embed"].get("lm_head")
-        if lm is None:
-            lm = params["embed"]["tok"].T
+        lm = emb.T if lm is None else put(lm)
+        w_norm = put(params["final_norm"]["w"])
         sv = np.zeros(n_b, np.int32)
         sv[:n] = served
         ex = np.zeros(n_b, np.int32)
@@ -339,9 +345,8 @@ def served_logits(config: dict, params, prompt: Sequence[int],
         outs = []
         for i in range(0, n_b, QB):
             outs.append(jax.device_get(_head(
-                x_d[i:i + QB], params["final_norm"]["w"], lm,
-                jnp.asarray(sv[i:i + QB]), jnp.asarray(ex[i:i + QB]),
-                eps=D["eps"], precision=precision)))
+                x_d[i:i + QB], w_norm, lm, put(sv[i:i + QB]),
+                put(ex[i:i + QB]), eps=D["eps"], precision=precision)))
     mx, am, at_s, at_e = (np.concatenate([o[j] for o in outs])[:n]
                           for j in range(4))
     return {"max": mx.astype(np.float64), "argmax": am.astype(np.int64),

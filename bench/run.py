@@ -12,6 +12,15 @@ to steady state where the mix asks for it, and then drives the engine for
 ``--seconds`` from the mix's clients. Set-up (``setup_s``) is the time
 from process start to the window's opening.
 
+A cell's ``chips`` is 1 or 4, and is its configuration's
+``deployment.chips``. A four-chip cell runs as one tensor-parallel engine
+over its chips: the engine gets the serving mesh ``(data=1, model=4)``
+(``repro.launch.mesh.make_serving_mesh``), and the weights are made on
+that mesh in the program's own tensor-parallel layout (``bench/weights.py``),
+with the values a one-chip make gives. The reference stays on one
+device: it moves the embedding, each layer and the LM head there in turn.
+A one-chip cell gets no mesh.
+
 ``--trace 0`` reports the cell's end-to-end metrics. ``--trace 1`` is a
 run of its own with the engine's HDP statistics on: it profiles the
 window (at most ``TRACE_S`` seconds of it) and reports the per-layer
@@ -74,6 +83,9 @@ KERNELS = ["hdp_paged_fum_decode"]
 #: arrivals that fall due together wait for the next step beyond it); the
 #: warm-up compiles every prefill group size up to it
 SUBMIT_CAP = 4
+#: the chips a cell may ask for: one, or one host's four as one
+#: tensor-parallel engine
+CHIPS = (1, 4)
 
 
 class NoChip(RuntimeError):
@@ -111,7 +123,18 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     mix = json.loads((root / "bench" / "traffic" / f"{wl['traffic']}.json")
                      .read_text())
     limits = json.loads((root / "bench" / "limits" / f"{name}.json").read_text())
-    return Cell(name, int(wl["chips"]), config, mix,
+    chips = int(wl["chips"])
+    if chips not in CHIPS:
+        raise SystemExit(f"{name}: chips {chips}; a cell runs on one of {CHIPS}")
+    if chips != config["deployment"]["chips"]:
+        raise SystemExit(f"{name}: chips {chips}, but its configuration "
+                         f"{wl['config']!r} is deployed on "
+                         f"{config['deployment']['chips']}")
+    if config["num_key_value_heads"] % chips:
+        raise SystemExit(f"{name}: {chips} chips do not divide the "
+                         f"{config['num_key_value_heads']} kv heads "
+                         f"(tensor parallelism shards them)")
+    return Cell(name, chips, config, mix,
                 _for_cell(spec["end_to_end"], name),
                 _for_cell(spec["per_layer"], name), limits)
 
@@ -145,6 +168,13 @@ def check_devices(chips: int):
     return devs[:chips]
 
 
+def peak_bytes(devices) -> int:
+    """The peak of device memory in use on the fullest of ``devices``
+    (0 where the backend does not report it)."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
+
+
 # ------------------------------------------------------------ statistics
 def percentile(values: List[float], q: float) -> float:
     """Nearest-rank percentile; a failed request is +inf."""
@@ -155,33 +185,21 @@ def percentile(values: List[float], q: float) -> float:
 
 
 # ---------------------------------------------------------------- engine
-def program_config(config: dict):
-    """The program's ModelConfig for a configuration file: the registered
-    model, with every size, the RoPE base, the window, the dtype and the
-    HDP settings taken from the file (the file is what is run)."""
-    from repro.configs import get_config
-    from repro.core.config import HDPConfig
-
-    from bench.weights import dims
-
-    D = dims(config)
-    return get_config(config["model"]).replace(
-        n_layers=D["L"], d_model=D["d"], n_heads=D["H"], n_kv_heads=D["N"],
-        head_dim=D["hd"], d_ff=D["f"], vocab_size=D["V"],
-        sliding_window=D["window"], tie_embeddings=D["tied"],
-        qkv_bias=D["bias"], rope_theta=D["rope_theta"],
-        dtype=config["torch_dtype"], hdp=HDPConfig(**config["hdp"]))
-
-
-def build_engine(cell: Cell, seed: int, collect_stats: bool):
+def build_engine(cell: Cell, seed: int, collect_stats: bool, devices):
+    """The engine of ``cell`` on ``devices`` (its chips), with the seed's
+    weights: one chip unsharded, four as one tensor-parallel engine on the
+    serving mesh (data=1, model=chips)."""
     import jax
+    from repro.launch.mesh import make_serving_mesh
     from repro.models import registry
     from repro.serving import Engine
 
-    from bench.weights import make_params
+    from bench.weights import make_params, program_config
 
     cfg = program_config(cell.config)
-    params = make_params(cell.config, seed)
+    mesh = (make_serving_mesh(tp=cell.chips, devices=devices)
+            if cell.chips > 1 else None)
+    params = make_params(cell.config, seed, mesh)
     want = jax.tree.map(lambda a: (a.shape, a.dtype),
                         registry.abstract_params(cfg)[0])
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
@@ -189,11 +207,12 @@ def build_engine(cell: Cell, seed: int, collect_stats: bool):
         raise RuntimeError("benchmark weights do not match the program's "
                            "parameter layout")
     jax.block_until_ready(params)
+    log(f"weights made: peak bytes per chip {peak_bytes(devices)}")
     dep = cell.config["deployment"]
     eng = Engine(cfg, params=params, max_batch=dep["max_batch"],
                  max_len=dep["max_len"],
                  prefill_buckets=tuple(dep["prefill_buckets"]),
-                 collect_stats=collect_stats)
+                 collect_stats=collect_stats, mesh=mesh)
     if eng.kv_dtype != dep["kv_pool"]["dtype"] or \
             eng.pages.page_size != dep["page_size"]:
         raise RuntimeError(f"engine pool {eng.kv_dtype}/{eng.pages.page_size}"
@@ -450,7 +469,7 @@ def sample_for_check(drv: Driver, traffic: Traffic, mix: dict,
 
 
 def reference_check(config: dict, seed: int, picks: List[dict],
-                    control: bool = False) -> dict:
+                    control: bool = False, mesh=None) -> dict:
     """How far the served tokens of ``picks`` lie below the float32
     reference's best logit, position by position: ``mean_gap`` (the
     number compared: the mean over every compared position), with the
@@ -461,12 +480,15 @@ def reference_check(config: dict, seed: int, picks: List[dict],
     served position the token that the reference one precision step
     below the configuration's dtype puts first replaces the served token,
     and the numbers are the control's; the program's are kept beside
-    them under ``program``."""
+    them under ``program``.
+
+    The weights are made again on the engine's ``mesh`` (None: one
+    device); the reference computes on one device whatever their layout."""
     import jax
     from bench.reference.model import CONTROL, served_logits
     from bench.weights import make_params
 
-    params = make_params(config, seed)
+    params = make_params(config, seed, mesh)
     gaps, prog, per = [], [], []
     for r in picks:
         ctrl = None
@@ -547,9 +569,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     D = dims(cell.config)
     dep = cell.config["deployment"]
-    eng = build_engine(cell, seed, collect_stats=trace)
+    eng = build_engine(cell, seed, collect_stats=trace, devices=devs)
+    mesh = eng.mesh
     log(f"engine: {cell.config['model']} decode {eng.resolved_backend('decode')}"
-        f" prefill {eng.resolved_backend('prefill')} kv {eng.kv_dtype}")
+        f" prefill {eng.resolved_backend('prefill')} kv {eng.kv_dtype}"
+        f" tp {eng.tp}")
     mix = cell.mix
     drain = float(mix.get("drain_seconds", 0.0))
     window_s = min(seconds, TRACE_S) if trace else seconds
@@ -576,8 +600,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     red = None
     in_window_compiles = compiles["n"] - compiles_setup
     window = t_end - t0
-    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devs)
+    peak = peak_bytes(devs)
     log(f"setup_s {t_setup:.3f} (warm-up requests {n_warm}, compiles "
         f"{compiles_setup}); window {window:.3f} s; compiles in window "
         f"{in_window_compiles}; tokens {tokens}; requests finished "
@@ -587,6 +610,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             f"max {np.max(lateness) * 1e3:.3f} ms over {len(lateness)} submits")
     log(f"sparsity (engine): block {summary.get('block_sparsity')} head "
         f"{summary.get('head_sparsity')} page {summary.get('page_sparsity')}")
+    if mesh is not None:
+        log(f"mesh {summary.get('mesh_shape')}: tp {summary.get('tp')}, pool "
+            f"bytes per shard {summary.get('cache_bytes_pool_per_shard')}")
 
     metrics: Dict[str, dict] = {}
     attempted = failed = 0
@@ -658,7 +684,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     del eng, drv
     gc.collect()
     t_check = time.perf_counter()
-    gaps = reference_check(cell.config, seed, picks, control=control)
+    gaps = reference_check(cell.config, seed, picks, control=control,
+                           mesh=mesh)
     limit = float(cell.limits["mean_gap"]["limit"])
     checked = {"mean_gap": {"value": gaps["mean_gap"], "limit": limit},
                "tokens_compared": {"value": gaps["tokens"], "limit": min_tokens},

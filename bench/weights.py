@@ -7,6 +7,14 @@ takes (``{"embed", "layers", "final_norm"}``, layers stacked on a leading
 axis); the harness checks that layout against the program's own before it
 hands the tree over.
 
+Given a mesh, the same tree is made in one jitted call whose outputs are
+laid out as the program lays out its weights for tensor parallelism
+(``RULES_TP``: heads, kv heads, the MLP width and the vocabulary on the
+mesh's ``model`` axis, norms replicated), so that each chip holds its
+share and its share of the float32 temporaries alone. Threefry's
+partitionable mode makes every value bitwise the one the unsharded call
+gives.
+
 Every matrix is N(0, 1/fan_in). The configuration's ``weights`` group adds
 what a trained model has and a plain draw lacks: ``qk_scale`` multiplies
 the q and k projections and ``qk_bias_std`` / ``v_bias_std`` draw the
@@ -20,8 +28,6 @@ reference (which holds rotated queries and keys in the served dtype too)
 scout the very same integer parts.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +59,6 @@ def _normal(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
 def _make(key, frozen):
     c = dict(frozen)
     w = dict(c.pop("weights"))
@@ -89,6 +94,9 @@ def _make(key, frozen):
             "final_norm": {"w": jnp.ones((d,), dt)}}
 
 
+_make_one = jax.jit(_make, static_argnums=(1,))
+
+
 def _freeze(x):
     if isinstance(x, dict):
         return tuple(sorted((k, _freeze(v)) for k, v in x.items()))
@@ -97,9 +105,39 @@ def _freeze(x):
     return x
 
 
-def make_params(config: dict, seed: int):
+def program_config(config: dict):
+    """The program's ModelConfig for a configuration file: the registered
+    model, with every size, the RoPE base, the window, the dtype and the
+    HDP settings taken from the file (the file is what is run)."""
+    from repro.configs import get_config
+    from repro.core.config import HDPConfig
+
+    D = dims(config)
+    return get_config(config["model"]).replace(
+        n_layers=D["L"], d_model=D["d"], n_heads=D["H"], n_kv_heads=D["N"],
+        head_dim=D["hd"], d_ff=D["f"], vocab_size=D["V"],
+        sliding_window=D["window"], tie_embeddings=D["tied"],
+        qkv_bias=D["bias"], rope_theta=D["rope_theta"],
+        dtype=config["torch_dtype"], hdp=HDPConfig(**config["hdp"]))
+
+
+def tp_shardings(config: dict, mesh):
+    """The program's tensor-parallel layout of ``config``'s weights on
+    ``mesh``: a tree of NamedShardings."""
+    from repro.distribution.sharding import RULES_TP, tree_specs
+    from repro.models.registry import abstract_params
+
+    return tree_specs(*abstract_params(program_config(config)), mesh, RULES_TP)
+
+
+def make_params(config: dict, seed: int, mesh=None):
     """The weights of ``config`` (a configuration file's JSON object) for
-    ``seed``, in the configuration's dtype, made in one jitted call."""
+    ``seed``, in the configuration's dtype, made in one jitted call: on
+    the default device, or laid out on ``mesh`` as the program shards
+    them (``tp_shardings``), with the same values."""
+    if not jax.config.jax_threefry_partitionable:
+        raise RuntimeError("the benchmark's weights are drawn with "
+                           "jax_threefry_partitionable on")
     keep = {k: config[k] for k in (
         "num_hidden_layers", "hidden_size", "num_attention_heads",
         "num_key_value_heads", "head_dim", "intermediate_size", "vocab_size",
@@ -108,4 +146,8 @@ def make_params(config: dict, seed: int):
         if k in config}
     frozen = tuple((k, _freeze(v) if k != "weights" else
                     tuple(sorted(v.items()))) for k, v in sorted(keep.items()))
-    return _make(seed_key(seed), frozen)
+    if mesh is None:
+        return _make_one(seed_key(seed), frozen)
+    make = jax.jit(_make, static_argnums=(1,),
+                   out_shardings=tp_shardings(config, mesh))
+    return make(seed_key(seed), frozen)

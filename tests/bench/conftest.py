@@ -29,11 +29,12 @@ class StepClock:
         self.now += s
 
 
-@pytest.fixture
-def tiny_run(monkeypatch):
-    """Run a tiny float32 closed-loop cell on the CPU through the whole
-    harness (the chip check skipped), on a clock that counts engine
-    steps; returns the result line."""
+def tiny_runner(monkeypatch, config="tiny.json", chips=1):
+    """Runs of a tiny float32 closed-loop cell on the CPU through the
+    whole harness (the chip check skipped), on a clock that counts engine
+    steps: ``go(seed, seconds, control)`` returns the result line. The
+    configuration is ``tests/bench/data/<config>``; ``chips`` of the
+    process's devices serve it."""
     import jax
 
     from bench import run as R
@@ -49,19 +50,25 @@ def tiny_run(monkeypatch):
     monkeypatch.setattr(R.Driver, "step", timed_step)
 
     root = Path(ROOT)
-    cfg = json.loads((root / "tests/bench/data/tiny.json").read_text())
+    cfg = json.loads((root / "tests/bench/data" / config).read_text())
     mix = json.loads((root / "bench/traffic/decode-long.json").read_text())
     mix.update(prompt={"dist": "uniform", "lo": 300, "hi": 700},
                output={"dist": "uniform", "lo": 20, "hi": 60}, requests=64,
                check={"requests": 2, "max_served_tokens": 200})
     spec = json.loads((root / "BENCHMARK.json").read_text())
     name = "qwen2-1.5b.decode-long"
-    cell = R.Cell(name, 1, cfg, mix, R._for_cell(spec["end_to_end"], name),
+    cell = R.Cell(name, chips, cfg, mix, R._for_cell(spec["end_to_end"], name),
                   R._for_cell(spec["per_layer"], name),
                   {"mean_gap": {"limit": TINY_LIMIT}, "min_tokens": 20})
     peaks = json.loads((root / "bench/peaks.json").read_text())["TPU v5 lite"]
 
     def go(seed=5, seconds=2.0, control=False):
         return R.run(cell, seed, seconds, False, control=control,
-                     devices=jax.devices(), peaks=peaks)
+                     devices=jax.devices()[:chips], peaks=peaks)
     return go
+
+
+@pytest.fixture
+def tiny_run(monkeypatch):
+    """``tiny_runner`` of the one-chip tiny cell."""
+    return tiny_runner(monkeypatch)

@@ -180,3 +180,64 @@ def test_kernel_byte_count_matches_the_tiles_the_kernel_fetches(monkeypatch):
                                 slots=B, table_pages=nP)
     fixed = B * N * (2 * G * hd * 4 + nP * G * 4) + B * nP * 4 * 2
     assert b_ == tiles * 2 * ps * hd + fixed
+
+
+def _checkout(tmp_path, chips, kv_heads, deployed):
+    """A checkout whose one cell asks for ``chips`` on a configuration of
+    ``kv_heads`` kv heads deployed on ``deployed`` chips."""
+    root = tmp_path / "checkout"
+    for d in ("configs", "traffic", "limits"):
+        (root / "bench" / d).mkdir(parents=True)
+    cfg = json.loads((ROOT / "bench/configs/qwen2-1.5b.json").read_text())
+    cfg["num_key_value_heads"] = kv_heads
+    cfg["num_attention_heads"] = 4 * kv_heads
+    cfg["deployment"]["chips"] = deployed
+    (root / "bench/configs/m.json").write_text(json.dumps(cfg))
+    shutil.copy(ROOT / "bench/traffic/decode-long.json", root / "bench/traffic")
+    shutil.copy(ROOT / "bench/limits/qwen2-1.5b.decode-long.json",
+                root / "bench/limits/m.decode-long.json")
+    spec = dict(SPEC, configs=[dict(SPEC["configs"][0], name="m",
+                                    file="bench/configs/m.json")],
+                workloads=[{"name": "m.decode-long", "config": "m",
+                            "traffic": "decode-long", "chips": chips,
+                            "why": "x"}])
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root
+
+
+@pytest.mark.parametrize("chips, kv_heads, deployed, refusal", [
+    (2, 8, 2, "chips 2"),                    # neither one chip nor a host's four
+    (4, 2, 4, "do not divide"),              # 4 chips cannot shard 2 kv heads
+    (4, 8, 1, "deployed on 1"),              # the configuration says one chip
+])
+def test_load_cell_refuses_a_cell_it_cannot_run(tmp_path, chips, kv_heads,
+                                                deployed, refusal):
+    root = _checkout(tmp_path, chips, kv_heads, deployed)
+    with pytest.raises(SystemExit, match=refusal):
+        R.load_cell("m.decode-long", root=root)
+
+
+def test_load_cell_takes_a_four_chip_cell(tmp_path):
+    root = _checkout(tmp_path, 4, 8, 4)
+    assert R.load_cell("m.decode-long", root=root).chips == 4
+
+
+def test_one_chip_engine_gets_no_mesh(monkeypatch):
+    import jax
+
+    import repro.serving as S
+
+    seen = {}
+    real = S.Engine
+
+    def engine(*a, **kw):
+        seen.update(kw)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(S, "Engine", engine)
+    cell = R.Cell("tiny", 1, tiny(), {}, [], [], {})
+    eng = R.build_engine(cell, 5, collect_stats=False,
+                         devices=jax.devices()[:1])
+    assert seen["mesh"] is None
+    assert eng.mesh is None and eng.tp == 1
+    assert all(len(a.devices()) == 1 for a in jax.tree.leaves(eng.params))
