@@ -52,7 +52,8 @@ def prefill_flops(config: dict, prompt_len: int, start: int = 0) -> int:
 
 def paged_kernel_cost(config: dict, *, kept_pages: float, calls: int,
                       slots: int, table_pages: int) -> tuple:
-    """(bytes, flops) of ``calls`` calls of the paged decode kernel.
+    """(bytes, flops) of ``calls`` calls of the paged decode kernel on one
+    chip of the configuration's ``deployment.chips``.
 
     One call is one layer of one decode step over ``slots`` slots: it
     DMAs, per slot and kv head, each kept page's int8 K and V tile
@@ -62,11 +63,17 @@ def paged_kernel_cost(config: dict, *, kept_pages: float, calls: int,
     column, and the scalar-prefetched page lists. ``kept_pages`` is the
     total of kept pages over all calls and slots (each counted once per
     kv head by this function). FLOPs: QK^T, FQ FK^T and PV of the query
-    group against each kept tile."""
+    group against each kept tile.
+
+    Under tensor parallelism over the deployment's chips each chip's
+    kernel walks only its own ``N // chips`` kv heads (``distribution/tp.py``
+    shards the pool on its head axis), with every slot's page lists:
+    ``calls`` are one chip's calls and the count is one chip's."""
     D = dims(config)
     dep = config["deployment"]
-    ps, N, hd = int(dep["page_size"]), D["N"], D["hd"]
-    G = D["H"] // N
+    ps, hd = int(dep["page_size"]), D["hd"]
+    N = D["N"] // int(dep["chips"])
+    G = D["H"] // D["N"]
     tile = ps * hd                                  # int8 bytes
     per_call_fixed = (slots * N * (2 * tile        # scratch K and V tile
                                    + 2 * G * hd * 4  # q and out blocks

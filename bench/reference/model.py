@@ -11,8 +11,13 @@ below the reference's best. It computes on one device, whatever layout
 the weights arrive in: the embedding, each layer's slice and the LM head
 are moved to that device before they are used.
 
-What it implements, beside the model (RMSNorm, RoPE, GQA attention with
-q/k/v biases where the model has them, SiLU-GLU MLP, the LM head):
+What it implements, beside the model (RMSNorm, RoPE, GQA attention,
+SiLU-GLU MLP, the LM head, and each bias where the weights hold it, as
+the published modelling code adds it: q/k/v after their projections,
+Qwen2's and Llama's; o after the output projection, Llama's under
+``attention_bias``; gate, up and down after theirs, Llama's under
+``mlp_bias``; transformers 4.57 ``models/llama/modeling_llama.py``
+:149-151 and :211-220, ``models/qwen2/modeling_qwen2.py``:134-137):
 
 * the serving numerics the configuration states: K and V stored on the
   int8 pool grid (``round(x / step)`` clipped to +/-127 codes), queries
@@ -113,6 +118,26 @@ def _rms(x, w, eps):
     return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * w.astype(F32)
 
 
+def _plus(y, bias):
+    """``y`` plus ``bias`` in float32, where the weights hold one."""
+    return y if bias is None else y + bias.astype(F32)
+
+
+def post_attention(lp, x, o, eps, precision: str = "fp32"):
+    """The rest of a layer once attention has given ``o`` [S, H * hd] for
+    the residual stream ``x`` [S, d]: the output projection and its bias,
+    the residual, RMSNorm, ``silu(h Wg + b_gate) * (h Wu + b_up)``, then
+    ``. Wd + b_down`` and the residual (``lp``: one layer's weights)."""
+    a, f = lp["attn"], lp["ffn"]
+    d = x.shape[-1]
+    x = x + _plus(_mm(o, a["wo"].reshape(-1, d), precision), a.get("bo"))
+    h = _rms(x, lp["ln2"]["w"], eps)
+    g = _plus(_mm(h, f["w_gate"], precision), f.get("b_gate"))
+    u = _plus(_mm(h, f["w_up"], precision), f.get("b_up"))
+    return x + _plus(_mm(jax.nn.silu(g) * u, f["w_down"], precision),
+                     f.get("b_down"))
+
+
 def _rope(x, pos, theta):
     """x [S, heads, hd], pos [S]: rotate-half RoPE."""
     hd = x.shape[-1]
@@ -211,14 +236,8 @@ def _layer(lp, x_pf, x_dec, pos_pf, pos_dec, chunk_of_block, plen, *, static):
                 _grid(v, step).transpose(1, 0, 2))
 
     def finish(x, o):
-        d = x.shape[-1]
         o = o.transpose(1, 0, 2).reshape(-1, H * hd)
-        x = x + _mm(o, a["wo"].reshape(H * hd, d), precision)
-        f = lp["ffn"]
-        h = _rms(x, lp["ln2"]["w"], eps)
-        g = _mm(h, f["w_gate"], precision)
-        u = _mm(h, f["w_up"], precision)
-        return x + _mm(jax.nn.silu(g) * u, f["w_down"], precision)
+        return post_attention(lp, x, o, eps, precision)
 
     # ---- prompt rows: query blocks pooled, head gate per chunk
     q_pf, k_pf, v_pf = qkv(x_pf, pos_pf)

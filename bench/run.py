@@ -236,7 +236,7 @@ class Driver:
         self.step_no = 0
         self.work = {"decode_flops": 0.0, "prefill_flops": 0.0,
                      "prefill_tokens": 0, "slot_step_pages": 0.0,
-                     "queue_waits": [], "decode_steps": 0}
+                     "queue_waits": [], "decode_steps": 0, "ttfts": []}
         self.active: Dict[int, dict] = {}     # uid -> record, accounting only
 
     def submit(self, r, uid: Optional[int] = None, due: Optional[float] = None):
@@ -632,6 +632,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             ttft.append(r["submitted"] - r["due"] + r["ttft_s"] if ok else math.inf)
             tp = r.get("tpot_s") if ok else None
             tpot.append(tp if tp is not None else math.inf)
+        drv.work["ttfts"] = ttft
         log(f"requests due in window {len(recs)}; ttft p50 "
             f"{percentile(ttft, 50):.4f} s; tpot p50 {percentile(tpot, 50) * 1e3:.3f} ms")
     e2e = {
@@ -663,7 +664,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         log(f"trace: window {red.window_s:.4f} s busy {red.busy_s:.4f} s "
             f"programs {red.program_s} {red.program_n} kernels {red.kernel_s}"
-            f" {red.kernel_n}")
+            f" {red.kernel_n} collectives {red.collective_s} s "
+            f"({red.collective_n} on the first chip)")
     else:
         for m in cell.end_to_end:
             v, unit = e2e[m["name"]]

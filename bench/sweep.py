@@ -6,10 +6,10 @@ engine sustains, once, on the chip.
 
 For each rate it runs the cell as ``bench/run.py`` does, with that rate in
 place of the mix's ``rate_per_s``, and prints one JSON line with the
-cell's end-to-end metrics. The comparison with the reference is skipped:
-a sweep judges the load, not the outputs. The knee is the highest rate at
-which ``ttft_p95_s`` stays near its low-load value; the cell's mix then
-runs at about four fifths of it.
+cell's end-to-end metrics and ``ttft_p95_s``. The comparison with the
+reference is skipped: a sweep judges the load, not the outputs. The knee
+is the highest rate at which ``ttft_p95_s`` stays near its low-load
+value; the cell's mix then runs at about four fifths of it.
 """
 from __future__ import annotations
 
@@ -33,6 +33,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=20.0)
     args = ap.parse_args(argv)
     cell = R.load_cell(args.workload)
+    if all(m["name"] != "ttft_p95_s" for m in cell.end_to_end):
+        cell.end_to_end.append({"name": "ttft_p95_s"})
     R.reference_check = _no_check
     for rate in [float(r) for r in args.rates.split(",")]:
         cell.mix["rate_per_s"] = rate

@@ -3,14 +3,25 @@
 ``load_xplane`` turns the JAX profiler's ``.xplane.pb`` into a small
 normalized record — per chip the device's op events and program (module)
 events, plus the harness's own host spans (``bench.*``), as
-``[name, start_ns, duration_ns]`` — and ``reduce`` computes everything
-from that record alone, so a recorded trace checks the reduction without
-a chip.
+``[name, start_ns, duration_ns]``, an op with its HLO opcode as a fourth
+element — and ``reduce`` computes everything from that record alone, so a
+recorded trace checks the reduction without a chip.
+
+Collective time is the device time of the ops that move data between
+chips: those whose HLO opcode is one of ``COLLECTIVES``, or the
+``-start`` / ``-done`` half of an asynchronous one, whatever the op is
+named (``psum`` lowers to ``%psum.N = ... all-reduce(...)``). The TPU
+compiler also wraps each half of an asynchronous collective in a fusion
+of its own (``%async-collective-start = ... fusion(...), kind=kCustom``);
+those count too. An asynchronous transfer's overlap with compute counts
+only through its start and done ops: the time between them, in which
+other ops run, is not collective time.
 """
 from __future__ import annotations
 
 import glob
 import os
+import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -19,6 +30,42 @@ Event = Tuple[str, float, float]
 #: ops that hold other ops (a scanned layer loop, a call): their time is
 #: their body's, so the breakdown lists the body's ops instead
 CONTAINERS = ("%while", "%conditional", "%call")
+#: HLO opcodes of the collectives
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+_OPCODE = re.compile(r"\s*([a-z][a-z0-9-]*)\(")
+_FUSED_ASYNC = re.compile(r"%?async-collective-(start|done)(\.\d+)?$")
+
+
+def hlo_opcode(text: str) -> str:
+    """The opcode of one HLO instruction's text, ``%name = shape
+    opcode(operands), attributes`` (a tuple shape in parentheses); ""
+    where the text has none."""
+    _, eq, rest = text.partition(" = ")
+    if not eq:
+        return ""
+    if rest.startswith("("):                  # tuple shape: to its close
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        i += 1
+    else:
+        i = rest.find(" ")
+        if i < 0:
+            return ""
+    m = _OPCODE.match(rest, i)
+    return m.group(1) if m else ""
+
+
+def is_collective(name: str, opcode: str) -> bool:
+    """Whether the op ``name`` of HLO ``opcode`` moves data between chips:
+    a collective, either half of an asynchronous one, or the fusion the
+    TPU compiler wraps such a half in."""
+    if re.sub(r"-(start|done)$", "", opcode) in COLLECTIVES:
+        return True
+    return opcode == "fusion" and bool(_FUSED_ASYNC.match(name))
 
 
 def load_xplane(trace_dir: str) -> dict:
@@ -40,9 +87,13 @@ def load_xplane(trace_dir: str) -> dict:
                 if dst is None:
                     continue
                 for e in line.events:
-                    # an op's event name is its HLO text: keep "%name.N"
-                    name = e.name.split(" = ")[0] if dst is ops else e.name
-                    dst.append([name, float(e.start_ns), float(e.duration_ns)])
+                    ev = [e.name, float(e.start_ns), float(e.duration_ns)]
+                    if dst is ops:
+                        # an op's event name is its HLO text: keep
+                        # "%name.N" and the opcode
+                        ev[0] = e.name.split(" = ")[0]
+                        ev.append(hlo_opcode(e.name))
+                    dst.append(ev)
             chips.append({"name": plane.name, "ops": ops, "modules": modules})
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -78,7 +129,9 @@ class Reduction:
     program_s: Dict[str, float] = field(default_factory=dict)
     program_n: Dict[str, int] = field(default_factory=dict)
     kernel_s: Dict[str, float] = field(default_factory=dict)
-    kernel_n: Dict[str, int] = field(default_factory=dict)
+    kernel_n: Dict[str, int] = field(default_factory=dict)     # chip 0
+    collective_s: float = 0.0              # mean over chips
+    collective_n: int = 0                  # chip 0
     device_ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
 
@@ -95,6 +148,10 @@ def reduce(record: dict, window: Tuple[float, float], *,
     ``programs`` maps a label to the substrings of the compiled-program
     names it covers (e.g. ``{"decode": ["_decode_loop_paged_fn"]}``);
     ``kernels`` lists kernel names, matched as substrings of op names.
+    Times are means over the chips and counts those of the first chip
+    (each chip runs every program, on its share of the work);
+    ``collective_s`` / ``collective_n`` cover the ops ``is_collective``
+    names, from the opcode the record keeps beside each op's name.
     ``device_ops`` lists the ops that took most device time, containers
     (a scanned loop, a call) left out.
     Busy time is the union of the device's op intervals; idle gaps are
@@ -124,7 +181,12 @@ def reduce(record: dict, window: Tuple[float, float], *,
             for k in kernels:
                 if k in e[0]:
                     red.kernel_s[k] = red.kernel_s.get(k, 0.0) + d / len(chips)
-                    red.kernel_n[k] = red.kernel_n.get(k, 0) + 1
+                    if ci == 0:
+                        red.kernel_n[k] = red.kernel_n.get(k, 0) + 1
+            if is_collective(e[0], e[3] if len(e) > 3 else ""):
+                red.collective_s += d / len(chips)
+                if ci == 0:
+                    red.collective_n += 1
         for e in chip["modules"]:
             c = _clip(e, t0, t1)
             if not c:

@@ -119,6 +119,26 @@ def test_kernel_cost_hand_count():
     assert C.roofline_s(b, f, peaks) == max(b / 1e9, f / 1e12)
 
 
+def test_kernel_cost_of_one_chip_of_four_hand_count():
+    """Tensor parallelism over the deployment's 4 chips: one chip's kernel
+    walks its own 4 // 4 = 1 kv head (query group still 8 // 4 = 2) over
+    every slot's page lists."""
+    c = json.loads((ROOT / "tests/bench/data/tiny_tp4.json").read_text())
+    assert c["deployment"]["chips"] == 4
+    kw = dict(kept_pages=3, calls=2, slots=4, table_pages=8)
+    b, f = C.paged_kernel_cost(c, **kw)
+    tile = 128 * 16
+    fixed = 4 * 1 * (2 * tile + 2 * 2 * 16 * 4 + 8 * 2 * 4) + 4 * 8 * 4 * 2
+    assert b == 3 * 1 * 2 * tile + 2 * fixed
+    assert f == 3 * 1 * 3 * 2 * 2 * 128 * 16
+    # the same file deployed on one chip counts all 4 kv heads
+    one = dict(c, deployment=dict(c["deployment"], chips=1))
+    b1, f1 = C.paged_kernel_cost(one, **kw)
+    assert (b1, f1) == (3 * 4 * 2 * tile + 2 * (
+        4 * 4 * (2 * tile + 2 * 2 * 16 * 4 + 8 * 2 * 4) + 4 * 8 * 4 * 2),
+        3 * 4 * 3 * 2 * 2 * 128 * 16)
+
+
 def test_kernel_byte_count_matches_the_tiles_the_kernel_fetches(monkeypatch):
     """At a small size in interpret mode: the kept pages the benchmark
     derives from the engine's page sparsity are the pages the paged

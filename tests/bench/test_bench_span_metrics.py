@@ -1,7 +1,10 @@
 """The per-layer metrics read from the engine's span totals and prompt
 token counters (``step_host_ms.*``, ``prefill_pad_share.chat``): nothing
 from a program without them, the right value from a hand-made summary
-and from a tiny engine's own."""
+and from a tiny engine's own; and ``ttft_p95_s.chat`` from the open
+loop's times to first token."""
+import math
+
 import numpy as np
 import pytest
 
@@ -71,3 +74,20 @@ def test_readers_on_a_tiny_engines_summary():
     assert pad == pytest.approx(
         100.0 * (1.0 - 25 / s["prefill_tokens"]))
     assert 0.0 < pad < 100.0
+
+
+def test_ttft_p95_chat_is_the_nearest_rank_of_the_window():
+    """The 95th percentile by nearest rank of the TTFTs the harness
+    handed over, a failed request (+inf) among them; nothing from a
+    closed loop, which hands over none."""
+    read = load_reader("ttft_p95_s.chat")
+
+    def of(ttfts):
+        return read(MetricContext(None, {}, {"ttfts": ttfts}, None, None,
+                                  None, 1))
+    ttfts = [0.01 * (i + 1) for i in range(29)]
+    assert of(ttfts) == pytest.approx(0.28)        # rank ceil(27.55) = 28
+    assert of(ttfts[::-1]) == pytest.approx(0.28)
+    assert of(ttfts[:19] + [math.inf]) == pytest.approx(0.19)
+    assert of(ttfts[:10] + [math.inf] * 2) == math.inf
+    assert of([]) is None

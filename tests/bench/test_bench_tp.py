@@ -7,7 +7,8 @@ what it reports:
 
 * ``make_params`` on a 4-way mesh gives, leaf by leaf, the bits of the
   unsharded call, and lays each matrix out on its head, kv-head, MLP or
-  vocabulary axis on ``model`` (norms replicated);
+  vocabulary axis on ``model`` (norms replicated); so too for Llama's
+  o and MLP biases (``data/tiny_llama.json``);
 * the reference reads the sharded weights as it reads the unsharded ones;
 * ``bench/run.run`` on ``data/tiny_tp4.json`` (8 q / 4 kv heads, chips 4)
   serves with an engine of ``tp`` 4 whose pool is head-sharded, comes out
@@ -37,6 +38,11 @@ SPLIT = {
     "['layers']['ffn']['w_gate']": 2, "['layers']['ffn']['w_up']": 2,
     "['layers']['ffn']['w_down']": 1,
 }
+#: the same for each case the child makes: Llama's o and MLP biases
+#: beside them, the MLP width's on "model"
+SPLITS = {"float32": SPLIT, "bfloat16": SPLIT, "llama": dict(SPLIT, **{
+    "['layers']['attn']['bo']": None, "['layers']['ffn']['b_gate']": 1,
+    "['layers']['ffn']['b_up']": 1, "['layers']['ffn']['b_down']": None})}
 
 
 def _child() -> dict:
@@ -53,16 +59,18 @@ def _child() -> dict:
     out = {"devices": len(jax.devices())}
     mesh = make_serving_mesh(tp=4, devices=jax.devices()[:4])
     cfg = json.loads((HERE / "data/tiny_tp4.json").read_text())
-    for dtype in ("float32", "bfloat16"):
-        c = dict(cfg, torch_dtype=dtype)
+    llama = json.loads((HERE / "data/tiny_llama.json").read_text())
+    cases = {"float32": cfg, "bfloat16": dict(cfg, torch_dtype="bfloat16"),
+             "llama": llama}
+    for case, c in cases.items():
         one = jax.tree_util.tree_flatten_with_path(make_params(c, SEED))[0]
         four = jax.tree_util.tree_flatten_with_path(make_params(c, SEED, mesh))[0]
-        out[f"equal.{dtype}"] = {
+        out[f"equal.{case}"] = {
             jax.tree_util.keystr(k): np.asarray(a).tobytes() == np.asarray(b).tobytes()
             for (k, a), (_, b) in zip(one, four)}
-        out[f"specs.{dtype}"] = {
+        out[f"specs.{case}"] = {
             jax.tree_util.keystr(k): list(b.sharding.spec) for k, b in four}
-        out[f"shapes.{dtype}"] = {
+        out[f"shapes.{case}"] = {
             jax.tree_util.keystr(k): [list(b.shape)] + [
                 list(s.data.shape) for s in b.addressable_shards]
             for k, b in four}
@@ -114,18 +122,21 @@ def child():
     return out
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("dtype", list(SPLITS))
 def test_sharded_weights_are_bitwise_the_unsharded(child, dtype):
     equal = child[f"equal.{dtype}"]
-    assert len(equal) == len(SPLIT) + 3           # + ln1, ln2, final_norm
+    assert len(equal) == len(SPLITS[dtype]) + 3   # + ln1, ln2, final_norm
     assert all(equal.values()), equal
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("dtype", list(SPLITS))
 def test_each_matrix_is_split_on_model_as_the_program_lays_it_out(child, dtype):
+    assert set(child[f"specs.{dtype}"]) == set(SPLITS[dtype]) | {
+        "['layers']['ln1']['w']", "['layers']['ln2']['w']",
+        "['final_norm']['w']"}
     for leaf, spec in child[f"specs.{dtype}"].items():
         spec = spec + [None] * (5 - len(spec))
-        want = SPLIT.get(leaf)
+        want = SPLITS[dtype].get(leaf)
         assert spec[:5] == [("model" if i == want else None) for i in range(5)], \
             (leaf, spec)
         whole, *shards = child[f"shapes.{dtype}"][leaf]
