@@ -29,6 +29,8 @@ class AttnStats:
         row (that kernel only).
     kernel_block_pages: pages of the compute blocks it visits, per row:
         ``ceil(kernel_pages / ppb) * ppb``.
+    scout_pages: table columns the Pallas paged scout kernel walks (the
+        pages it DMAs), per row (that kernel only).
     """
 
     block_sparsity: jnp.ndarray
@@ -37,6 +39,7 @@ class AttnStats:
     page_sparsity: Optional[jnp.ndarray] = None
     kernel_pages: Optional[jnp.ndarray] = None
     kernel_block_pages: Optional[jnp.ndarray] = None
+    scout_pages: Optional[jnp.ndarray] = None
 
     # dict-style compat with the pre-registry stats consumers
     def __getitem__(self, key: str):
@@ -58,7 +61,8 @@ class AttnStats:
 jax.tree_util.register_dataclass(
     AttnStats,
     data_fields=("block_sparsity", "head_sparsity", "theta_head",
-                 "page_sparsity", "kernel_pages", "kernel_block_pages"),
+                 "page_sparsity", "kernel_pages", "kernel_block_pages",
+                 "scout_pages"),
     meta_fields=())
 
 
@@ -73,7 +77,8 @@ def normalize_stats(raw: Any) -> Optional[AttnStats]:
             theta_head=raw.get("theta_head"),
             page_sparsity=raw.get("page_sparsity"),
             kernel_pages=raw.get("kernel_pages"),
-            kernel_block_pages=raw.get("kernel_block_pages"))
+            kernel_block_pages=raw.get("kernel_block_pages"),
+            scout_pages=raw.get("scout_pages"))
     # core.hdp.HDPStats-shaped object (attribute access)
     return AttnStats(
         block_sparsity=jnp.asarray(raw.block_sparsity),

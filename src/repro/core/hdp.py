@@ -68,6 +68,22 @@ def decode_scout(int_scores: jnp.ndarray, valid: jnp.ndarray, cfg: HDPConfig,
         int_scores = int_scores[..., :, None, :]
         valid = valid[..., :, None, :]
     theta, bvalid = blocking.pooled_block_theta(int_scores, valid, cfg.block_k)
+    return scout_decision(theta, bvalid, valid.sum(axis=(-2, -1)), cfg)
+
+
+def scout_decision(theta: jnp.ndarray, bvalid: jnp.ndarray,
+                   n_valid: jnp.ndarray, cfg: HDPConfig):
+    """The decode scout's decisions from pooled page importances.
+
+    ``theta`` [..., nk] are the per-page abs-sums of the integer scores
+    over valid positions, ``bvalid`` [..., nk] (broadcastable) the pages
+    with any valid position and ``n_valid`` [...] (broadcastable) the
+    count of valid positions, which ``normalize_head_score`` divides the
+    head importance by. Returns (keep, bvalid, theta, theta_head,
+    head_kept) as :func:`decode_scout` documents: the row threshold and
+    keep mask, and the early head gate. Every decode scout decides here,
+    whichever way its importances were pooled.
+    """
     if cfg.block_pruning:
         thr = blocking.row_threshold(theta, cfg.rho_b, bvalid)
         keep = blocking.block_keep_mask(theta, thr, bvalid)
@@ -76,7 +92,7 @@ def decode_scout(int_scores: jnp.ndarray, valid: jnp.ndarray, cfg: HDPConfig,
     theta_head = jnp.where(bvalid, theta, 0.0).sum(-1)
     if cfg.normalize_head_score:
         theta_head = theta_head / jnp.maximum(
-            valid.sum(axis=(-2, -1)).astype(jnp.float32), 1.0)
+            n_valid.astype(jnp.float32), 1.0)
     head_kept = (theta_head > cfg.tau_h) if cfg.head_pruning \
         else jnp.ones_like(theta_head, bool)
     return keep, bvalid, theta, theta_head, head_kept

@@ -153,7 +153,11 @@ def tp_paged_attention(q, call, spec, *, q_pos, k_pos, cache, page_table,
                               jax.lax.psum(stats.kernel_pages, "model")),
                 kernel_block_pages=(
                     None if stats.kernel_block_pages is None else
-                    jax.lax.psum(stats.kernel_block_pages, "model")))
+                    jax.lax.psum(stats.kernel_block_pages, "model")),
+                # every shard's scout walks the same columns, each for
+                # its own kv heads
+                scout_pages=(None if stats.scout_pages is None else
+                             jax.lax.pmean(stats.scout_pages, "model")))
         return out, stats
 
     # stats presence/fields are call-static — derive the output pytree

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro.attention import AttnCall, AttnSpec, attention
 from repro.core import blocking
 from repro.core.config import HDPConfig
-from repro.core.hdp import calibrated_split, decode_scout
+from repro.core.hdp import calibrated_split, decode_scout, scout_decision
 from repro.core.quant import (FRAC_SCOUT_SCALE, POISON_CODE, encode_pool,
                               encode_pool_scaled, pool_int_bits, pool_scale,
                               pool_view_finite, quantize_and_split,
@@ -621,6 +621,18 @@ def _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep, head_kept,
     return _head_gate(out, head_kept)
 
 
+def _paged_scout_route(stage3, k_pool, hdp: HDPConfig, *, kv_scale, window,
+                       n_q, per_query, draft) -> bool:
+    """Whether stage 1 of a paged decode call runs as the paged scout
+    kernel: where stage 3 is the paged kernel, over an int8 pool on the
+    static grid, for one causal query row per slot with no draft and no
+    window. Verify rows, drafts, sliding windows, calibrated scales and
+    fp32 / bf16 pools keep the XLA scout."""
+    return (stage3 == "pallas_paged" and k_pool.dtype == jnp.int8
+            and kv_scale != "absmax" and not window and n_q == 1
+            and not per_query and draft is None and hdp.causal)
+
+
 def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                q_pos, k_pos, hdp: HDPConfig, window: int = 0,
                                return_stats: bool = False,
@@ -651,9 +663,15 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
     Stage 1 streams the int8 scout copy for EVERY allocated page (the
     paper's always-read integer pass), pools it into per-page importances
     and derives the keep mask + early head gate (core.hdp.decode_scout).
-    Stage 2 fetches full-precision K/V only for surviving pages — pruned
-    pages' gather indices are redirected to the scratch page, so their
-    memory is never touched (the TPU kernel analogue never DMAs them).
+    Under ``stage3="pallas_paged"`` a plain decode step (one causal query
+    row, no draft) over an int8 static-grid pool pools its importances
+    in the paged scout kernel instead (``kernels/hdp_paged_scout.py``:
+    only each slot's columns up to its extent are DMA'd, no score slab
+    is made); both paths decide through ``core.hdp.scout_decision``, so
+    the keep mask is the same bit for bit. Stage 2 fetches full-precision
+    K/V only for surviving pages — pruned pages' gather indices are
+    redirected to the scratch page, so their memory is never touched (the
+    TPU kernel analogue never DMAs them).
     Stage 3 runs the approximate attention QK^T - FQ FK^T on the fetched
     pages with the keep mask excluded from the softmax.
 
@@ -723,34 +741,55 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                 for x in (k_pool, v_pool, ik_pool, fk_pool))
             layer = None
 
-    kernel_stats = {}       # what the Pallas paged kernel walks, if it runs
+    paged_scout = _paged_scout_route(stage3, k_pool, hdp, kv_scale=kv_scale,
+                                     window=window, n_q=Sq,
+                                     per_query=per_query, draft=draft)
+    kernel_stats = {}       # what the Pallas paged kernels walk, if they run
     # ---- stage 1: integer scout on the always-streamed int8 copy ----
     with jax.named_scope("hdp.scout"):
-        if absmax:
-            # calibrated scales: dequantize the scout stream through a
-            # sanitized scale copy — poison codes -> 0 and NaN freed-page
-            # scales -> the static step, preserving the scout-always-finite
-            # contract of the static-grid view
-            codes = gather_pages(k_pool, table, layer)              # [B,nP,ps,N,hd]
-            ksc = k_scale[table]                             # [B,nP,N]
-            ksc = jnp.where(jnp.isfinite(ksc), ksc, pool_scale(hdp.int_bits))
-            cf = jnp.where(codes == POISON_CODE, 0, codes).astype(F32)
-            k_fin = (cf * ksc[:, :, None, :, None]).reshape(B, Sk, N, hd)
-            ik = jnp.trunc(k_fin)
-        elif quantized:
-            # the pool's codes ARE the scout stream: the finite static-grid
-            # view (poison sentinels -> 0, masked anyway) truncates to the
-            # same integer parts the fp32 pools' write-time copy stored
-            k_fin = pool_view_finite(gather_pages(k_pool, table, layer), hdp.int_bits)
-            k_fin = k_fin.reshape(B, Sk, N, hd)
-            ik = jnp.trunc(k_fin)
-        else:
-            ik = gather_pages(ik_pool, table, layer).reshape(B, Sk, N, hd).astype(F32)
         qq, iq, fq = _fixed_split(q, hdp)
-        s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik, preferred_element_type=F32)
-        valid = _mask_bias(q_pos, k_pos, hdp.causal, window)
-        keep, bvalid, theta, theta_head, head_kept = decode_scout(
-            s_int, valid, hdp, per_query=per_query)
+        if paged_scout:
+            from repro.kernels.hdp_paged_scout import hdp_paged_scout
+            from repro.kernels.ops import _auto_interpret
+            # causal single-row decode: positions 0 .. q_pos are valid
+            kv_len = jnp.clip(q_pos.reshape(B) + 1, 0, Sk).astype(jnp.int32)
+            theta, walked = hdp_paged_scout(
+                iq[..., 0, :], k_pool, table, kv_len, int_bits=hdp.int_bits,
+                layer=layer, interpret=_auto_interpret(None))
+            bvalid = (jnp.arange(nP) * ps < kv_len[:, None])[:, None, None]
+            keep, bvalid, theta, theta_head, head_kept = scout_decision(
+                theta, bvalid, kv_len[:, None, None], hdp)
+            kernel_stats["scout_pages"] = walked.astype(F32)
+        else:
+            if absmax:
+                # calibrated scales: dequantize the scout stream through a
+                # sanitized scale copy — poison codes -> 0 and NaN
+                # freed-page scales -> the static step, preserving the
+                # scout-always-finite contract of the static-grid view
+                codes = gather_pages(k_pool, table, layer)  # [B,nP,ps,N,hd]
+                ksc = k_scale[table]                             # [B,nP,N]
+                ksc = jnp.where(jnp.isfinite(ksc), ksc,
+                                pool_scale(hdp.int_bits))
+                cf = jnp.where(codes == POISON_CODE, 0, codes).astype(F32)
+                k_fin = (cf * ksc[:, :, None, :, None]).reshape(B, Sk, N, hd)
+                ik = jnp.trunc(k_fin)
+            elif quantized:
+                # the pool's codes ARE the scout stream: the finite
+                # static-grid view (poison sentinels -> 0, masked anyway)
+                # truncates to the same integer parts the fp32 pools'
+                # write-time copy stored
+                k_fin = pool_view_finite(gather_pages(k_pool, table, layer),
+                                         hdp.int_bits)
+                k_fin = k_fin.reshape(B, Sk, N, hd)
+                ik = jnp.trunc(k_fin)
+            else:
+                ik = gather_pages(ik_pool, table, layer).reshape(
+                    B, Sk, N, hd).astype(F32)
+            s_int = jnp.einsum("bngqh,bsnh->bngqs", iq, ik,
+                               preferred_element_type=F32)
+            valid = _mask_bias(q_pos, k_pos, hdp.causal, window)
+            keep, bvalid, theta, theta_head, head_kept = decode_scout(
+                s_int, valid, hdp, per_query=per_query)
 
     with jax.named_scope("hdp.attend"):
         # ---- stage 2: fetch-upon-mask page selection ----
@@ -806,9 +845,9 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
             from repro.kernels.hdp_paged_decode import pages_per_block
             ppb = pages_per_block(nP, k_pool, v_pool)
             kernel_pages = fetched.sum(-1).astype(F32)
-            kernel_stats = {"kernel_pages": kernel_pages,
-                            "kernel_block_pages":
-                                jnp.ceil(kernel_pages / ppb) * ppb}
+            kernel_stats.update(kernel_pages=kernel_pages,
+                                kernel_block_pages=jnp.ceil(
+                                    kernel_pages / ppb) * ppb)
             out = _paged_fum_kernel_stage3(qq, k_pool, v_pool, table, keep,
                                            head_kept, q_pos, fetched,
                                            hdp=hdp, ps=ps,

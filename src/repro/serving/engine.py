@@ -1432,6 +1432,7 @@ class Engine:
                 "block_sparsity": 0.0, "head_sparsity": 0.0,
                 "page_sparsity": 0.0, "stat_samples": 0, "page_samples": 0,
                 "kernel_pages": 0.0, "kernel_block_pages": 0.0,
+                "scout_pages": 0.0, "scout_samples": 0,
                 "cow_copies": 0, "spec_rounds": 0, "draft_tokens": 0,
                 "accepted_tokens": 0,
                 # stream-scheduler counters (zero when it is off)
@@ -1509,6 +1510,10 @@ class Engine:
             for k in ("kernel_pages", "kernel_block_pages"):
                 m[k] += float(np.sum(self._real_samples(getattr(stats, k),
                                                         mask)))
+        if getattr(stats, "scout_pages", None) is not None:
+            walked = self._real_samples(stats.scout_pages, mask)
+            m["scout_pages"] += float(np.sum(walked))
+            m["scout_samples"] += walked.size
         m["stat_samples"] += 1
 
     def _finish(self, slot: int, now: Optional[float] = None, *,
@@ -2044,7 +2049,9 @@ class Engine:
         ``paged_block_fill`` is the kept pages the kernel visited over the
         pages of the compute blocks it visited (``kernel_pages`` /
         ``kernel_block_pages``): the share of its block work that is not
-        padding of a row's last block."""
+        padding of a row's last block. ``scout_pages`` is the table
+        columns the Pallas paged scout walked (the pages it DMA'd) over
+        its ``scout_samples`` layer-slot samples."""
         m = dict(self.metrics)
         m["prefill_s"] = self.spans.total("engine.prefill")
         m["decode_s"] = self.spans.total("engine.decode")

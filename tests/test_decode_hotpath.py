@@ -264,6 +264,81 @@ def test_paged_scan_never_reads_pruned_pages():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_bad))
 
 
+# ----------------------------------------------------- paged scout kernel
+def test_paged_scout_kernel_tokens_match_xla_scout(monkeypatch):
+    """An int8 paged engine on the Pallas paged decode backend scouts in
+    the paged scout kernel: its tokens and page sparsity equal those of
+    the same engine with stage 1 sent back to the XLA scout, over a
+    multi-step decode. With stats on, ``scout_pages`` counts the table
+    columns the kernel walked, ceil((position + 1) / page) for every
+    layer and decoded slot-step; the XLA scout counts none."""
+    from repro.models import attention as A
+
+    cfg = _qwen()
+    prompts = _prompts(3, lo=3, hi=12, seed=11)
+    pallas = AttnSpec(backend="pallas")
+    eng, kern = _serve(cfg, None, prompts, 1, max_new=7, collect_stats=True,
+                       attn=pallas)
+    assert eng.resolved_backend("decode") == "pallas_paged_decode"
+    assert eng.kv_dtype == "int8"
+    s = eng.summary()
+    ps, layers = eng.pages.page_size, cfg.n_layers
+    walked = slots = 0
+    for uid, p in enumerate(prompts):
+        # each token comes from a decode step whose query sits at the
+        # position before it: the first step replays the prompt's last
+        for pos in range(len(p) - 1, len(p) - 1 + len(kern[uid])):
+            walked += -(-(pos + 1) // ps)
+            slots += 1
+    assert s["scout_pages"] == layers * walked
+    assert s["scout_samples"] == layers * slots
+
+    monkeypatch.setattr(A, "_paged_scout_route", lambda *a, **k: False)
+    eng2, xla = _serve(cfg, eng.params, prompts, 1, max_new=7,
+                       collect_stats=True, attn=pallas)
+    assert xla == kern
+    s2 = eng2.summary()
+    assert s2["scout_samples"] == 0 and s2["scout_pages"] == 0
+    assert s2["page_sparsity"] == s["page_sparsity"]
+    assert s2["head_sparsity"] == s["head_sparsity"]
+
+
+@pytest.mark.parametrize("case,kernel", [
+    ("decode", True), ("verify", False), ("window", False),
+    ("fp32_pool", False), ("absmax", False)])
+def test_paged_scout_route(case, kernel):
+    """Stage 1 takes the paged scout kernel only for one causal query row
+    per slot over an int8 static-grid pool under the paged kernel's
+    stage 3; verify rows, a sliding window, fp32 pools and calibrated
+    (absmax) scales keep the XLA scout. The kernel's presence shows in
+    the stats it alone reports (``scout_pages``)."""
+    from repro.core.quant import encode_pool, pool_scale
+    hdp = HDPConfig(block_q=1, block_k=4, rho_b=0.5, causal=True,
+                    head_pruning=False, calib="none")
+    q, ks, vs, ik, table, q_pos, k_pos = _paged_inputs(2, hdp, n_pages=4)
+    kw = dict(stage3="pallas_paged", return_stats=True)
+    if case == "verify":
+        q = jnp.concatenate([q, q], axis=3)
+        q_pos = jnp.concatenate([q_pos - 1, q_pos], axis=3)
+        kw["per_query"] = True
+    elif case == "window":
+        kw["window"] = 8
+    if case != "fp32_pool":
+        P, N = ks.shape[:2]
+        ks, vs = encode_pool(ks, hdp.int_bits), encode_pool(vs, hdp.int_bits)
+        scale = jnp.full((P, N), pool_scale(hdp.int_bits), F32)
+        kw.update(k_scale=scale, v_scale=scale, kv_scale=(
+            "absmax" if case == "absmax" else "grid"))
+        ik = None
+    out, stats = hdp_paged_decode_attention(
+        q, ks, vs, ik, table, q_pos=q_pos, k_pos=k_pos, hdp=hdp, **kw)
+    assert bool(jnp.isfinite(out).all())
+    assert ("scout_pages" in stats) == kernel
+    if kernel:
+        np.testing.assert_array_equal(np.asarray(stats["scout_pages"]),
+                                      table.shape[1])
+
+
 # --------------------------------------------------------- run() exhaustion
 def test_run_budget_exhaustion_warns_and_marks_incomplete():
     cfg = _qwen()

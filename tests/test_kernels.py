@@ -273,3 +273,134 @@ class TestPagedFumKernel:
         bad = np.asarray(_paged_kernel(c, **poison(kept)))
         assert np.isnan(bad[0]).all(), "a poisoned kept page did not trip"
         np.testing.assert_array_equal(bad[1:], clean[1:])
+
+
+# The paged scout at qwen2-1.5b's widths (2 kv heads x 6 query heads,
+# head_dim 128, 128-token pages) over a layer-stacked int8 pool. Nine
+# table columns give ppb = 3 columns a block, so a full slot walks three
+# blocks; the slots' extents are each case's.
+SCOUT_COLS, SCOUT_L, SCOUT_LAYER = 9, 2, 1
+SCOUT_LENS = {
+    "partial_page": (9 * 128 - 5, 4 * 128 + 1, 100, 7 * 128 + 64, 383),
+    "empty_slot": (0, 5 * 128 + 3, 0, 0, 9 * 128),
+    "page_boundary": (128, 3 * 128, 6 * 128, 9 * 128, 256),
+    "scratch_columns": (2 * 128 + 7, 0, 8 * 128 + 1, 128, 4 * 128),
+    "extreme_codes": (9 * 128, 9 * 128 - 1, 5 * 128, 3 * 128 + 9, 1),
+    "poison_allocated": (9 * 128 - 5, 4 * 128 + 1, 100, 7 * 128 + 64, 383),
+    "poison_past_count": (2 * 128 + 7, 0, 8 * 128 + 1, 128, 4 * 128),
+}
+
+
+def _scout_case(case, seed=0):
+    """A layer-stacked int8 pool, a page table and one decode query per
+    slot, with ``SCOUT_LENS[case]`` valid positions a slot."""
+    from repro.models.attention import _fixed_split
+
+    lens = np.asarray(SCOUT_LENS[case])
+    B, N, G, hd, ps, nP = len(lens), 2, 6, 128, 128, SCOUT_COLS
+    P = 1 + B * nP
+    rng = np.random.default_rng(seed)
+    pool = rng.integers(-127, 128, size=(SCOUT_L, P, N, ps, hd), dtype=np.int8)
+    q = rnd(B, N, G, 1, hd, seed=seed + 1, scale=6.0)
+    if case == "extreme_codes":
+        pool = np.where(rng.random(pool.shape) < 0.5, -127, 127).astype(np.int8)
+        q = 20.0 * jnp.sign(q)        # integer parts -16 and 15
+    table = rng.permutation(np.arange(1, P, dtype=np.int32)).reshape(B, nP)
+    counts = -(-lens // ps)
+    if case in ("scratch_columns", "poison_past_count"):
+        # columns past the count are unallocated: they name scratch page 0
+        table[np.arange(nP)[None] >= counts[:, None]] = 0
+    hdp = HDPConfig(block_q=1, block_k=ps, rho_b=0.3, causal=True,
+                    head_pruning=True, normalize_head_score=True,
+                    calib="none")
+    _, iq, _ = _fixed_split(q, hdp)
+    q_pos = jnp.asarray(lens - 1, jnp.int32)[:, None, None, None]
+    return dict(pool=pool, table=table, iq=iq, q_pos=q_pos, lens=lens,
+                counts=counts, hdp=hdp, ps=ps)
+
+
+def _xla_scout(c, pool):
+    """The XLA scout of the int8 pool: gather every column, widen,
+    truncate, multiply, pool and decide (``decode_scout``)."""
+    from repro.core.hdp import decode_scout
+    from repro.core.quant import pool_view_finite
+    from repro.models.attention import _mask_bias, gather_pages
+
+    iq, hdp = c["iq"], c["hdp"]
+    B, N, G, _, hd = iq.shape
+    nP = c["table"].shape[1]
+    k = pool_view_finite(gather_pages(jnp.asarray(pool), c["table"],
+                                      SCOUT_LAYER), hdp.int_bits)
+    ik = jnp.trunc(k.reshape(B, nP * c["ps"], N, hd))
+    s = jnp.einsum("bngqh,bsnh->bngqs", iq, ik)
+    ar = jnp.arange(nP * c["ps"])
+    k_pos = jnp.where(ar[None] <= c["q_pos"].reshape(B, 1), ar, -1)
+    valid = _mask_bias(c["q_pos"], k_pos[:, None, None, :], True, 0)
+    return decode_scout(s, valid, hdp)
+
+
+def _kernel_scout(c, pool):
+    """The paged scout kernel and the shared decision on its theta."""
+    from repro.core.hdp import scout_decision
+    from repro.kernels.hdp_paged_scout import hdp_paged_scout
+
+    lens = jnp.asarray(c["lens"], jnp.int32)
+    theta, walked = hdp_paged_scout(
+        c["iq"][..., 0, :], jnp.asarray(pool), jnp.asarray(c["table"]), lens,
+        int_bits=c["hdp"].int_bits, layer=jnp.int32(SCOUT_LAYER),
+        interpret=True)
+    bvalid = (jnp.arange(SCOUT_COLS) * c["ps"] < lens[:, None])[:, None, None]
+    return scout_decision(theta, bvalid, lens[:, None, None], c["hdp"]), walked
+
+
+class TestPagedScoutKernel:
+    @pytest.mark.parametrize("case", list(SCOUT_LENS))
+    def test_matches_xla_scout(self, case):
+        """Theta, the keep mask and the head gate equal the XLA scout's
+        bit for bit; the kernel walks ceil(kv_len / ps) columns a slot.
+        Poison codes in allocated pages read 0 at valid positions and
+        change nothing past a slot's extent; poisoned pages past the
+        count (the scratch page among them) change nothing."""
+        c = _scout_case(case)
+        pool = c["pool"]
+        if case == "poison_allocated":
+            ps, table = c["ps"], c["table"]
+            hit = np.zeros(pool.shape[1:], bool)          # [P, N, ps, hd]
+            for b, n in enumerate(c["lens"]):
+                last = table[b, max(int(c["counts"][b]) - 1, 0)]
+                hit[last, :, n % ps:] = True             # the tail past kv_len
+                hit[table[b, 0], 1, 3, :5] = n > 3       # valid positions
+            poisoned = pool.copy()
+            poisoned[SCOUT_LAYER][hit] = POISON_CODE
+            clean = pool.copy()
+            clean[SCOUT_LAYER][hit] = 0
+        elif case == "poison_past_count":
+            dead = [0] + [int(p) for b, n in enumerate(c["counts"])
+                          for p in c["table"][b, n:] if p]
+            poisoned = pool.copy()
+            poisoned[SCOUT_LAYER, dead] = POISON_CODE
+            clean = pool
+        else:
+            poisoned = clean = pool
+        # gate about half the heads: tau_h at the median head importance
+        tau = float(np.median(np.asarray(_xla_scout(c, clean)[3])))
+        c["hdp"] = c["hdp"].replace(tau_h=tau)
+        (keep, bvalid, theta, theta_head, head_kept), walked = \
+            _kernel_scout(c, poisoned)
+        want = _xla_scout(c, poisoned)
+        names = ("keep", "bvalid", "theta", "theta_head", "head_kept")
+        for name, got, ref in zip(names, (keep, bvalid, theta, theta_head,
+                                          head_kept), want):
+            np.testing.assert_array_equal(
+                np.broadcast_to(np.asarray(got), np.shape(ref)),
+                np.asarray(ref), err_msg=name)
+        np.testing.assert_array_equal(np.asarray(walked), c["counts"])
+        theta = np.asarray(theta)
+        past = np.arange(SCOUT_COLS)[None] >= c["counts"][:, None]
+        assert (theta.transpose(0, 3, 1, 2)[past] == 0).all()
+        assert (theta.transpose(0, 3, 1, 2)[~past] > 0).all()
+        assert 0 < np.asarray(head_kept).sum() < head_kept.size
+        assert 0 < np.asarray(keep).sum() < np.asarray(bvalid).sum() * 12
+        if poisoned is not clean:
+            np.testing.assert_array_equal(
+                theta, np.asarray(_kernel_scout(c, clean)[0][2]))

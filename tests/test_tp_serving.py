@@ -123,6 +123,24 @@ def test_tp_byte_identity(tp, arch):
     assert eng_tp.tp == tp and dict(eng_tp.mesh.shape)["model"] == tp
 
 
+def test_tp2_paged_scout_kernel():
+    """Under the Pallas paged backend each shard scouts its own kv heads
+    in the paged scout kernel: the tokens equal the unsharded engine's,
+    and ``scout_pages`` (the columns walked, the same on every shard)
+    equals the unsharded count, not twice it."""
+    cfg = _cfg()
+    prompts = _prompts(3, seed=9)
+    kw = dict(attn=AttnSpec(backend="pallas"), collect_stats=True)
+    eng, ref = _serve(cfg, None, prompts, **kw)
+    eng_tp, got = _serve(cfg, eng.params, prompts, tp=2, **kw)
+    assert eng_tp.resolved_backend("decode") == "pallas_paged_decode"
+    assert got == ref, "tp=2 changed the generated tokens"
+    s, s_tp = eng.summary(), eng_tp.summary()
+    assert s["scout_samples"] > 0
+    assert s_tp["scout_pages"] == s["scout_pages"]
+    assert s_tp["scout_samples"] == s["scout_samples"]
+
+
 @pytest.mark.parametrize("feat", [
     {"decode_horizon": 4},
     {"spec_decode": True, "draft_len": 3},

@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.hdp_block_attn import hdp_block_sparse_attention
 from repro.kernels.hdp_paged_decode import hdp_paged_fum_decode
+from repro.kernels.hdp_paged_scout import hdp_paged_scout
 from repro.kernels.hdp_scout import hdp_scout
 
 F32, I32 = jnp.float32, jnp.int32
@@ -132,6 +133,27 @@ def test_scout_compiles(one_chip):
     _assert_kernel(lambda a, b: hdp_scout(a, b, rho_b=0.5), x, x)
 
 
+@pytest.mark.parametrize("slots,table", [(12, 80), (26, 36)])
+def test_paged_scout_compiles_at_cell_shapes(one_chip, slots, table):
+    """The paged integer scout at the serving cells' decode shapes (12
+    slots x 80 table columns, 26 x 36) over the layer-stacked int8 pool
+    of 28 layers x 961 pages: it fits the chip's SMEM and VMEM there, and
+    its Mosaic call is named ``hdp_paged_scout``."""
+    layers, pages = 28, 961
+    args = [_sds((slots, N, G, HD), F32, one_chip),
+            _sds((layers, pages, N, PS, HD), jnp.int8, one_chip),
+            _sds((slots, table), I32, one_chip), _sds((slots,), I32, one_chip),
+            _sds((), I32, one_chip)]
+
+    def fn(iq, pool, tbl, kv_len, layer):
+        return hdp_paged_scout(iq, pool, tbl, kv_len, layer=layer)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert calls
+    assert all("hdp_paged_scout" in name for name in calls), calls
+
+
 def test_block_sparse_compiles(one_chip):
     nq = S // 128
     x = _sds((1, H, S, HD), F32, one_chip)
@@ -198,10 +220,10 @@ def _producers(text):
 
 def test_decode_step_hands_pool_to_kernel_in_place(topo, monkeypatch):
     """The engine's decode step at qwen2-1.5b widths (two layers): the
-    paged kernel reads the layer-stacked int8 pool the step carries — no
-    copy, relayout or per-layer slice of the pool feeds it, so the only
-    pool bytes moved are the scout stream and the surviving pages the
-    kernel DMAs."""
+    paged scout and paged decode kernels read the layer-stacked int8 pool
+    the step carries — no copy, relayout or per-layer slice of the pool
+    feeds them, so the only pool bytes moved are the K codes the scout
+    DMAs and the surviving pages the decode kernel DMAs."""
     from repro.models import registry
     from repro.serving import Engine
 
@@ -233,11 +255,14 @@ def test_decode_step_hands_pool_to_kernel_in_place(topo, monkeypatch):
     prod = _producers(text)
     calls = [n for n, (_, op, _, rest) in prod.items()
              if op == "custom-call" and "tpu_custom_call" in rest]
-    assert calls, "no paged decode kernel in the decode step"
+    assert {c.split(".")[0] for c in calls} == {
+        "hdp_paged_scout", "hdp_paged_fum_decode"}, calls
     for call in calls:
         pools = [o for o in prod[call][2]
                  if prod.get(o, ("",))[0] in pool_shapes]
-        assert len(pools) == 2, f"{call}: K/V pool operands not found"
+        # the scout reads K alone, the decode kernel K and V
+        want = 1 if call.startswith("hdp_paged_scout") else 2
+        assert len(pools) == want, f"{call}: pool operands not found"
         for o in pools:
             # the pool arrives as the loop carries it, updated in place by
             # the decode write — never as a copy or a fusion's output
