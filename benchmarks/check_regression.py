@@ -8,8 +8,8 @@ Compares the ``current`` row block of a freshly produced
 BENCH_serving.json against the ``current`` block of the *committed* copy
 (saved aside before the bench run overwrites the file), row-matched by
 (bench, arch, hdp, backend, decode_horizon, attn_policy, kv_dtype,
-tp, dp) — the policy component keeps serving_autotune's static-vs-cost
-legs from colliding with rows of the other serving benches, the
+tp, dp) — the policy component is "static" on every row (older rows
+carry no attn_policy and normalize to it), the
 kv_dtype component keeps serving_kvquant's int8-vs-fp32 legs apart
 (rows from before the quantized pool normalize to "fp32"), and the
 tp/dp components keep serving_tp's mesh legs apart (pre-mesh rows
@@ -44,8 +44,8 @@ def _load_rows(path: str):
 
 
 def _key(row: dict):
-    # rows recorded before the autotune subsystem carry no attn_policy
-    # (they all ran static selection), rows recorded before the
+    # rows without an attn_policy ran static selection, as every row
+    # does now, rows recorded before the
     # quantized KV pool carry no kv_dtype (they all served the fp32
     # pool), rows recorded before mesh-sharded serving carry no tp/dp
     # (they all served one unsharded engine), and rows recorded before

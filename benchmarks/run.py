@@ -16,7 +16,6 @@
 | serving_prefix | prefix-cache hit vs cold A/B   |
 | serving_spec   | speculative decode vs H=4 A/B  |
 | serving_stream | stream scheduler vs static/solo|
-| serving_autotune | cost policy vs static A/B + crossover sweep |
 | serving_kvquant | int8/fp8_v KV pool vs fp32 oracle A/B |
 | serving_tp     | tensor-parallel TP=1/2/4 sharded-pool A/B |
 | serving_chaos  | goodput under injected faults vs clean A/B |
@@ -289,93 +288,6 @@ def bench_serving_stream(quick: bool = False, backend: str = "auto"):
     print(",".join(str(h) for h in hdr))
     for r in rows:
         print(",".join(str(r.get(h, "")) for h in hdr))
-    return rows
-
-
-def bench_serving_autotune(quick: bool = False, backend: str = "auto"):
-    """Cost-driven backend selection A/B plus the predicted crossover sweep.
-
-    Per arch x hdp config, the same seeded workload runs once under
-    ``--policy static`` (registry priority order) and once under
-    ``--policy cost`` (the repro.autotune cost model ranks the auto
-    candidates under the detected hardware profile, sharpened by the
-    measured sparsity counters). Asserts the subsystem's acceptance
-    contract: generated tokens byte-identical across policies (cost only
-    selects among backends supporting the same call semantics), and —
-    whenever the cost policy resolves a DIFFERENT decode backend than
-    the static order — that its pick's decode tok/s stays within a noise
-    tolerance of the static pick. When both policies resolve the same
-    backend the compiled programs are identical, so the ratio is
-    reported but not gated (a handful of quick decode steps cannot
-    support a perf assertion). Tuner cache counters are recorded per
-    cost row.
-
-    Also records the predicted kv_len x page-sparsity crossover table
-    (paged-HDP decode vs dense attention step time) — the motivating
-    tradeoff of the whole subsystem — as ``backend="crossover"`` rows
-    (no decode_tok_s, so the regression gate skips them by design).
-    """
-    from repro.autotune import CallSig, crossover_table, reset_default_tuner
-    from repro.launch import serve
-    from repro.roofline.hardware import detect_profile
-
-    rows = []
-    tol = 0.5 if quick else 0.35
-    for arch in ("qwen2-1.5b",) if quick else ("qwen2-1.5b", "granite-8b"):
-        for no_hdp in (False, True):
-            pair = {}
-            for policy in ("static", "cost"):
-                reset_default_tuner()   # each leg tunes from cold
-                args = serve.build_parser().parse_args(
-                    ["--arch", arch, "--requests", "4" if quick else "8",
-                     "--max-new", "8" if quick else "24",
-                     "--backend", backend, "--policy", policy, "--warmup"]
-                    + (["--no-hdp"] if no_hdp else []))
-                out = serve.run(args)
-                row = {"arch": arch, "hdp": not no_hdp, **out}
-                row["backend"] = policy   # the A/B independent variable
-                rows.append(row)
-                pair[policy] = row
-            st, co = pair["static"], pair["cost"]
-            assert co["tokens_fp"] == st["tokens_fp"], \
-                f"{arch} hdp={not no_hdp}: cost policy changed the tokens"
-            if co["attn_decode"] != st["attn_decode"]:
-                # cost picked a different program — THAT choice must not
-                # be a regression. When the picks agree the compiled
-                # programs are identical and any tok/s delta is host
-                # noise (these quick runs decode a handful of steps), so
-                # the ratio is reported, not gated.
-                assert co["decode_tok_s"] >= st["decode_tok_s"] * (1 - tol), \
-                    (f"{arch} hdp={not no_hdp}: cost-picked "
-                     f"{co['attn_decode']} decode {co['decode_tok_s']} "
-                     f"tok/s fell more than {tol:.0%} below static "
-                     f"{st['attn_decode']} {st['decode_tok_s']}")
-            print(f"## {arch} hdp={not no_hdp}: cost "
-                  f"{co['decode_tok_s']} tok/s ({co['attn_decode']}) vs "
-                  f"static {st['decode_tok_s']} ({st['attn_decode']}), "
-                  f"tuner misses {co.get('tuner_misses', 0)} probes "
-                  f"{co.get('tuner_probes', 0)}, tokens byte-identical")
-    print("# serving cost-policy A/B (auto candidates ranked by the "
-          "analytic cost model, measured-sparsity sharpened)")
-    hdr = [h for h in rows[0] if h != "requests"]
-    print(",".join(str(h) for h in hdr))
-    for r in rows:
-        print(",".join(str(r.get(h, "")) for h in hdr))
-
-    # predicted crossover sweep: where sparsity x kv_len starts paying for
-    # the sparse pipeline's per-op overhead (model-free — pure predictor)
-    hw = detect_profile()
-    sig = CallSig(mode="decode", layout="paged", batch=4, n_kv_heads=2,
-                  group=6, sq=1, hd=64, kv_len=0, page_size=16, hdp=True,
-                  per_slot=True, kv_itemsize=1)  # the int8 default pool
-    print(f"# predicted paged-HDP vs dense crossover ({hw.name})")
-    print("kv_len,page_sparsity,t_hdp_s,t_dense_s,winner")
-    for c in crossover_table(sig, hw, kv_lens=(128, 512, 2048, 8192),
-                             page_sparsities=(0.0, 0.25, 0.5, 0.75)):
-        print(f"{c['kv_len']},{c['page_sparsity']},{c['t_hdp_s']:.3e},"
-              f"{c['t_dense_s']:.3e},{c['winner']}")
-        rows.append({"arch": "predictor", "hdp": True,
-                     "backend": "crossover", "hw": hw.name, **c})
     return rows
 
 
@@ -661,7 +573,6 @@ def _register():
         "serving_prefix": bench_serving_prefix,
         "serving_spec": bench_serving_spec,
         "serving_stream": bench_serving_stream,
-        "serving_autotune": bench_serving_autotune,
         "serving_kvquant": bench_serving_kvquant,
         "serving_tp": bench_serving_tp,
         "serving_chaos": bench_serving_chaos,
@@ -670,8 +581,8 @@ def _register():
 
 #: benches that accept an attention-backend selection (--backend)
 _BACKEND_AWARE = ("serving", "serving_paged", "serving_prefix",
-                  "serving_spec", "serving_stream", "serving_autotune",
-                  "serving_kvquant", "serving_tp", "serving_chaos")
+                  "serving_spec", "serving_stream", "serving_kvquant",
+                  "serving_tp", "serving_chaos")
 
 
 def write_bench_json(path: str, results: dict, *, quick: bool,

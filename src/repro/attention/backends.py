@@ -8,17 +8,17 @@ Seven implementations, one registry (reference lives in reference.py):
 | xla_hdp            | hdp_prefill/decode_attention                 | HDP on, dense layout (+draft/verify) |
 | paged_hdp_decode   | hdp_paged_decode_attention (XLA stage 3)     | HDP on, paged decode (+draft/verify) |
 | pallas_flash       | kernels.flash_attention                      | HDP off, aligned self-attn prefill   |
-| pallas_hdp_block   | kernels.ops.hdp_attention_tpu / FUM stage 3  | HDP on, aligned prefill or paged     |
+| pallas_hdp_block   | kernels.ops.hdp_attention_tpu                | HDP on, aligned dense prefill        |
 | pallas_paged_decode| kernels.hdp_paged_decode (gather-free FUM)   | HDP on, causal paged (+verify)       |
 
-Pallas backends rank above XLA only on TPU (``pallas_paged_decode``
-out-ranks ``pallas_hdp_block`` there: it streams surviving pages straight
-from the pool instead of densifying first, so pruned pages cost no HBM
-traffic at all); off-TPU they run in interpret mode when explicitly
-requested and are never auto-selected. None has a VJP, so none supports
-trainable calls, and the FUM kernels' per-row validity (cols < kv_len)
-cannot express a sliding window's lower bound — windowed calls fall back
-to the XLA chain.
+Pallas backends rank above XLA only on TPU; off-TPU they run in
+interpret mode when explicitly requested and are never auto-selected.
+``pallas_paged_decode`` streams surviving pages straight from the pool,
+so pruned pages cost no HBM traffic at all; every paged call it refuses
+(non-causal, windowed, draft, approx-softmax, absmax-scaled) resolves to
+``paged_hdp_decode``. None has a VJP, so none supports trainable calls,
+and the FUM kernels' per-row validity (cols < kv_len) cannot express a
+sliding window's lower bound — windowed calls fall back to the XLA chain.
 """
 from __future__ import annotations
 
@@ -99,7 +99,7 @@ def _run_paged(q, call, *, q_pos, k_pos, cache, page_table, stage3):
         draft=call.draft, per_query=call.verify,
         fk_pool=cache.get("f_scout"),
         k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
-        kv_scale=getattr(call, "kv_scale", "grid"),
+        kv_scale=call.kv_scale,
         layer=cache.get("layer"))
     return out, normalize_stats(st)
 
@@ -136,23 +136,15 @@ def _supports_pallas_hdp(call: AttnCall) -> bool:
     if call.hdp is None or call.trainable or call.window != 0 \
             or call.hdp.approx_softmax:
         return False
-    if call.draft is not None or call.verify:
-        # the block kernel computes neither the draft score sources nor
-        # per-query-row scouts; speculative calls fall down the chain
-        return False
-    if call.layout == "paged":
-        return True
-    return (call.mode == "prefill" and call.self_aligned
-            and not call.per_slot and call.hdp.causal == call.causal)
+    return (call.layout == "dense" and call.mode == "prefill"
+            and call.self_aligned and not call.per_slot
+            and call.hdp.causal == call.causal)
 
 
 @register_backend("pallas_hdp_block", supports=_supports_pallas_hdp,
                   priority=5, tpu_priority=20, tags=("pallas",))
 def run_pallas_hdp_block(q, k, v, call, *, q_pos, k_pos, cache=None,
                          page_table=None):
-    if call.layout == "paged":
-        return _run_paged(q, call, q_pos=q_pos, k_pos=k_pos, cache=cache,
-                          page_table=page_table, stage3="pallas_block")
     from repro.kernels.ops import hdp_attention_tpu
     B, N, G, Sq, hd = q.shape
     out, st = hdp_attention_tpu(
@@ -171,12 +163,15 @@ def _supports_pallas_paged(call: AttnCall) -> bool:
     whose consecutive rows each extend the bound by their query index —
     but cannot express a sliding window's lower bound or a non-causal
     extent. Draft calls fall down the chain: the kernel reads the
-    full-precision pool, which the draft score sources never touch.
+    full-precision pool, which the draft score sources never touch. So do
+    calibrated (``absmax``) pools: the kernel derives its in-register
+    scout from the static grid, which would fork the keep mask.
     """
     return (call.hdp is not None and call.layout == "paged"
             and call.mode == "decode" and not call.trainable
             and call.window == 0 and not call.hdp.approx_softmax
-            and call.causal and call.hdp.causal and call.draft is None)
+            and call.causal and call.hdp.causal and call.draft is None
+            and call.kv_scale != "absmax")
 
 
 @register_backend("pallas_paged_decode", supports=_supports_pallas_paged,

@@ -2,14 +2,16 @@
 
 Each backend registers a ``run`` callable plus a ``supports(call)``
 capability predicate and a priority; :func:`attention` is the single
-dispatch entry the model layer calls. Selection is explicit and
-testable:
+dispatch entry the model layer calls. One fixed rule picks the backend:
 
 * ``AttnSpec(backend="auto")`` picks the highest-ranked backend whose
   ``supports(call)`` is True. Pallas backends out-rank XLA only on TPU
   (off-TPU they would run in interpret mode — still selectable
   explicitly, never picked automatically); the ``reference`` oracle
-  ranks last, so the fallback chain is pallas -> xla -> reference.
+  ranks last, so the fallback chain is pallas -> xla -> reference. The
+  predicates are the only authority: a backend that declares a call
+  runs it as declared, and ``Engine.summary()`` reports the same
+  resolution.
 * An exact name (``"pallas_hdp_block"``) or family tag (``"pallas"``)
   requests that implementation; if it cannot serve the call the spec
   either falls down the auto chain (``allow_fallback=True``, the
@@ -20,8 +22,8 @@ testable:
   oracle path exercised on every PR.
 
 Registering a new backend is one ``@register_backend`` function plus one
-row in the conformance matrix (tests/test_attention_registry.py) — the
-extension point for the ROADMAP's TPU-native decode work.
+row in the conformance matrix and the selection table
+(tests/test_attention_registry.py).
 """
 from __future__ import annotations
 
@@ -36,11 +38,6 @@ from repro.attention.spec import AttnCall, AttnSpec
 
 #: env var forcing the *default* spec's backend (explicit specs win).
 BACKEND_ENV = "REPRO_ATTN_BACKEND"
-
-#: env var deciding how policy="auto" specs rank auto-selected backends:
-#: "cost" routes through the repro.autotune cost model; anything else
-#: (including unset) keeps the static priority order.
-POLICY_ENV = "REPRO_ATTN_POLICY"
 
 _BACKEND_MODULES = ("repro.attention.reference", "repro.attention.backends")
 
@@ -132,24 +129,9 @@ def default_spec() -> AttnSpec:
     return AttnSpec(backend=os.environ.get(BACKEND_ENV, "auto"))
 
 
-def effective_policy(spec: AttnSpec) -> str:
-    """The selection policy ``spec`` actually runs under: its own unless
-    "auto", in which case REPRO_ATTN_POLICY=cost opts the process in."""
-    if spec.policy != "auto":
-        return spec.policy
-    return ("cost" if os.environ.get(POLICY_ENV, "").strip() == "cost"
-            else "static")
-
-
-def resolve_backend(call: AttnCall, spec: Optional[AttnSpec] = None, *,
-                    sig=None, tuner=None) -> Backend:
-    """Pick the backend serving ``call`` under ``spec`` (static logic).
-
-    ``sig`` (a :class:`repro.autotune.cost.CallSig`) activates cost-based
-    ranking of the auto candidates when the spec's effective policy is
-    "cost"; without it (or under explicit requests) the static priority
-    order decides. ``tuner`` overrides the process-default tuner.
-    """
+def resolve_backend(call: AttnCall,
+                    spec: Optional[AttnSpec] = None) -> Backend:
+    """Pick the backend serving ``call`` under ``spec``."""
     _ensure_backends()
     spec = spec if spec is not None else default_spec()
     cands = [b for b in _REGISTRY.values() if b.supports(call)]
@@ -165,11 +147,6 @@ def resolve_backend(call: AttnCall, spec: Optional[AttnSpec] = None, *,
         # forces the oracle end-to-end even through explicit specs that
         # only pin the layout; explicit non-auto requests still win
         req = os.environ.get(BACKEND_ENV, "auto")
-    if req == "auto" and sig is not None and effective_policy(spec) == "cost":
-        if tuner is None:
-            from repro.autotune.tuner import default_tuner
-            tuner = default_tuner()
-        return tuner.choose(call, sig, cands)
     if req != "auto":
         known = {n for b in _REGISTRY.values() for n in (b.name, *b.tags)}
         if req not in known:
@@ -205,21 +182,6 @@ def attention(q, k, v, call: AttnCall, *, spec: Optional[AttnSpec] = None,
         q_pos = jnp.arange(q.shape[-2])
     if k_pos is None and k is not None:
         k_pos = jnp.arange(k.shape[1])
-    sig = None
-    eff_spec = spec if spec is not None else default_spec()
-    if (effective_policy(eff_spec) == "cost"
-            and eff_spec.requested_for(call.mode) == "auto"):
-        # shapes/dtypes are static under tracing, so the signature (and
-        # hence the choice) is burnt into the compiled program
-        from repro.autotune.cost import call_signature
-        from repro.distribution.tp import active_tp
-
-        # under tensor-parallel serving this runs inside shard_map, so
-        # q/cache shapes are already per-shard (local heads); the tp
-        # degree keys the signature so cached probe results never cross
-        # mesh shapes, and funds the collective term in the cost model
-        sig = call_signature(call, q, k=k, cache=cache,
-                             page_table=page_table, tp=active_tp())
-    backend = resolve_backend(call, eff_spec, sig=sig)
+    backend = resolve_backend(call, spec)
     return backend.run(q, k, v, call, q_pos=q_pos, k_pos=k_pos,
                        cache=cache, page_table=page_table)

@@ -10,17 +10,14 @@ static under ``jax.jit`` tracing. The paper-level knobs named in the
 design (q_offset / kv_len) are generalized here to the ``q_pos`` /
 ``k_pos`` position arrays every implementation already masks with.
 
-``AttnSpec`` is the user-facing selection policy threaded through the
-model / serving layers instead of the former stringly-typed
-``attn_backend=`` / ``cache_backend=`` kwargs: an exact backend name, a
-family tag ("xla" | "pallas" | "reference"), or "auto", with optional
-per-mode overrides plus the serving cache layout. The old string kwargs
-keep working for one release via :func:`spec_from_legacy`.
+``AttnSpec`` is the user-facing selection request threaded through the
+model / serving layers: an exact backend name, a family tag ("xla" |
+"pallas" | "reference"), or "auto", with optional per-mode overrides
+plus the serving cache layout.
 """
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional
 
 from repro.core.config import HDPConfig
@@ -29,7 +26,6 @@ MODES = ("prefill", "decode")
 LAYOUTS = ("dense", "paged")
 CACHE_LAYOUTS = ("auto", "dense", "paged")
 DRAFT_SCORES = ("scout", "int", "approx")
-POLICIES = ("auto", "static", "cost")
 KV_DTYPES = ("auto", "fp32", "int8", "fp8_v")
 KV_SCALES = ("grid", "absmax")
 
@@ -161,7 +157,7 @@ class AttnCall:
 
 @dataclasses.dataclass(frozen=True)
 class AttnSpec:
-    """Backend-selection policy threaded through models / serving.
+    """Backend-selection request threaded through models / serving.
 
     Attributes:
       backend: exact backend name (``"xla_hdp"``), family tag (``"xla"``,
@@ -186,15 +182,6 @@ class AttnSpec:
         A/B drift gate is the accuracy contract instead).
       allow_fallback: when the requested backend does not support a call,
         fall down the auto chain instead of raising.
-      policy: how "auto" picks among supporting candidates —
-        * ``"static"``: registry priority order (the historical rule).
-        * ``"cost"``: the :mod:`repro.autotune` cost model ranks the
-          candidates under the detected hardware profile, probing
-          ambiguous calls once. Only consulted when the *requested*
-          backend resolves to "auto" — an exact name or family tag still
-          pins.
-        * ``"auto"`` (default): ``REPRO_ATTN_POLICY`` decides (``cost``
-          enables the tuner, anything else means static).
     """
 
     backend: str = "auto"
@@ -204,7 +191,6 @@ class AttnSpec:
     kv_dtype: str = "auto"
     kv_scale: str = "grid"
     allow_fallback: bool = True
-    policy: str = "auto"
 
     def __post_init__(self):
         if self.layout not in CACHE_LAYOUTS:
@@ -216,9 +202,6 @@ class AttnSpec:
         if self.kv_scale not in KV_SCALES:
             raise ValueError(
                 f"kv_scale must be one of {KV_SCALES}, got {self.kv_scale!r}")
-        if self.policy not in POLICIES:
-            raise ValueError(
-                f"policy must be one of {POLICIES}, got {self.policy!r}")
 
     def requested_for(self, mode: str) -> str:
         over = self.prefill if mode == "prefill" else self.decode
@@ -227,35 +210,3 @@ class AttnSpec:
     def replace(self, **kw) -> "AttnSpec":
         return dataclasses.replace(self, **kw)
 
-
-_LEGACY_ATTN = {"xla": "xla", "pallas": "pallas", "auto": "auto"}
-
-
-def spec_from_legacy(attn_backend: Optional[str] = None,
-                     cache_backend: Optional[str] = None,
-                     base: Optional[AttnSpec] = None,
-                     stacklevel: int = 3) -> AttnSpec:
-    """Map the deprecated string kwargs onto an :class:`AttnSpec`.
-
-    Emits ONE DeprecationWarning covering every legacy kwarg passed.
-    Removal is scheduled for the release after the registry lands.
-    """
-    spec = base if base is not None else AttnSpec()
-    legacy = []
-    if attn_backend is not None:
-        if attn_backend not in _LEGACY_ATTN:
-            raise ValueError(f"unknown attn_backend {attn_backend!r}")
-        legacy.append(f"attn_backend={attn_backend!r}")
-        spec = spec.replace(backend=_LEGACY_ATTN[attn_backend])
-    if cache_backend is not None:
-        if cache_backend not in CACHE_LAYOUTS:
-            raise ValueError(f"unknown cache_backend {cache_backend!r}")
-        legacy.append(f"cache_backend={cache_backend!r}")
-        spec = spec.replace(layout=cache_backend)
-    if legacy:
-        warnings.warn(
-            f"{', '.join(legacy)} string kwargs are deprecated; pass "
-            f"attn=AttnSpec(backend={spec.backend!r}, layout={spec.layout!r}) "
-            "instead (repro.attention.AttnSpec)",
-            DeprecationWarning, stacklevel=stacklevel)
-    return spec

@@ -27,7 +27,7 @@ import sys
 
 import numpy as np
 
-from repro.attention import AttnSpec, spec_from_legacy
+from repro.attention import AttnSpec
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.launch.runtime import check_platform, enable_compile_cache
@@ -59,12 +59,6 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["auto", "paged", "dense"],
                     help="serving cache layout: paged = block-paged KV cache "
                          "(FUM page gather); dense = per-slot contiguous")
-    ap.add_argument("--cache-backend", default=None,
-                    choices=["auto", "paged", "dense"],
-                    help="DEPRECATED: use --layout")
-    ap.add_argument("--attn-backend", default=None,
-                    choices=["xla", "pallas"],
-                    help="DEPRECATED: use --backend")
     ap.add_argument("--kv-dtype", default="auto",
                     choices=["auto", "fp32", "int8", "fp8_v"],
                     help="paged KV pool storage format: int8 = per-page "
@@ -122,18 +116,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--draft-len", type=int, default=None,
                     help="tokens proposed+verified per speculative round; "
                          "default honors REPRO_DRAFT_LEN, else 4")
-    ap.add_argument("--policy", default=None,
-                    choices=["static", "cost"],
-                    help="auto-selection policy for the attention backend: "
-                         "static = registry priority order; cost = the "
-                         "repro.autotune cost model ranks candidates under "
-                         "the detected hardware profile (probing ambiguous "
-                         "calls once). Default honors REPRO_ATTN_POLICY, "
-                         "else static")
-    ap.add_argument("--tuner-cache", default=None,
-                    help="JSON path for the cost-policy tuner's measured "
-                         "cache: loaded before serving (warm start) and "
-                         "written back after, so repeat runs skip probes")
     ap.add_argument("--adaptive-spec", dest="adaptive_spec",
                     action="store_true", default=None,
                     help="acceptance-adaptive speculation: an EMA of the "
@@ -227,20 +209,9 @@ def run(args) -> dict:
             hdp = dataclasses.replace(hdp, calib=args.calib)
         cfg = cfg.replace(hdp=hdp)
 
-    policy = getattr(args, "policy", None)
     spec = AttnSpec(backend=args.backend, layout=args.layout,
-                    policy=policy if policy is not None else "auto",
                     kv_dtype=getattr(args, "kv_dtype", "auto"),
                     kv_scale=getattr(args, "kv_scale", "grid"))
-    if args.attn_backend is not None or args.cache_backend is not None:
-        # one-release deprecation shim for the old string flags
-        spec = spec_from_legacy(args.attn_backend, args.cache_backend,
-                                base=spec)
-    tuner = None
-    tuner_cache = getattr(args, "tuner_cache", None)
-    if tuner_cache:
-        from repro.autotune import Tuner
-        tuner = Tuner(cache_path=tuner_cache)
     stream = getattr(args, "stream_sched", None)
     sched_cfg = SchedulerConfig(
         prefill_chunk_tokens=getattr(args, "prefill_chunk", None),
@@ -259,7 +230,6 @@ def run(args) -> dict:
                      spec_decode=args.spec_decode,
                      draft_len=args.draft_len,
                      adaptive_spec=getattr(args, "adaptive_spec", None),
-                     tuner=tuner,
                      stream_sched=stream, sched=sched_cfg,
                      tp=getattr(args, "tp", None))
     fault_plan = getattr(args, "fault_plan", None)
@@ -387,7 +357,6 @@ def run(args) -> dict:
         "tokens_fp": tokens_fp,
         "spec_decode": s["spec_decode"],
         "stream_sched": s["stream_sched"],
-        "attn_policy": s["attn_policy"],
         "tp": int(s.get("tp", 1)),
         "dp": dp,
     }
@@ -421,15 +390,6 @@ def run(args) -> dict:
             round(float(v), 5) for v in s.get("replica_last_step_s", [])]
     if "meas_decode_step_s" in s:
         out["meas_decode_step_s"] = round(s["meas_decode_step_s"], 6)
-    if s["attn_policy"] == "cost":
-        out.update(tuner_hits=int(s.get("tuner_hits", 0)),
-                   tuner_misses=int(s.get("tuner_misses", 0)),
-                   tuner_probes=int(s.get("tuner_probes", 0)),
-                   tuner_cached=int(s.get("tuner_cached", 0)))
-        if "pred_decode_step_s" in s:
-            out["pred_decode_step_s"] = round(s["pred_decode_step_s"], 6)
-        if tuner_cache and eng0.tuner is not None:
-            eng0.tuner.save(tuner_cache)   # warm-start the next run
     if s["stream_sched"]:
         out.update(
             sched_admitted=int(s["sched_admitted"]),

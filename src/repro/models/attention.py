@@ -28,8 +28,8 @@ from repro.core.hdp import calibrated_split, decode_scout, scout_decision
 from repro.core.quant import (FRAC_SCOUT_SCALE, POISON_CODE, encode_pool,
                               encode_pool_scaled, pool_int_bits, pool_scale,
                               pool_view_finite, quantize_and_split,
-                              quantize_fixed, roundtrip_pool,
-                              scout_frac_codes, scout_int_codes)
+                              roundtrip_pool, scout_frac_codes,
+                              scout_int_codes)
 from repro.distribution.sharding import shard_activation as shd
 from repro.models import layers as L
 
@@ -684,8 +684,6 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
       stage-2 memory stays O(page_chunk) instead of O(Sk).
     * ``"pallas_paged"`` — the gather-free FUM kernel: scalar-prefetched
       page ids index the pool directly (interpret mode off-TPU).
-    * ``"pallas_block"`` — the block-sparse kernel on a densified gather
-      (the pre-kernel route, kept for the conformance matrix).
 
     ``kv_scale="absmax"`` (quantized pools only) reads per-page
     *calibrated* scales instead of assuming the static power-of-two
@@ -694,7 +692,9 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
     codes -> 0, so the scout stays finite exactly as on the static
     grid), and the stage-3 consumers already dequantize through the
     gathered scales. The FUM kernel derives its scout from the static
-    grid, so ``stage3="pallas_paged"`` falls back to "xla" here.
+    grid, so ``stage3="pallas_paged"`` falls back to "xla" here (as it
+    does for a sliding window; the registry never routes either call to
+    the kernel, and these fallbacks keep direct calls correct).
 
     ``per_query`` runs the scout per query row (the multi-query verify
     shape: each of the Sq rows computes the keep mask / head gate its own
@@ -716,11 +716,6 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
         # the kernels' per-row validity is an upper bound (cols < kv_len)
         # and cannot express the sliding-window lower bound; fall back to
         # the jnp path rather than silently attending out-of-window keys
-        stage3 = "xla"
-    if stage3 == "pallas_block" and per_query:
-        # the densifying block kernel's reshapes are Sq-unaware; fall
-        # back like the windowed case instead of crashing a direct
-        # conformance call (registry dispatch never routes verify here)
         stage3 = "xla"
     if stage3 == "pallas_paged" and absmax:
         # the FUM kernel derives its in-register scout from the STATIC
@@ -854,42 +849,6 @@ def hdp_paged_decode_attention(q, k_pool, v_pool, ik_pool, table, *,
                                            k_scale=k_scale if quantized else None,
                                            v_scale=v_scale if quantized else None,
                                            layer=layer)
-        elif stage3 == "pallas_block":
-            from repro.kernels.hdp_block_attn import hdp_block_sparse_attention
-            from repro.kernels.ops import _auto_interpret
-            from repro.kernels.ref import keep_mask_to_indices
-
-            gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
-            if quantized:
-                k = _dequant_pages(gather_pages(k_pool, gather_idx, layer),
-                                   k_scale[gather_idx])
-                v = _dequant_pages(gather_pages(v_pool, gather_idx, layer),
-                                   v_scale[gather_idx])
-                k = k.reshape(B, Sk, N, hd)
-                v = v.reshape(B, Sk, N, hd)
-            else:
-                k = gather_pages(k_pool, gather_idx, layer).reshape(B, Sk, N, hd)
-                v = gather_pages(v_pool, gather_idx, layer).reshape(B, Sk, N, hd)
-            H = N * G
-            def per_head(x):  # [B,Sk,N,hd] -> [B,H,Sk,hd]
-                xh = jnp.repeat(x.transpose(0, 2, 1, 3), G, axis=1)
-                return xh
-            kq_h = per_head(quantize_fixed(k.astype(F32), hdp.int_bits,
-                                           hdp.frac_bits))
-            v_h = per_head(v)
-            qq_h = qq.reshape(B, H, Sq, hd)
-            keep_h = keep.reshape(B, H, 1, nP)
-            kv_idx, counts = keep_mask_to_indices(
-                keep_h, theta.reshape(B, H, 1, nP), nP)
-            # per-row validity: cols <= current position (replaces the kernel's
-            # aligned-self-attention causal mask, wrong for cached decode)
-            lens = (q_pos.reshape(B)[:, None] + 1) * jnp.ones((B, H), jnp.int32)
-            out = hdp_block_sparse_attention(
-                qq_h, kq_h, v_h, kv_idx, counts, head_kept.reshape(B, H),
-                causal=False, approx=hdp.approx, block_q=max(8, Sq),
-                block_k=ps, score_scale=1.0, kv_len=lens,
-                interpret=_auto_interpret(None))
-            out = out.reshape(B, N, G, Sq, hd)
         else:
             gather_idx = jnp.where(fetched, table, 0)         # pruned -> scratch
             cpp = max(1, page_chunk // ps)                    # pages per chunk

@@ -1,12 +1,8 @@
-"""Pluggable hardware profiles for roofline and cost-model predictions.
+"""Pluggable hardware profiles for roofline predictions.
 
 The dry-run roofline tables always projected TPU v5e numbers from
-module-level constants in ``analysis.py``; the serving autotuner
-(``repro.autotune``) reuses the same constants to predict attention-
-backend step times — but it runs wherever the engine runs, which in CI
-and on dev machines is a CPU host. A cost prediction made with TPU
-bandwidth on a CPU host is silently wrong in a way that flips backend
-choices, so the constants live here as named profiles and
+module-level constants in ``analysis.py``. The constants live here as
+named profiles so a roofline can also be drawn for the host it runs on:
 :func:`detect_profile` picks the one matching the actual JAX backend.
 
 ``analysis.py`` keeps re-exporting the TPU v5e numbers under their old
@@ -35,8 +31,7 @@ class HardwareProfile:
         that makes multi-stage sparse pipelines lose to one dense matmul
         at short kv_len.
       pallas_native: Pallas kernels compile natively; when False they run
-        in interpret mode and predictions scale by ``interpret_slowdown``
-        so auto-selection can never cost-pick an interpreted kernel.
+        in interpret mode, slower by about ``interpret_slowdown``.
     """
 
     name: str
@@ -58,9 +53,7 @@ TPU_V5E = HardwareProfile(
 
 #: Conservative CPU host envelope (CI runners, dev machines): XLA:CPU
 #: matmul throughput and DRAM bandwidth, with Pallas in interpret mode.
-#: Absolute numbers are order-of-magnitude — the autotuner compares
-#: backends under ONE profile, so ranking needs the ratios right
-#: (sparsity x kv_len vs per-op overhead), not the absolutes.
+#: Absolute numbers are order-of-magnitude.
 HOST_CPU = HardwareProfile(
     name="host_cpu", peak_flops=5e10, hbm_bw=2.5e10, ici_bw=1e9,
     mem_bytes=8 * 2 ** 30, dispatch_s=2e-5, op_overhead_s=2e-6,

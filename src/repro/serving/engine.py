@@ -90,10 +90,8 @@ aggregated into engine metrics so serving examples/benchmarks can report
 the achieved sparsity next to throughput. Attention implementation and
 cache layout are selected by an ``repro.attention.AttnSpec``
 (``attn=AttnSpec(backend="pallas")`` routes the paged HDP decode through
-the block-sparse Pallas kernel, interpret mode off-TPU); the resolved
-backend per phase is reported by ``summary()``. The old
-``cache_backend=``/``attn_backend=`` string kwargs keep working for one
-release through a deprecation shim.
+the gather-free Pallas paged kernel, interpret mode off-TPU); the
+backend the registry resolves per phase is reported by ``summary()``.
 """
 from __future__ import annotations
 
@@ -109,8 +107,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.attention import (AttnSpec, DraftProfile, default_spec,
-                             effective_policy, known_backend_names,
-                             resolve_backend, spec_from_legacy)
+                             known_backend_names, resolve_backend)
 from repro.configs.base import ModelConfig
 from repro.models import registry
 from repro.models.attention import build_attn_call
@@ -289,8 +286,6 @@ class Engine:
         (``AttnSpec(layout=...)``: auto = paged for transformer families,
         dense otherwise). None uses the default spec (honors the
         REPRO_ATTN_BACKEND env var).
-    cache_backend / attn_backend: DEPRECATED string kwargs, mapped onto
-        ``attn`` via a shim for one release (emits a DeprecationWarning).
     page_size: paged-layout page length; defaults to ``hdp.block_k``
         (must match it while HDP is enabled).
     num_pages: page-pool size override (default: one full table per slot
@@ -339,11 +334,6 @@ class Engine:
         — the knobs only move the work/acceptance tradeoff). None reads
         ``REPRO_ADAPTIVE_SPEC`` and degrades silently when spec decode
         is off; passing True explicitly without spec_decode raises.
-    tuner: explicit `repro.autotune.Tuner` to install as the process
-        default (shared by cost-policy dispatch everywhere; engines are
-        traced against the process tuner because backend selection
-        happens inside jit traces). None keeps the current default —
-        created lazily, warm-started from ``REPRO_TUNER_CACHE``.
     stream_sched: continuous-batching stream scheduler —
         ``submit()`` enqueues into a waiting queue and every step runs
         one `scheduler.StreamScheduler` tick (token-budget admission,
@@ -367,8 +357,6 @@ class Engine:
                  prefill_buckets: Sequence[int] = (32, 64, 128),
                  collect_stats: bool = False,
                  attn: Optional[AttnSpec] = None,
-                 cache_backend: Optional[str] = None,
-                 attn_backend: Optional[str] = None,
                  page_size: Optional[int] = None,
                  num_pages: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
@@ -377,7 +365,6 @@ class Engine:
                  draft_len: Optional[int] = None,
                  draft_profile: Optional[DraftProfile] = None,
                  adaptive_spec: Optional[bool] = None,
-                 tuner=None,
                  stream_sched: Optional[bool] = None,
                  sched: Optional[SchedulerConfig] = None,
                  mesh: Optional[jax.sharding.Mesh] = None,
@@ -389,8 +376,6 @@ class Engine:
         if isinstance(attn, str):
             attn = AttnSpec(backend=attn)
         spec = attn if attn is not None else default_spec()
-        if attn_backend is not None or cache_backend is not None:
-            spec = spec_from_legacy(attn_backend, cache_backend, base=spec)
         for phase in ("prefill", "decode"):
             req = spec.requested_for(phase)
             if req != "auto" and req not in known_backend_names():
@@ -474,20 +459,6 @@ class Engine:
         self.collect_stats = collect_stats
         self.paged = layout == "paged"
         self.attn_spec = spec
-        self.policy = effective_policy(spec)
-        self.tuner = None
-        if tuner is not None:
-            # backend selection happens inside jit traces, which consult
-            # the process-default tuner — install the explicit one there
-            from repro.autotune import set_default_tuner
-            set_default_tuner(tuner)
-        if self.policy == "cost":
-            from repro.autotune import default_tuner
-            self.tuner = default_tuner()
-        # static retrace token for the decode/spec AND prefill/chunk jits:
-        # bumped when a flushed probe flips a tuner decision, so exactly
-        # the affected programs re-trace (and re-consult the tuner).
-        self._attn_epoch = 0
         if decode_horizon is None:
             decode_horizon = int(os.environ.get(HORIZON_ENV, "1") or 1)
         if decode_horizon < 1:
@@ -594,21 +565,21 @@ class Engine:
         # insert copy remains on the admission path.
         self._prefill_jit = jax.jit(
             self._prefill_paged_fn if self.paged else self._prefill_dense_fn,
-            static_argnums=(2, 3), donate_argnums=(4,))
+            static_argnums=(2,), donate_argnums=(3,))
         self._chunk_jit = jax.jit(self._prefill_chunk_fn,
-                                  static_argnums=(2,), donate_argnums=(3,))
-        # static argnums: scan length / draft plan + the attention epoch
-        # (cost-policy retrace token); the spec round also threads the
-        # round's DraftProfile statically so the adaptive controller can
-        # swap profiles at a bounded number of compile entries
+                                  donate_argnums=(2,))
+        # static argnums: scan length / draft plan; the spec round also
+        # threads the round's DraftProfile statically so the adaptive
+        # controller can swap profiles at a bounded number of compile
+        # entries
         self._decode_jit = jax.jit(
             self._decode_loop_paged_fn if self.paged
             else self._decode_loop_dense_fn,
-            static_argnums=(0, 1), donate_argnums=(4,))
+            static_argnums=(0,), donate_argnums=(3,))
         self._spec_jit = jax.jit(
             self._spec_round_paged_fn if self.paged
             else self._spec_round_dense_fn,
-            static_argnums=(0, 1, 2), donate_argnums=(5,))
+            static_argnums=(0, 1), donate_argnums=(4,))
 
     # ------------------------------------------------------------ serving mesh
     def _mesh_ctx(self):
@@ -659,24 +630,21 @@ class Engine:
             collect_stats=self.collect_stats, attn=self.attn_spec)
         return new_cache, stats
 
-    def _prefill_paged_fn(self, params, tokens, bucket_len, epoch, pool,
-                          page_idx):
+    def _prefill_paged_fn(self, params, tokens, bucket_len, pool, page_idx):
         """Batched prefill fused with the page scatter, pool donated.
 
         ``page_idx`` [nb, pages_per_slot]: destination pool page per
         request-cache page (0-padded — the scratch page absorbs bucket
         padding, exactly as in `PagedKVCache.insert`)."""
-        del epoch  # static retrace token only — selection reruns per trace
         one_cache, stats = self._prefill_body(params, tokens, bucket_len)
         for r in range(tokens.shape[0]):
             pool = self.pages._insert_fn(pool, one_cache["k"],
                                          one_cache["v"], page_idx[r], r)
         return pool, stats
 
-    def _prefill_dense_fn(self, params, tokens, bucket_len, epoch,
-                          slot_cache, slots):
+    def _prefill_dense_fn(self, params, tokens, bucket_len, slot_cache,
+                          slots):
         """Batched prefill fused with the slot insert, slot cache donated."""
-        del epoch
         one_cache, stats = self._prefill_body(params, tokens, bucket_len)
         for r in range(tokens.shape[0]):
             slot_cache = kv_cache.insert_slot(slot_cache, one_cache,
@@ -684,8 +652,7 @@ class Engine:
                                               row=r)
         return slot_cache, stats
 
-    def _prefill_chunk_fn(self, params, tokens, epoch, cache, offset):
-        del epoch  # static retrace token only
+    def _prefill_chunk_fn(self, params, tokens, cache, offset):
         _, new_cache, stats = registry.apply_prefill(
             self.cfg, params, {"tokens": tokens}, cache,
             collect_stats=self.collect_stats, pos_offset=offset,
@@ -755,15 +722,13 @@ class Engine:
         tok, cache, pos, active, remaining = carry
         return ys, tok, cache, pos, active, remaining
 
-    def _decode_loop_paged_fn(self, length, epoch, params, tok, cache, table,
+    def _decode_loop_paged_fn(self, length, params, tok, cache, table,
                               floors, pos, active, remaining, eos, inject):
-        del epoch  # static retrace token only — selection reruns per trace
         return self._decode_loop(length, params, tok, cache, table, floors,
                                  pos, active, remaining, eos, inject)
 
-    def _decode_loop_dense_fn(self, length, epoch, params, tok, cache, pos,
+    def _decode_loop_dense_fn(self, length, params, tok, cache, pos,
                               active, remaining, eos, inject):
-        del epoch
         return self._decode_loop(length, params, tok, cache, None, None,
                                  pos, active, remaining, eos, inject)
 
@@ -923,16 +888,13 @@ class Engine:
         return ((exact.T, commit.T, fault, stats), tok, cache, pos,
                 new_active, remaining)
 
-    def _spec_round_paged_fn(self, k, profile, epoch, params, tok, cache,
-                             table, floors, pos, active, remaining, eos,
-                             inject):
-        del epoch  # static retrace token only
+    def _spec_round_paged_fn(self, k, profile, params, tok, cache, table,
+                             floors, pos, active, remaining, eos, inject):
         return self._spec_round(k, profile, params, tok, cache, table,
                                 floors, pos, active, remaining, eos, inject)
 
-    def _spec_round_dense_fn(self, k, profile, epoch, params, tok, cache,
-                             pos, active, remaining, eos, inject):
-        del epoch
+    def _spec_round_dense_fn(self, k, profile, params, tok, cache, pos,
+                             active, remaining, eos, inject):
         return self._spec_round(k, profile, params, tok, cache, None, None,
                                 pos, active, remaining, eos, inject)
 
@@ -1181,8 +1143,8 @@ class Engine:
             try:
                 with self._mesh_ctx():
                     new_cache, stats = self._prefill_jit(
-                        self.params, jnp.asarray(toks), bucket, self._attn_epoch,
-                        cache, scatter)
+                        self.params, jnp.asarray(toks), bucket, cache,
+                        scatter)
             except BaseException:
                 store.restore_if_undonated(cache)
                 for slot in slots:                 # roll admission back
@@ -1222,8 +1184,7 @@ class Engine:
         piece[0, :min(rem, clen)] = prompt[off:off + clen]
         with self._mesh_ctx():
             cache, stats = self._chunk_jit(
-                self.params, jnp.asarray(piece), self._attn_epoch, cache,
-                jnp.asarray(off, I32))
+                self.params, jnp.asarray(piece), cache, jnp.asarray(off, I32))
         self._record_stats(stats)
         self.metrics["prefill_tokens"] += clen
         self.metrics["prefill_prompt_tokens"] += min(rem, clen)
@@ -1501,11 +1462,6 @@ class Engine:
             p_mean = self._masked_mean(stats.page_sparsity, mask)
             m["page_sparsity"] += p_mean
             m["page_samples"] += 1
-            if self.tuner is not None:
-                # sharpen the cost model's sparse terms with measured
-                # decode sparsity (prefill samples carry no page field
-                # and would skew the decode-centric EMA)
-                self.tuner.observe_sparsity(b_mean, h_mean, p_mean)
         if getattr(stats, "kernel_block_pages", None) is not None:
             for k in ("kernel_pages", "kernel_block_pages"):
                 m[k] += float(np.sum(self._real_samples(getattr(stats, k),
@@ -1660,18 +1616,6 @@ class Engine:
         self.metrics["sched_preempted"] += 1
         return resume
 
-    def _maybe_retune(self) -> None:
-        """Flush pending tuner probes (host side, between device steps).
-
-        A measured winner that flips a standing cost decision bumps the
-        attention epoch — a static argument of the decode/spec AND
-        prefill/chunk jits — so exactly the affected programs re-trace
-        once and re-consult the tuner. Called at the top of every step
-        and by the stream scheduler when a recycled slot re-enters the
-        batch. No-op under static policy."""
-        if self.tuner is not None and self.tuner.flush_probes():
-            self._attn_epoch += 1
-
     def step(self) -> int:
         """One engine iteration: admit + one fused decode horizon (or,
         with ``spec_decode``, one fused self-speculative round).
@@ -1717,7 +1661,6 @@ class Engine:
         if self.faults is not None:
             self.faults.sleep(step_no)
         self._enforce_deadlines()
-        self._maybe_retune()
         with self.spans.span("engine.admit"):
             if self.sched is not None:
                 ticked = self.sched.tick()
@@ -1757,13 +1700,13 @@ class Engine:
                         with self._mesh_ctx():
                             ys, tok, new_cache, pos, active, remaining = \
                                 self._decode_jit(
-                                    length, self._attn_epoch, self.params,
-                                    self._last_tok, cache, self.pages.table(),
+                                    length, self.params, self._last_tok,
+                                    cache, self.pages.table(),
                                     self._floor_dev, self._pos, self._active_dev,
                                     self._remaining_dev, self._eos_dev, inject)
                     else:
                         ys, tok, new_cache, pos, active, remaining = self._decode_jit(
-                            length, self._attn_epoch, self.params, self._last_tok,
+                            length, self.params, self._last_tok,
                             cache, self._pos, self._active_dev, self._remaining_dev,
                             self._eos_dev, inject)
                 except BaseException:
@@ -1848,14 +1791,14 @@ class Engine:
                         with self._mesh_ctx():
                             ys, tok, new_cache, pos, active, remaining = \
                                 self._spec_jit(
-                                    k, profile, self._attn_epoch, self.params,
-                                    self._last_tok, cache, self.pages.table(),
+                                    k, profile, self.params, self._last_tok,
+                                    cache, self.pages.table(),
                                     self._floor_dev, self._pos, self._active_dev,
                                     self._remaining_dev, self._eos_dev, inject)
                     else:
                         ys, tok, new_cache, pos, active, remaining = self._spec_jit(
-                            k, profile, self._attn_epoch, self.params,
-                            self._last_tok, cache, self._pos, self._active_dev,
+                            k, profile, self.params, self._last_tok,
+                            cache, self._pos, self._active_dev,
                             self._remaining_dev, self._eos_dev, inject)
                 except BaseException:
                     store.restore_if_undonated(cache)
@@ -2013,11 +1956,9 @@ class Engine:
         ``phase``: "prefill" | "decode" | "draft" | "verify" (the last
         two are the speculative round's passes). Uses the SAME call
         constructor as ``attn_apply`` (models.attention.build_attn_call),
-        so the report cannot drift from the dispatch. Under the cost
-        policy the tuner's recorded decision for the phase (ground truth
-        of what a trace actually dispatched) takes precedence; before
-        any trace the static resolution is reported. Families without
-        attention layers (recurrent) report "none".
+        with the engine's ``kv_scale``, so the report cannot drift from
+        the dispatch. Families without attention layers (recurrent)
+        report "none".
         """
         if self.cfg.family in ("rwkv6",):
             return "none"
@@ -2028,11 +1969,7 @@ class Engine:
             per_slot=decode_like,
             collect_stats=self.collect_stats,
             draft=self.draft_profile if phase == "draft" else None,
-            verify=phase == "verify")
-        if self.tuner is not None:
-            dec = self.tuner.decision_for(call)
-            if dec is not None:
-                return dec
+            verify=phase == "verify", kv_scale=self.kv_scale)
         return resolve_backend(call, self.attn_spec).name
 
     # ------------------------------------------------------------- reporting
@@ -2088,31 +2025,8 @@ class Engine:
         m["cache_backend"] = "paged" if self.paged else "dense"
         m["attn_backend_prefill"] = self.resolved_backend("prefill")
         m["attn_backend_decode"] = self.resolved_backend("decode")
-        m["attn_policy"] = self.policy
         if m["decode_steps"]:
             m["meas_decode_step_s"] = m["decode_s"] / m["decode_steps"]
-        if self.tuner is not None:
-            ts = self.tuner.stats()
-            m["tuner_hits"] = ts["hits"]
-            m["tuner_misses"] = ts["misses"]
-            m["tuner_probes"] = ts["probes"]
-            m["tuner_cached"] = ts["measured"]
-            est = None
-            if self.cfg.family not in ("rwkv6",):
-                # under spec decode the per-round hot path is the
-                # multi-query verify call, not a plain decode step —
-                # predict the phase that actually ran
-                call = build_attn_call(
-                    self.cfg, mode="decode", paged=self.paged,
-                    per_slot=True, collect_stats=self.collect_stats,
-                    verify=self.spec)
-                est = self.tuner.estimate_for(call)
-            if est is not None:
-                from repro.autotune import predict_engine_step
-                _, ce = est
-                m["pred_decode_step_s"] = predict_engine_step(
-                    registry.param_count(self.cfg, active_only=True),
-                    self.max_batch, self.cfg.n_layers, ce, self.tuner.hw)
         if self.faults is not None:
             m["fault_plan"] = self.faults.plan.spec
             m["faults_fired"] = len(self.faults.fired)

@@ -413,10 +413,6 @@ class StreamScheduler:
             # decode already ran: this admission filled a slot vacated
             # mid-run — the continuous-batching recycle the bench pins
             m["sched_recycled"] += 1
-            # a recycled slot changes the shape mix the engine serves;
-            # give pending cost-policy probes a chance to settle before
-            # the refilled batch decodes (no-op under static policy)
-            self.eng._maybe_retune()
 
     # ---------------------------------------------- interleaved prefill
     def _advance_chunk(self) -> bool:

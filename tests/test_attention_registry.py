@@ -1,4 +1,4 @@
-"""Backend conformance matrix + auto-selection + deprecation shim.
+"""Backend conformance matrix + the one selection rule.
 
 Load-bearing guarantees pinned here:
 
@@ -6,18 +6,16 @@ Load-bearing guarantees pinned here:
   ``reference`` oracle on a (mode x layout x causal x hdp-on/off) grid —
   token-for-token up to float-reduction-order tolerance (the backends
   compute identical math with different reduction schedules; see ATOL);
-* off-TPU auto-selection resolves to the documented fallback chain for
-  each call shape (pallas -> xla -> reference, pallas never auto
-  off-TPU), and REPRO_ATTN_BACKEND forces the *default* spec only;
-* the deprecated ``attn_backend=``/``cache_backend=`` string kwargs keep
-  working end-to-end through Engine and launch/serve.py, emitting exactly
-  ONE DeprecationWarning (these tests are the only exemption from the
-  fast CI tier's ``-W error::DeprecationWarning``);
-* ``Engine.summary()`` reports the resolved backend per phase.
+* auto-selection resolves every call class serving, training and the
+  conformance grid make to the documented backend, off TPU and on it
+  (pallas -> xla -> reference, pallas never auto off-TPU), and
+  REPRO_ATTN_BACKEND forces the *default* spec only;
+* ``Engine.summary()`` reports the backend whose ``run`` the engine's
+  program dispatched, per phase.
 """
 from __future__ import annotations
 
-import warnings
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +23,9 @@ import numpy as np
 import pytest
 
 from repro.attention import (AttnCall, AttnSpec, BackendUnsupported,
-                             attention, get_backend, list_backends,
-                             resolve_backend, spec_from_legacy)
+                             DraftProfile, attention, get_backend,
+                             list_backends, resolve_backend)
+from repro.attention import registry
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.core.config import HDPConfig
@@ -167,18 +166,95 @@ def no_env(monkeypatch):
     monkeypatch.delenv("REPRO_ATTN_BACKEND", raising=False)
 
 
-@pytest.mark.parametrize("call,expect", [
-    (AttnCall(mode="prefill", hdp=HDP, self_aligned=True), "xla_hdp"),
-    (AttnCall(mode="prefill", self_aligned=True), "xla_dense"),
-    (AttnCall(mode="prefill", trainable=True, hdp=HDP), "xla_hdp"),
-    (AttnCall(mode="decode", hdp=HDP, per_slot=True), "xla_hdp"),
-    (AttnCall(mode="decode", layout="paged", hdp=HDP, per_slot=True),
-     "paged_hdp_decode"),
-    (AttnCall(mode="decode", layout="paged", per_slot=True), "xla_dense"),
-], ids=["prefill-hdp", "prefill-dense", "train-hdp", "decode-hdp",
-        "paged-hdp", "paged-dense"])
-def test_auto_resolution_off_tpu(call, expect, no_env):
-    assert jax.default_backend() != "tpu"
+# the HDP config build_attn_call hands a causal call (hdp.causal follows
+# the call's own mask)
+CAUSAL = HDP.replace(causal=True)
+PAGED = AttnCall(mode="decode", layout="paged", hdp=CAUSAL, per_slot=True)
+
+#: (id, call, backend off TPU, backend on TPU): one row per call class
+#: that serving, training or the conformance grid makes
+SELECTION = [
+    ("prefill-aligned-hdp",
+     AttnCall(mode="prefill", hdp=CAUSAL, self_aligned=True),
+     "xla_hdp", "pallas_hdp_block"),
+    ("prefill-aligned-dense",
+     AttnCall(mode="prefill", self_aligned=True),
+     "xla_dense", "pallas_flash"),
+    ("prefill-aligned-hdp-noncausal",
+     AttnCall(mode="prefill", causal=False, hdp=HDP, self_aligned=True),
+     "xla_hdp", "pallas_hdp_block"),
+    ("prefill-aligned-dense-noncausal",
+     AttnCall(mode="prefill", causal=False, self_aligned=True),
+     "xla_dense", "pallas_flash"),
+    # the engine prefills into a request cache: not self-aligned
+    ("prefill-request-cache-hdp",
+     AttnCall(mode="prefill", hdp=CAUSAL),
+     "xla_hdp", "xla_hdp"),
+    ("prefill-request-cache-dense",
+     AttnCall(mode="prefill"),
+     "xla_dense", "xla_dense"),
+    ("prefill-windowed-hdp",
+     AttnCall(mode="prefill", window=8, hdp=CAUSAL, self_aligned=True),
+     "xla_hdp", "xla_hdp"),
+    ("prefill-windowed-dense",
+     AttnCall(mode="prefill", window=8, self_aligned=True),
+     "xla_dense", "xla_dense"),
+    ("train-hdp",
+     AttnCall(mode="prefill", hdp=CAUSAL, self_aligned=True,
+              trainable=True),
+     "xla_hdp", "xla_hdp"),
+    ("train-dense",
+     AttnCall(mode="prefill", self_aligned=True, trainable=True),
+     "xla_dense", "xla_dense"),
+    ("decode-dense-hdp",
+     AttnCall(mode="decode", hdp=CAUSAL, per_slot=True),
+     "xla_hdp", "xla_hdp"),
+    ("decode-dense-nohdp",
+     AttnCall(mode="decode", per_slot=True),
+     "xla_dense", "xla_dense"),
+    ("decode-dense-verify",
+     AttnCall(mode="decode", hdp=CAUSAL, per_slot=True, verify=True),
+     "xla_hdp", "xla_hdp"),
+    ("decode-dense-draft",
+     AttnCall(mode="decode", hdp=CAUSAL, per_slot=True,
+              draft=DraftProfile()),
+     "xla_hdp", "xla_hdp"),
+    ("decode-cross-hdp",
+     AttnCall(mode="decode", causal=False, hdp=HDP),
+     "xla_hdp", "xla_hdp"),
+    # the cells' decode: a causal paged call over a static-grid pool
+    ("paged-grid", PAGED,
+     "paged_hdp_decode", "pallas_paged_decode"),
+    ("paged-absmax", PAGED.replace(kv_scale="absmax"),
+     "paged_hdp_decode", "paged_hdp_decode"),
+    ("paged-windowed", PAGED.replace(window=8),
+     "paged_hdp_decode", "paged_hdp_decode"),
+    ("paged-verify", PAGED.replace(verify=True),
+     "paged_hdp_decode", "pallas_paged_decode"),
+    ("paged-verify-absmax", PAGED.replace(verify=True, kv_scale="absmax"),
+     "paged_hdp_decode", "paged_hdp_decode"),
+    ("paged-draft", PAGED.replace(draft=DraftProfile()),
+     "paged_hdp_decode", "paged_hdp_decode"),
+    ("paged-noncausal", PAGED.replace(causal=False, hdp=HDP),
+     "paged_hdp_decode", "paged_hdp_decode"),
+    ("paged-approx-softmax",
+     PAGED.replace(hdp=CAUSAL.replace(approx_softmax=True)),
+     "paged_hdp_decode", "paged_hdp_decode"),
+    ("paged-nohdp", PAGED.replace(hdp=None),
+     "xla_dense", "xla_dense"),
+]
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("call,off_tpu,on_tpu",
+                         [row[1:] for row in SELECTION],
+                         ids=[row[0] for row in SELECTION])
+def test_selection_rule(call, off_tpu, on_tpu, platform, no_env,
+                        monkeypatch):
+    """The registry's fixed rule: the highest-ranked backend whose
+    ``supports(call)`` holds, Pallas ranked first only on TPU."""
+    monkeypatch.setattr(registry, "_on_tpu", lambda: platform == "tpu")
+    expect = on_tpu if platform == "tpu" else off_tpu
     assert resolve_backend(call).name == expect
 
 
@@ -186,16 +262,19 @@ def test_explicit_pallas_and_fallback(no_env):
     paged = AttnCall(mode="decode", layout="paged",
                      hdp=HDP.replace(causal=True), per_slot=True)
     spec = AttnSpec(backend="pallas")
-    # the "pallas" family tag prefers the gather-free page-table-native
-    # kernel; the densifying block kernel stays explicitly addressable
+    # the "pallas" family tag picks the gather-free page-table-native
+    # kernel; the block kernel serves no paged call, so asking for it
+    # falls down the chain (or raises without fallback)
     assert resolve_backend(paged, spec).name == "pallas_paged_decode"
-    assert resolve_backend(
-        paged, AttnSpec(backend="pallas_hdp_block")).name == "pallas_hdp_block"
+    block = AttnSpec(backend="pallas_hdp_block")
+    assert resolve_backend(paged, block).name == "paged_hdp_decode"
+    with pytest.raises(BackendUnsupported):
+        resolve_backend(paged, block.replace(allow_fallback=False))
     # non-causal paged calls can't use the gather-free kernel (its per-row
-    # validity is an upper bound) but the block kernel still serves them
+    # validity is an upper bound): no Pallas backend serves them
     noncausal = AttnCall(mode="decode", layout="paged", hdp=HDP,
                          causal=False, per_slot=True)
-    assert resolve_backend(noncausal, spec).name == "pallas_hdp_block"
+    assert resolve_backend(noncausal, spec).name == "paged_hdp_decode"
     # the FUM kernels cannot express a sliding window's lower bound ->
     # windowed calls fall down the chain to the XLA implementation
     windowed = paged.replace(window=8)
@@ -236,7 +315,7 @@ def test_supports_capability_edges():
         AttnCall(mode="prefill", layout="paged")
 
 
-# -------------------------------------------------------- deprecation shim
+# ------------------------------------------------------------ engine reports
 def _prompts(n, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(1, 250, size=10).tolist() for _ in range(n)]
@@ -247,51 +326,14 @@ def _cfg(calib="none"):
     return cfg.replace(hdp=cfg.hdp.replace(calib=calib))
 
 
-@pytest.mark.filterwarnings("always::DeprecationWarning")
-def test_engine_legacy_kwargs_single_warning():
-    cfg = _cfg()
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        eng = Engine(cfg, max_batch=1, max_len=64, prefill_buckets=(16,),
-                     cache_backend="paged", attn_backend="pallas")
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1, [str(w.message) for w in dep]
-    assert "AttnSpec" in str(dep[0].message)
-    # the shim maps onto the same spec the new API would build
-    assert eng.paged
-    assert eng.attn_spec.backend == "pallas"
-    eng.submit(Request(0, _prompts(1)[0], max_new_tokens=2))
-    toks = eng.run()[0].tokens
-    assert len(toks) == 2
-
-    new = Engine(cfg, params=eng.params, max_batch=1, max_len=64,
-                 prefill_buckets=(16,),
-                 attn=AttnSpec(backend="pallas", layout="paged"))
-    new.submit(Request(0, _prompts(1)[0], max_new_tokens=2))
-    assert new.run()[0].tokens == toks
-
-
-@pytest.mark.filterwarnings("always::DeprecationWarning")
-def test_serve_legacy_flags_end_to_end():
-    from repro.launch import serve
-    args = serve.build_parser().parse_args(
-        ["--arch", "qwen2-1.5b", "--requests", "1", "--max-new", "2",
-         "--attn-backend", "xla", "--cache-backend", "dense"])
-    with pytest.warns(DeprecationWarning):
-        out = serve.run(args)
-    assert out["completed"] == 1
-    assert out["backend"] == "dense"
-    assert out["attn_decode"] == "xla_hdp"
-
-
 def test_engine_rejects_unknown_strings():
     with pytest.raises(ValueError):
         Engine(_cfg(), max_batch=1, max_len=32,
                attn="definitely-not-a-backend")
     with pytest.raises(ValueError):
-        spec_from_legacy(attn_backend="cuda")
+        Engine(_cfg(), max_batch=1, max_len=32, attn=AttnSpec(backend="cuda"))
     with pytest.raises(ValueError):
-        spec_from_legacy(cache_backend="ring")
+        AttnSpec(layout="ring")
 
 
 def test_engine_summary_reports_resolved_backends(no_env):
@@ -304,3 +346,40 @@ def test_engine_summary_reports_resolved_backends(no_env):
     s = dense.summary()
     assert s["attn_backend_prefill"] == "reference"
     assert s["attn_backend_decode"] == "reference"
+
+
+@pytest.mark.parametrize("phase", ["decode", "verify"])
+@pytest.mark.parametrize("kv_scale", ["grid", "absmax"])
+def test_reported_backend_runs(kv_scale, phase, monkeypatch):
+    """The backend ``summary()`` names for a phase is the one whose
+    ``run`` the engine's program dispatched, and the Pallas paged kernel
+    walks pages exactly when that backend is ``pallas_paged_decode``
+    (an absmax pool is refused by its predicate, not rerouted inside
+    it). The ``pallas`` tag asks for the kernel wherever it can serve."""
+    ran = []
+
+    def spy(b):
+        def run(q, k, v, call, **kw):
+            if call.mode == "decode" and call.draft is None:
+                ran.append((call.verify, b.name))
+            return b.run(q, k, v, call, **kw)
+        return dataclasses.replace(b, run=run)
+
+    for b in list_backends():
+        monkeypatch.setitem(registry._REGISTRY, b.name, spy(b))
+    verify = phase == "verify"
+    eng = Engine(_cfg(), max_batch=1, max_len=32, prefill_buckets=(16,),
+                 collect_stats=True, spec_decode=verify, draft_len=2,
+                 attn=AttnSpec(backend="pallas", layout="paged",
+                               kv_dtype="int8", kv_scale=kv_scale))
+    eng.submit(Request(0, _prompts(1)[0], max_new_tokens=3))
+    eng.run()
+    s = eng.summary()
+    reported = (eng.resolved_backend("verify") if verify
+                else s["attn_backend_decode"])
+    dispatched = {name for v, name in ran if v == verify}
+    assert dispatched == {reported}
+    assert reported == ("pallas_paged_decode" if kv_scale == "grid"
+                        else "paged_hdp_decode")
+    assert (s["kernel_block_pages"] > 0) == (reported
+                                             == "pallas_paged_decode")

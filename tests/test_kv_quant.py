@@ -21,8 +21,6 @@ Load-bearing guarantees pinned here:
 * COW keeps the donor page's codes AND scale byte-identical, and
   prefix-cache hits under ``kv_dtype="int8"`` are token-identical to
   cold serves (the prefill-time round-trip guarantee);
-* the tuner's epoch token threads through the prefill AND chunked-
-  prefill jits: one forced probe flip re-traces each exactly once;
 * the serving summary reports the dtype-aware resident footprint
   (int8 <= 0.35x fp32 bytes per cached token).
 
@@ -289,53 +287,6 @@ def test_summary_reports_dtype_footprint():
             / legs["fp32"]["cache_bytes_per_token"]
         assert ratio <= 0.35, \
             f"{dt} pool is x{ratio:.2f} of fp32 bytes/token (> 0.35)"
-
-
-# --------------------------------------------------- epoch -> prefill jits
-def test_probe_flip_retraces_prefill_jits_once(monkeypatch):
-    """The tuner's epoch token is a static arg of the bucketed-prefill
-    AND chunked-prefill jits: a forced probe flip re-traces each compiled
-    entry exactly once (on the next admission after the flip), and the
-    re-trace commits identical tokens."""
-    monkeypatch.delenv("REPRO_ATTN_BACKEND", raising=False)
-    cfg = _qwen()
-    rng = np.random.default_rng(7)
-    short = rng.integers(1, 250, size=6).tolist()
-    long = rng.integers(1, 250, size=40).tolist()   # > largest bucket
-
-    eng = Engine(cfg, max_batch=1, max_len=64, prefill_buckets=(8, 16),
-                 attn=AttnSpec(policy="cost"), prefix_cache=False,
-                 spec_decode=False, stream_sched=False)
-
-    def serve(uids):
-        for uid, p in zip(uids, (short, long)):
-            eng.submit(Request(uid, p, max_new_tokens=3))
-        return {u: r.tokens for u, r in eng.run().items() if u in uids}
-
-    ref = serve((0, 1))
-    n_pref = eng._prefill_jit._cache_size()
-    n_chunk = eng._chunk_jit._cache_size()
-    assert n_pref > 0 and n_chunk > 0, "both prefill paths must have run"
-
-    # identical re-serve, no flip: nothing recompiles
-    out = serve((2, 3))
-    assert out == {2: ref[0], 3: ref[1]}
-    assert eng._prefill_jit._cache_size() == n_pref
-    assert eng._chunk_jit._cache_size() == n_chunk
-
-    # exactly one probe flip: the epoch bumps once during this wave's
-    # decode, so the NEXT wave's prefills re-trace...
-    flips = iter([True])
-    eng.tuner.flush_probes = lambda: next(flips, False)
-    out = serve((4, 5))
-    assert out == {4: ref[0], 5: ref[1]}
-    assert eng._attn_epoch == 1
-
-    # ...exactly once per compiled entry, tokens unchanged
-    out = serve((6, 7))
-    assert out == {6: ref[0], 7: ref[1]}
-    assert eng._prefill_jit._cache_size() == 2 * n_pref
-    assert eng._chunk_jit._cache_size() == 2 * n_chunk
 
 
 # --------------------------------------------------- absmax calibration

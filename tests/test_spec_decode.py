@@ -249,9 +249,7 @@ def test_scout_draft_requires_frac_pool():
 
 
 # --------------------------------------------------- per-query verify scout
-# pallas_block documents an Sq-unaware kernel: its per-query calls fall
-# back to the xla stage, which this conformance row pins
-@pytest.mark.parametrize("stage3", ["xla", "pallas_paged", "pallas_block"])
+@pytest.mark.parametrize("stage3", ["xla", "pallas_paged"])
 def test_verify_rows_match_sequential_steps(stage3):
     """Row j of a multi-query verify call must equal the single-step
     decode at position j — keep masks, head gates and softmax alike
@@ -280,7 +278,8 @@ def test_verify_rows_match_sequential_steps(stage3):
 
 def test_verify_call_resolves_through_registry():
     """The verify AttnCall resolves to backends that declared multi-query
-    capability; the draft call never lands on a Pallas kernel."""
+    capability; the draft call never lands on a Pallas kernel, and the
+    block kernel serves no paged call at all."""
     from repro.attention import get_backend, resolve_backend
     from repro.models.attention import build_attn_call
     cfg = _qwen()

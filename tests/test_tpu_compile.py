@@ -249,7 +249,7 @@ def test_decode_step_hands_pool_to_kernel_in_place(topo, monkeypatch):
         return _sds(shape, dt, one)
 
     text = eng._decode_jit.lower(
-        1, 0, params, v((b, 1)), pool, v((b, eng.pages.pages_per_slot)),
+        1, params, v((b, 1)), pool, v((b, eng.pages.pages_per_slot)),
         v((b,)), v((b,)), v((b,), jnp.bool_), v((b,)), v((b,)),
         v((b,), jnp.bool_)).compile().as_text()
     prod = _producers(text)

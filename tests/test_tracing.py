@@ -153,7 +153,7 @@ def test_decode_program_carries_the_named_scopes():
     eng._admit()
     p = eng.pages
     hlo = eng._decode_jit.lower(
-        1, eng._attn_epoch, eng.params, eng._last_tok, p.cache, p.table(),
+        1, eng.params, eng._last_tok, p.cache, p.table(),
         eng._floor_dev, eng._pos, eng._active_dev, eng._remaining_dev,
         eng._eos_dev, eng._zero_inject).compile().as_text()
     for scope in ("hdp.scout", "hdp.attend", "kv.write", "attn.proj", "mlp",
